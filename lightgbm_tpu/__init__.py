@@ -8,25 +8,39 @@ Python API and text model format.
 """
 import os as _os
 
-# Persistent XLA compilation cache (VERDICT r2 item 6: a first 2M-row
-# train paid ~2 min of compile before iteration 1 on every process).
-# Re-runs of any already-seen (shape, config) signature now load from
-# disk. Opt out with LIGHTGBM_TPU_COMPILE_CACHE=0; redirect with
-# LIGHTGBM_TPU_COMPILE_CACHE_DIR. jax.config.update is safe pre-backend
-# and does not initialize XLA.
-if _os.environ.get("LIGHTGBM_TPU_COMPILE_CACHE", "1") != "0":
+# Persistent XLA compilation cache: a re-run of an already-seen (shape,
+# config) signature loads its programs from disk instead of compiling.
+# ONE rule, kept here. If JAX_COMPILATION_CACHE_DIR is set, jax reads it
+# itself and this package sets no cache directory in code — not at
+# import, not from `tpu_compile_cache_dir` — so whoever launches the
+# process decides where the cache lives. Otherwise the cache sits at a
+# fixed path inside the checkout: the directory is part of what makes a
+# later process hit, so it is never derived from a home directory, a
+# temp dir, a pid or a clock. LIGHTGBM_TPU_COMPILE_CACHE=0 opts out of
+# the default (the environment variable, read by jax, still applies).
+DEFAULT_COMPILE_CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def compile_cache_dir_from_env() -> str:
+    """The cache directory placed from outside ("" when unset). While it
+    is non-empty no code in this package may re-point the cache
+    (`serving.forest.enable_compile_cache` asks here)."""
+    return _os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+
+
+if (_os.environ.get("LIGHTGBM_TPU_COMPILE_CACHE", "1") != "0"
+        and not compile_cache_dir_from_env()):
     try:
+        # jax.config.update is safe pre-backend: it initializes nothing
         import jax as _jax
-        _jax.config.update(
-            "jax_compilation_cache_dir",
-            _os.environ.get(
-                "LIGHTGBM_TPU_COMPILE_CACHE_DIR",
-                _os.path.join(_os.path.expanduser("~"), ".cache",
-                              "lightgbm_tpu", "jax_cache")))
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        _jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:  # pragma: no cover — cache is best-effort
-        pass
+        _jax.config.update("jax_compilation_cache_dir",
+                           DEFAULT_COMPILE_CACHE_DIR)
+    except Exception as _exc:  # training proceeds uncached, but says so
+        from . import log as _log
+        _log.warning("persistent compile cache at %s could not be armed: "
+                     "%r", DEFAULT_COMPILE_CACHE_DIR, _exc)
 
 # The public names below resolve lazily (PEP 562).  Training-free serving
 # replicas import `lightgbm_tpu.export.runtime` with the trainer modules

@@ -8,7 +8,7 @@ Three project-specific analyses several rules need:
 - **traced contexts** — which functions' bodies execute under a jax
   trace. Seeds: functions decorated with ``jax.jit`` (directly or via
   ``functools.partial``) or ``shard_map``; functions passed by name to
-  ``jax.jit(...)`` / ``shard_map(...)`` / ``shard_map_compat(...)``;
+  ``jax.jit(...)`` / ``shard_map(...)``;
   plus rule-configured known-traced name patterns (for getattr-style
   wrapping the AST cannot see, e.g. ops/predict.py's forest kernels
   jitted through ``gbdt._forest_jit``). Tracedness propagates through
@@ -145,7 +145,7 @@ def identifiers_in(node: ast.AST) -> Set[str]:
 # ---------------------------------------------------------------------------
 _JIT_NAMES = {"jax.jit", "jit", "jax.pjit", "pjit",
               "jax.experimental.pjit.pjit"}
-_SHARD_MAP_NAMES = {"jax.shard_map", "shard_map", "shard_map_compat",
+_SHARD_MAP_NAMES = {"jax.shard_map", "shard_map",
                     "jax.experimental.shard_map.shard_map"}
 
 

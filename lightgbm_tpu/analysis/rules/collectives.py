@@ -13,7 +13,8 @@ What the rule checks:
   watchdog.deadline(...)` block (or in a function whose every in-module
   call site does; see astutil.ModuleIndex.covered_functions).
 - calling a shard_map-produced function (a local name assigned from
-  `shard_map(...)` / `shard_map_compat(...)` / `jax.shard_map(...)`) is
+  `shard_map(...)` / `jax.shard_map(...)`, or from the learners'
+  `_sharded_grower(...)` builder that jits one) is
   a host-level dispatch of a program whose collectives can block on a
   peer: same deadline requirement, same interprocedural coverage (the
   learners.py idiom — `__call__` arms the deadline, `_dispatch` runs
@@ -41,7 +42,7 @@ from ..astutil import ModuleIndex, call_target, dotted_name
 LAX_COLLECTIVES = {"psum", "psum_scatter", "all_gather", "pmax", "pmin",
                    "pmean", "all_to_all", "ppermute", "pshuffle"}
 HOST_COLLECTIVES = {"process_allgather"}
-SHARD_MAP_MAKERS = {"shard_map", "shard_map_compat"}
+SHARD_MAP_MAKERS = {"shard_map", "_sharded_grower"}
 
 # traced-only functions the AST cannot see get jitted: ops/predict.py's
 # forest kernels are wrapped via jax.jit(getattr(predict_ops, name)) in
@@ -66,7 +67,7 @@ class UnguardedCollectiveRule(Rule):
         traced = idx.traced_functions()
 
         # local names bound to shard_map-produced callables, per
-        # enclosing function (run = shard_map_compat(f, ...); run(...))
+        # enclosing function (run = shard_map(f, ...); run(...))
         sharded_names: Set[ast.AST] = set()  # the Assign nodes
         shard_bound: dict = {}  # (enclosing_fn, name) -> assign node
         for node in ast.walk(src.tree):

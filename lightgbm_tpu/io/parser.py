@@ -67,9 +67,14 @@ def load_data_file(path: str, has_header: bool = False,
     if native_delim is not None:
         mat = _native_parse(path, native_delim, has_header)
         if mat is not None:
+            log.info("Parsed %s with the native parser "
+                     "(native/parser_native.so)", path)
             labels = mat[:, label_column]
             data = np.delete(mat, label_column, axis=1)
             return np.ascontiguousarray(data), labels.copy()
+    # which parser ran is never silent: the .so is a build product
+    # (native/build.py), absent from a fresh checkout
+    log.info("Parsing %s with the Python parser", path)
     rows: List[List[float]] = []
     labels: List[float] = []
     with open(path) as fh:
@@ -91,17 +96,25 @@ _native_lib = None
 _native_tried = False
 
 
+def native_parser_loaded() -> bool:
+    """True once a parse call has loaded native/parser_native.so."""
+    return _native_lib is not None
+
+
 def _native_parse(path: str, delim: str, has_header: bool):
     """Parse via native/parser_native.so (native/parser.cpp) when built;
     returns None to fall back to the Python path."""
     global _native_lib, _native_tried
     if not _native_tried:
-        _native_tried = True
         import ctypes
         so = os.path.join(os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__)))),
             "native", "parser_native.so")
+        # the .so is a build product: latch only once it exists, so a
+        # process that builds it (native/build.py) after its first parse
+        # still picks it up
         if os.path.exists(so):
+            _native_tried = True
             try:
                 lib = ctypes.CDLL(so)
                 lib.lgbm_tpu_parse_dense.restype = ctypes.c_int

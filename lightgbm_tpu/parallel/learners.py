@@ -46,16 +46,29 @@ from ..testing import faults
 from . import watchdog
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: the top-level binding (with
-    check_vma) only exists on newer jax; older releases ship it as
-    jax.experimental.shard_map.shard_map (with check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+def _sharded_grower(mesh, cfg, row_axis, state_spec, scatter, quantized):
+    """`jax.jit(jax.shard_map(grow_tree))` for one operand layout. After
+    the six leading operands come, in order: the scatter schedule's
+    replicated owned-feature table (each shard dynamic-indexes its own
+    row — multihost-safe), the quantized path's [3] dequant scale, the 7
+    fmeta arrays; the f32 dispatch thus keeps its own signature and
+    program. Callers build this ONCE per (cfg, layout) and keep it: an
+    eager shard_map rebuilt from a fresh closure re-traces and
+    re-compiles the whole grow program on every dispatch."""
+    def body(b, g, h, w, fm, nv, *rest):
+        rest = list(rest)
+        of = rest.pop(0) if scatter else None
+        qs = rest.pop(0) if quantized else None
+        return grow_tree(b, g, h, w, fm, *rest, cfg, n_valid=nv,
+                         owned_feats=of, qscale=qs)
+
+    extra = ((P(None, None),) if scatter else ()) \
+        + ((P(None),) if quantized else ())
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(row_axis, None), P(row_axis), P(row_axis), P(row_axis),
+                  P(None), P()) + extra + (P(None),) * 7,
+        out_specs=state_spec, check_vma=False))
 
 
 def make_mesh(num_devices: Optional[int] = None, axis_name: str = "data",
@@ -97,6 +110,8 @@ class DataParallelGrower:
         self._global_binned = None
         self._global_binned_id = None
         self._calls = 0
+        # (cfg, scatter, quantized) -> jitted shard_map (_sharded_grower)
+        self._programs: Dict = {}
         # scatter prep cache: (id(binned) -> padded binned), owned table
         self._scatter_binned = None
         self._scatter_binned_id = None
@@ -191,7 +206,6 @@ class DataParallelGrower:
         owned_feats = None
         if self.cfg.hist_scatter:
             binned, owned_feats = self._scatter_prep(binned, fmeta)
-        cfg = self.cfg
         ax = self.axis
         # multi-host: inputs arrive as THIS PROCESS's row shard — assemble
         # the global row axis (each host contributes its loader partition,
@@ -216,62 +230,30 @@ class DataParallelGrower:
             if needs_assembly(row_weight):
                 row_weight = global_row_array(np.asarray(row_weight),
                                               self.mesh, ax)
-        # out_specs: leaf_id stays sharded by rows; everything else is
-        # replicated (identical on all shards by construction)
-        state_spec = self._state_specs()
+        else:
+            # hand the row vectors over in the sharding the program
+            # declares (a no-op once they are): the first tree's inputs
+            # are unplaced, later ones derive from the sharded leaf ids,
+            # and jit would compile the grower once for each layout
+            grad, hess, row_weight = jax.device_put(
+                (grad, hess, row_weight), NamedSharding(self.mesh, P(ax)))
         from ..learner.grow import FMETA_KEYS
         # n_valid=None means "all rows real" — identical to the padded
-        # row count, so one shard_map signature serves both
+        # row count, so one program signature serves both
         if n_valid is None:
             n_valid = binned.shape[0]
-        # quantized-gradient mode: the [3] dequant scale rides replicated
-        # as an EXTRA trailing operand — the f32 dispatch keeps its
-        # existing signature (and compiled program) untouched
-        if owned_feats is None:
-            if qscale is None:
-                run = shard_map_compat(
-                    lambda b, g, h, w, fm, nv, *meta: grow_tree(
-                        b, g, h, w, fm, *meta, cfg, n_valid=nv),
-                    mesh=self.mesh,
-                    in_specs=(P(ax, None), P(ax), P(ax), P(ax), P(None),
-                              P()) + (P(None),) * 7,
-                    out_specs=state_spec)
-                return run(binned, grad, hess, row_weight, feature_mask,
-                           jnp.int32(n_valid),
-                           *[fmeta[k] for k in FMETA_KEYS])
-            run = shard_map_compat(
-                lambda b, g, h, w, fm, nv, qs, *meta: grow_tree(
-                    b, g, h, w, fm, *meta, cfg, n_valid=nv, qscale=qs),
-                mesh=self.mesh,
-                in_specs=(P(ax, None), P(ax), P(ax), P(ax), P(None), P(),
-                          P(None)) + (P(None),) * 7,
-                out_specs=state_spec)
-            return run(binned, grad, hess, row_weight, feature_mask,
-                       jnp.int32(n_valid), qscale,
-                       *[fmeta[k] for k in FMETA_KEYS])
-        # scatter schedule: the owned-feature table rides replicated and
-        # each shard dynamic-indexes its own row (multihost-safe)
-        if qscale is None:
-            run = shard_map_compat(
-                lambda b, g, h, w, fm, nv, of, *meta: grow_tree(
-                    b, g, h, w, fm, *meta, cfg, n_valid=nv, owned_feats=of),
-                mesh=self.mesh,
-                in_specs=(P(ax, None), P(ax), P(ax), P(ax), P(None), P(),
-                          P(None, None)) + (P(None),) * 7,
-                out_specs=state_spec)
-            return run(binned, grad, hess, row_weight, feature_mask,
-                       jnp.int32(n_valid), owned_feats,
-                       *[fmeta[k] for k in FMETA_KEYS])
-        run = shard_map_compat(
-            lambda b, g, h, w, fm, nv, of, qs, *meta: grow_tree(
-                b, g, h, w, fm, *meta, cfg, n_valid=nv, owned_feats=of,
-                qscale=qs),
-            mesh=self.mesh,
-            in_specs=(P(ax, None), P(ax), P(ax), P(ax), P(None), P(),
-                      P(None, None), P(None)) + (P(None),) * 7,
-            out_specs=state_spec)
+        scatter, quantized = owned_feats is not None, qscale is not None
+        key = (self.cfg, scatter, quantized)
+        run = self._programs.get(key)
+        if run is None:
+            # out_specs: leaf_id stays sharded by rows; everything else
+            # is replicated (identical on all shards by construction)
+            run = self._programs[key] = _sharded_grower(
+                self.mesh, self.cfg, self.axis, self._state_specs(),
+                scatter, quantized)
+        extra = [a for a in (owned_feats, qscale) if a is not None]
         return run(binned, grad, hess, row_weight, feature_mask,
-                   jnp.int32(n_valid), owned_feats, qscale,
+                   jnp.int32(n_valid), *extra,
                    *[fmeta[k] for k in FMETA_KEYS])
 
     def _state_specs(self):
@@ -293,6 +275,9 @@ class FeatureParallelGrower:
         self.nshards = mesh.shape[axis]
         self.cfg = cfg._replace(feature_axis=axis,
                                 num_feature_shards=self.nshards)
+        self._calls = 0
+        # (cfg, quantized) -> jitted shard_map (_sharded_grower)
+        self._programs: Dict = {}
 
     def pad_features(self, binned: np.ndarray, fmeta: Dict):
         """Pad the feature dimension to a multiple of the shard count with
@@ -317,7 +302,7 @@ class FeatureParallelGrower:
 
     def __call__(self, binned, grad, hess, row_weight, feature_mask, fmeta,
                  n_valid=None, qscale=None):
-        self._calls = getattr(self, "_calls", 0) + 1
+        self._calls += 1
         with watchdog.deadline("collective.dispatch",
                                iteration=self._calls):
             return self._dispatch(binned, grad, hess, row_weight,
@@ -328,32 +313,21 @@ class FeatureParallelGrower:
         faults.inject("collective.call")
         telemetry.heartbeat(self._calls, phase="grower_dispatch")
         telemetry.counter_add("parallel/grower_calls", 1)
-        cfg = self.cfg
-        ax = self.axis
         from ..learner.grow import FMETA_KEYS, TreeGrowerState
-        fields = {name: P() for name in TreeGrowerState._fields}
-        state_spec = TreeGrowerState(**fields)
         if n_valid is None:
             n_valid = binned.shape[0]
-        if qscale is None:
-            run = shard_map_compat(
-                lambda b, g, h, w, fm, nv, *meta: grow_tree(
-                    b, g, h, w, fm, *meta, cfg, n_valid=nv),
-                mesh=self.mesh,
-                in_specs=(P(None, None), P(None), P(None), P(None), P(None),
-                          P()) + (P(None),) * 7,
-                out_specs=state_spec)
-            return run(binned, grad, hess, row_weight, feature_mask,
-                       jnp.int32(n_valid), *[fmeta[k] for k in FMETA_KEYS])
-        run = shard_map_compat(
-            lambda b, g, h, w, fm, nv, qs, *meta: grow_tree(
-                b, g, h, w, fm, *meta, cfg, n_valid=nv, qscale=qs),
-            mesh=self.mesh,
-            in_specs=(P(None, None), P(None), P(None), P(None), P(None),
-                      P(), P(None)) + (P(None),) * 7,
-            out_specs=state_spec)
+        quantized = qscale is not None
+        key = (self.cfg, quantized)
+        run = self._programs.get(key)
+        if run is None:
+            state_spec = TreeGrowerState(
+                **{name: P() for name in TreeGrowerState._fields})
+            run = self._programs[key] = _sharded_grower(
+                self.mesh, self.cfg, None, state_spec,
+                scatter=False, quantized=quantized)
+        extra = [] if qscale is None else [qscale]
         return run(binned, grad, hess, row_weight, feature_mask,
-                   jnp.int32(n_valid), qscale,
+                   jnp.int32(n_valid), *extra,
                    *[fmeta[k] for k in FMETA_KEYS])
 
 

@@ -376,28 +376,37 @@ _COMPILE_CACHE_LOCK = threading.Lock()
 
 
 def enable_compile_cache(path: str) -> bool:
-    """Point JAX's persistent compilation cache at `path`
-    (`tpu_compile_cache_dir`): every program the shape-bucket ladder
-    compiles is written to disk, and a RESTARTED replica's warmup()
-    loads the same ladder back instead of re-tracing it — the
-    29-81s wide-shape cold start becomes a file read. Thresholds are
-    dropped to zero so even small bucket programs persist (the default
-    1s floor would skip exactly the small-batch programs a serving
-    replica warms first). Idempotent per path; returns False when the
-    cache could not be armed (best-effort, serving proceeds without
-    it)."""
+    """Arm JAX's persistent compilation cache for a booster or predictor
+    that names `tpu_compile_cache_dir`: every program the shape-bucket
+    ladder compiles is written to disk, and a RESTARTED replica's
+    warmup() loads the same ladder back instead of re-tracing it. The
+    persistence thresholds are dropped to zero so even small bucket
+    programs persist (the default 1s floor would skip exactly the
+    small-batch programs a serving replica warms first).
+
+    WHERE the cache lives follows the package rule
+    (lightgbm_tpu/__init__.py): with JAX_COMPILATION_CACHE_DIR set the
+    environment wins — the directory is left alone and that is logged
+    once; otherwise the cache is re-pointed at `path`. Idempotent per
+    path; returns False when the cache could not be armed (a warning
+    names the path and the error; serving proceeds uncached)."""
     global _COMPILE_CACHE_ARMED
-    path = os.path.abspath(path)
+    from .. import compile_cache_dir_from_env, log
+    env_dir = compile_cache_dir_from_env()
+    path = env_dir or os.path.abspath(path)
     with _COMPILE_CACHE_LOCK:
         if _COMPILE_CACHE_ARMED == path:
             return True
-        if _COMPILE_CACHE_ARMED is not None:
+        if env_dir:
+            log.info("tpu_compile_cache_dir is ignored: "
+                     "JAX_COMPILATION_CACHE_DIR=%s places the persistent "
+                     "compile cache", env_dir)
+        elif _COMPILE_CACHE_ARMED is not None:
             # the cache is PROCESS-GLOBAL (one jax config): two
             # resident models naming different dirs cannot each get
             # their own — the flip is honored but loudly, because the
             # earlier model's future compiles now persist to the new
             # path and its restarted replicas will find a cold cache
-            from .. import log
             log.warning(
                 "tpu_compile_cache_dir is process-global: re-pointing "
                 "the persistent compile cache from %s to %s (programs "
@@ -405,22 +414,20 @@ def enable_compile_cache(path: str) -> bool:
                 _COMPILE_CACHE_ARMED, path)
         try:
             import jax
-            jax.config.update("jax_compilation_cache_dir", path)
             jax.config.update("jax_persistent_cache_min_compile_time_secs",
                               0.0)
             jax.config.update("jax_persistent_cache_min_entry_size_bytes",
                               -1)
-            try:
+            if not env_dir:
                 # a cache already initialized at another dir (the
-                # package-level default) must be re-pointed, not ignored
-                from jax._src import compilation_cache as _cc
-                _cc.reset_cache()
-            except Exception:  # pragma: no cover — jax-version-specific
-                pass
-        except Exception as exc:  # pragma: no cover — cache best-effort
-            from .. import log
-            log.warning("tpu_compile_cache_dir=%s could not be armed: %s",
-                        path, exc)
+                # package default) must be re-pointed, not ignored
+                from jax.experimental.compilation_cache import \
+                    compilation_cache as cc
+                cc.set_cache_dir(path)
+                cc.reset_cache()
+        except Exception as exc:
+            log.warning("persistent compile cache at %s could not be "
+                        "armed: %r", path, exc)
             return False
         _COMPILE_CACHE_ARMED = path
     return True

@@ -163,17 +163,16 @@ def _versions() -> Dict[str, str]:
 
 def _device_topology() -> Dict[str, Any]:
     """Backend topology for the header (the backend is already up by the
-    time training telemetry starts — booster init touched devices)."""
-    try:
-        import jax
-        devs = jax.devices()
-        return {"platform": devs[0].platform if devs else "none",
-                "num_devices": len(devs),
-                "num_processes": jax.process_count(),
-                "local_devices": len(jax.local_devices())}
-    except Exception:  # pragma: no cover — headless schema tests
-        return {"platform": "unknown", "num_devices": 0,
-                "num_processes": 1, "local_devices": 0}
+    time training telemetry starts — booster init touched devices). A
+    backend error propagates: a run log must never record a run whose
+    device it could not name."""
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "num_devices": len(devs),
+            "num_processes": jax.process_count(),
+            "local_devices": len(jax.local_devices())}
 
 
 class TrainRecorder:

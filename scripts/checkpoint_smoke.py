@@ -7,6 +7,9 @@ finally-blocks, exactly what a preempted pod looks like — then reruns
 the identical command and asserts the resumed run's model is
 byte-identical to an uninterrupted one.
 
+CPU gate: every CLI child runs with JAX_PLATFORMS=cpu; it checks
+byte-identity, not device speed, and needs no chip.
+
 Usage: python scripts/checkpoint_smoke.py
 Exits 0 on success, 1 on any mismatch.
 """

@@ -38,6 +38,10 @@ byte-identity verdict.
 Usage:
     python scripts/elastic_smoke.py [--rounds 12] [--mode devices]
         [--out ELASTIC_r01.json] [--timeout 240] [--max-retries 2]
+
+CPU gate: this script and every child it starts run on the CPU platform
+(JAX_PLATFORMS=cpu); it checks behaviour, not device speed, and needs no
+chip — no parent here holds a chip that a child then needs.
 """
 from __future__ import annotations
 

@@ -24,6 +24,10 @@ BENCH_SHAPE=export):
 Usage: python scripts/export_smoke.py [--out EXPORT_r01.json]
 Exits nonzero on any gate failure; prints one machine-readable JSON
 line per phase plus a final summary line.
+
+CPU gate: this script and every child it starts run on the CPU platform
+(JAX_PLATFORMS=cpu); it checks behaviour, not device speed, and needs no
+chip — no parent here holds a chip that a child then needs.
 """
 from __future__ import annotations
 
@@ -34,7 +38,7 @@ import subprocess
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)

@@ -207,8 +207,11 @@ DESCRIPTIONS = {
                              "directory: bucket-ladder and grower "
                              "programs persist to disk so restarted "
                              "trainers / cold serving replicas warm "
-                             "from a file read instead of re-tracing "
-                             "(empty = package default)",
+                             "from a file read instead of re-tracing. "
+                             "The environment variable "
+                             "JAX_COMPILATION_CACHE_DIR, when set, wins "
+                             "over this parameter (empty = package "
+                             "default, <checkout>/.jax_cache)",
     "tpu_predict_warmup_rows": "Predictor.warmup() compiles bucket "
                                "programs up to this many rows",
     "tpu_predict_micro_batch": "max concurrent single-row requests "

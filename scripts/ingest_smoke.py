@@ -22,6 +22,10 @@ the cap.
 
 Usage: python scripts/ingest_smoke.py
 Env: SMOKE_ROWS (default 600000), SMOKE_FEATURES (40), SMOKE_ITERS (3).
+
+CPU gate: this script and every child it starts run on the CPU platform
+(JAX_PLATFORMS=cpu); it checks behaviour, not device speed, and needs no
+chip — no parent here holds a chip that a child then needs.
 """
 from __future__ import annotations
 

@@ -21,7 +21,7 @@ for the higgs shape: this box may expose fewer cores than the reference's
 benchmark setup (docs/GPU-Performance.md:96-116 used 28 threads), and an
 undersized baseline would flatter vs_baseline. REFERENCE_8T_FLOOR is the
 8-thread measurement of this exact workload recorded in round 1's review
-(VERDICT.md: 20.2 s train on 500k x 28 x 20 iters = 0.495 mrow_iters/s).
+(20.2 s train on 500k x 28 x 20 iters = 0.495 mrow_iters/s).
 Other shapes record the raw measurement (threads = all visible cores).
 
 MUST run on an otherwise-idle machine: this box exposes ONE cpu to the

@@ -1,4 +1,4 @@
-"""Throughput sweep for PARITY.md: ours (TPU) vs the reference binary
+"""Throughput sweep (PARITY_SWEEP.json): ours vs the reference binary
 across row scales, plus a 500-iteration amortized point and a lambdarank
 ranking point.
 
@@ -247,9 +247,7 @@ def ours_predict(rows=500_000, trees=100):
     from lightgbm_tpu.cli import main as cli_main
     walls = []
     # 1 cold (jit compile) + 5 warm; the committed figure is the warm
-    # MEDIAN (round-4 verdict: the single-shot number swung 2x with
-    # relay session noise and the committed artifact landed on the bad
-    # end)
+    # MEDIAN (a single shot swung 2x between sessions)
     for _ in range(6):
         t0 = time.time()
         cli_main([f"task=predict", f"data={data_path}",

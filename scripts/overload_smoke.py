@@ -29,6 +29,10 @@ BENCH_SHAPE=overload):
 Usage: python scripts/overload_smoke.py [--out OVERLOAD_r01.json]
 Exits nonzero on any gate failure; prints one machine-readable JSON
 line per phase plus a final summary line.
+
+CPU gate: this script and every child it starts run on the CPU platform
+(JAX_PLATFORMS=cpu); it checks behaviour, not device speed, and needs no
+chip — no parent here holds a chip that a child then needs.
 """
 from __future__ import annotations
 
@@ -40,7 +44,7 @@ import sys
 import threading
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -341,11 +345,15 @@ def _cold_child(cache_dir: str) -> None:
 
 def phase_cold_start() -> dict:
     import tempfile
+    # a scratch cache for the cold-vs-warm replica pair, placed the way
+    # a deployment places one — from outside, by the standard variable,
+    # which wins over any default (lightgbm_tpu/__init__.py). The
+    # replicas still name tpu_compile_cache_dir: that drops the
+    # persistence floor so the small bucket programs persist.
     cache_dir = tempfile.mkdtemp(prefix="lgbm_tpu_overload_cc_")
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    # the package-level default cache would mask the param under test
-    env["LIGHTGBM_TPU_COMPILE_CACHE"] = "0"
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     runs = []
     for i in range(2):
         res = subprocess.run(

@@ -19,6 +19,10 @@ BEFORE the swap, so swap-time traffic never retraces).
 
 Usage: python scripts/predict_latency_smoke.py
 Exits nonzero on regression; prints one machine-readable JSON line.
+
+CPU gate: this script and every child it starts run on the CPU platform
+(JAX_PLATFORMS=cpu); it checks behaviour, not device speed, and needs no
+chip — no parent here holds a chip that a child then needs.
 """
 from __future__ import annotations
 
@@ -28,7 +32,7 @@ import sys
 import threading
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)

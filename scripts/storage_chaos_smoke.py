@@ -30,6 +30,10 @@ Writes a machine-readable artifact (CHAOS_r01.json).
 Usage:
     python scripts/storage_chaos_smoke.py [--rounds 8]
         [--out CHAOS_r01.json] [--timeout 240]
+
+CPU gate: this script and every child it starts run on the CPU platform
+(JAX_PLATFORMS=cpu); it checks behaviour, not device speed, and needs no
+chip — no parent here holds a chip that a child then needs.
 """
 from __future__ import annotations
 
@@ -72,7 +76,7 @@ print("CHAOS_REPORT " + json.dumps({{
 
 HATCH_CHILD = r"""
 import json, os, sys
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, {repo!r})
 from lightgbm_tpu import durable
 from lightgbm_tpu.checkpoint import CheckpointManager

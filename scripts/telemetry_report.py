@@ -66,6 +66,7 @@ def digest(records):
             "run_id": hdr.get("run_id"),
             "rank": hdr.get("rank"),
             "platform": (hdr.get("devices") or {}).get("platform"),
+            "device_kind": (hdr.get("devices") or {}).get("device_kind"),
             "num_devices": (hdr.get("devices") or {}).get("num_devices"),
             "boosting": hdr.get("boosting"),
             "start_iteration": hdr.get("start_iteration"),

@@ -12,24 +12,6 @@ from lightgbm_tpu.learner.grow import GrowerConfig, grow_tree
 from lightgbm_tpu.parallel import (DataParallelGrower, FeatureParallelGrower,
                                    VotingParallelGrower, make_mesh)
 
-# pre-0.5 jax has no top-level jax.shard_map; the library routes through
-# parallel.learners.shard_map_compat (jax.experimental.shard_map), which
-# the multi-chip dryrun gate exercises end-to-end every round — but under
-# the legacy entry point these 8-virtual-device CPU grower compiles take
-# minutes each and blow the tier-1 wall budget, so by default the
-# identity sweep runs only on jax versions with the native binding. Set
-# LGBM_TPU_RUN_LEGACY_DISTRIBUTED=1 to run it on legacy jax anyway
-# (budget permitting) and cover the check_rep fallback branch in pytest.
-import os as _os
-
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax, "shard_map")
-    and not _os.environ.get("LGBM_TPU_RUN_LEGACY_DISTRIBUTED"),
-    reason="legacy jax.experimental.shard_map compiles too slowly on the "
-           "virtual-device CPU mesh for the tier-1 budget (library path "
-           "covered by shard_map_compat + the dryrun_multichip gate; "
-           "set LGBM_TPU_RUN_LEGACY_DISTRIBUTED=1 to run)")
-
 
 @pytest.fixture(scope="module")
 def problem():

@@ -630,8 +630,15 @@ def test_warmup_marks_ladder_no_single_flight(base):
     assert p._single_flight.counts["waits"] == 0
 
 
-def test_compile_cache_param_arms_jax_config(base, tmp_path):
+def test_compile_cache_param_arms_jax_config(base, tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR unset, `tpu_compile_cache_dir`
+    re-points the cache (tests/test_chip_smoke.py covers the case where
+    the environment wins)."""
+    import os
+
     import jax
+    from lightgbm_tpu.serving.forest import enable_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     X, b = base
     cache_dir = str(tmp_path / "cc")
     prev = jax.config.jax_compilation_cache_dir
@@ -639,12 +646,10 @@ def test_compile_cache_param_arms_jax_config(base, tmp_path):
         p = Predictor(_serving_clone(b, tpu_compile_cache_dir=cache_dir))
         assert jax.config.jax_compilation_cache_dir == cache_dir
         p.warmup(max_rows=16)
-        import os
         assert os.path.isdir(cache_dir) and os.listdir(cache_dir), \
             "warmup wrote no programs to the persistent cache"
     finally:
         if prev is not None:
-            from lightgbm_tpu.serving.forest import enable_compile_cache
             enable_compile_cache(prev)
 
 

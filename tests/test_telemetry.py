@@ -235,6 +235,8 @@ def test_train_run_emits_schema_valid_log(tmp_path):
     assert iters[0]["compile"]["compiles"] > 0  # first iter compiles
     hdr = recs[0]
     assert hdr["devices"]["platform"] == "cpu"
+    import jax
+    assert hdr["devices"]["device_kind"] == jax.devices()[0].device_kind
     assert hdr["schedule"]["grower"]["num_leaves"] == 31
     # Prometheus exposition written alongside
     prom = os.path.join(td, "metrics_r0.prom")

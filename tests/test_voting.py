@@ -153,12 +153,11 @@ def test_voting_psum_operand_is_elected_slice(problem):
     state_spec = TreeGrowerState(
         **{name: (P("data") if name == "leaf_id" else P())
            for name in TreeGrowerState._fields})
-    from lightgbm_tpu.parallel.learners import shard_map_compat
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P("data", None), P("data"), P("data"), P("data"), P(None))
                  + (P(None),) * 7,
-        out_specs=state_spec)
+        out_specs=state_spec, check_vma=False)
     jaxpr = jax.make_jaxpr(sharded)(
         jnp.asarray(ds.binned), jnp.asarray(grad), jnp.asarray(hess),
         jnp.ones(n, jnp.float32), jnp.ones(ds.num_features, bool),
