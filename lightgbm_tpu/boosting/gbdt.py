@@ -13,16 +13,18 @@ from __future__ import annotations
 import contextlib
 import copy
 import functools
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from .. import checkpoint as ckpt
 from .. import log
+from .. import telemetry
 from ..testing import faults
 from ..config import Config
 from ..dataset import Dataset, Metadata
-from ..learner.grow import GrowerConfig, grow_tree
+from ..learner.grow import GrowerConfig, compact_capacity, grow_tree
 from ..metrics import Metric, create_metric, default_metric_for_objective
 from ..objectives import ObjectiveFunction
 from ..ops.predict import predict_leaf_binned, predict_value_binned
@@ -130,6 +132,7 @@ def _is_plain(v) -> bool:
 def _jit_gradients(obj, arr_keys):
     import jax
 
+    @telemetry.scope("lgbm/gradients")
     def f(s, arrs):
         with objective_arrays_swapped(obj, arr_keys, arrs):
             return obj.get_gradients(s.reshape(-1))
@@ -223,12 +226,13 @@ def _grow_and_update_impl(score, binned, grad, hess, row_weight, fmask,
 
     state = grow_tree(binned, grad, hess, row_weight, fmask, *fmeta_args,
                       cfg, n_valid=n_valid, qscale=qscale)
-    grew = state.num_leaves_used > 1
-    leaf_vals = state.leaf_value * shrinkage
-    delta = jnp.where(
-        grew,
-        leaf_vals[jnp.clip(state.leaf_id, 0, cfg.num_leaves - 1)], 0.0)
-    score = score.at[cls].add(delta)
+    with telemetry.scope("lgbm/score/update"):
+        grew = state.num_leaves_used > 1
+        leaf_vals = state.leaf_value * shrinkage
+        delta = jnp.where(
+            grew,
+            leaf_vals[jnp.clip(state.leaf_id, 0, cfg.num_leaves - 1)], 0.0)
+        score = score.at[cls].add(delta)
     small = {k: getattr(state, k) for k in _SMALL_STATE_KEYS}
     return score, small
 
@@ -324,10 +328,12 @@ def _grow_and_update_multi_impl(score, binned, grads, hesses, row_weight,
         return jnp.where(grew,
                          vals[jnp.clip(lid, 0, cfg.num_leaves - 1)], 0.0)
 
-    delta = jax.vmap(upd)(state.leaf_value, state.leaf_id,
-                          state.num_leaves_used > 1)
+    with telemetry.scope("lgbm/score/update"):
+        delta = jax.vmap(upd)(state.leaf_value, state.leaf_id,
+                              state.num_leaves_used > 1)
+        score = score + delta
     small = {k: getattr(state, k) for k in _SMALL_STATE_KEYS}
-    return score + delta, small
+    return score, small
 
 
 def _grow_and_update_multi(score, binned, grads, hesses, row_weight, fmasks,
@@ -564,7 +570,6 @@ class GBDT:
         # the deadline guard when tpu_collective_timeout_s is set
         import os as _os
 
-        from .. import telemetry
         from ..parallel import watchdog
         net = self.config.network
         rank = jax.process_index()
@@ -1046,7 +1051,6 @@ class GBDT:
         import jax
         import jax.numpy as jnp
 
-        from .. import telemetry, tracing
         from ..learner.grow import FMETA_KEYS
         from ..ops.histogram import quantize_gradients, train_qmax
 
@@ -1090,7 +1094,7 @@ class GBDT:
         scale = max(float(jnp.max(jnp.abs(lv_f))), 1.0)
         delta = float(jnp.max(jnp.abs(vq[:n_cal] - vf[:n_cal]))) / scale
         telemetry.gauge_set("train/hist_quantize_gate_delta", delta)
-        tracing.counter("train/hist_quantize_gate_runs", 1)
+        telemetry.counter_add("train/hist_quantize_gate_runs", 1)
         log.debug("Hist-quantize gate (%s, qmax=%d): relative leaf-value "
                   "delta %.3g on %d calibration rows", mode, qmax, delta,
                   n_cal)
@@ -1181,8 +1185,7 @@ class GBDT:
             self._bag_cache = _bagging_mask_device(
                 self.config.boosting.bagging_seed, iter_idx // freq,
                 self._n, self._n_pad, bf)
-            from .. import tracing
-            tracing.counter("boosting/bagging_refresh", 1)
+            telemetry.counter_add("boosting/bagging_refresh", 1)
         return self._bag_cache
 
     def _row_weight_from_bag(self, bag):
@@ -1242,17 +1245,16 @@ class GBDT:
         faults.inject("backend.grow")
         import jax.numpy as jnp
 
-        from .. import tracing
-
+        t_enter = time.perf_counter()
+        it = self.iter_
         k = self.num_tree_per_iteration
         n_pad = self._n_pad
         if gradients is None or hessians is None:
             if self.objective is None:
                 log.fatal("Custom objective training requires explicit "
                           "gradients and hessians")
-            with tracing.phase("boosting/gradients"):
+            with telemetry.span("lgbm/iter/gradients", iteration=it):
                 grad, hess = self._compute_gradients(self._score)
-                tracing.block(grad)
         else:
             grad = jnp.asarray(np.asarray(gradients, np.float32).reshape(k, -1))
             hess = jnp.asarray(np.asarray(hessians, np.float32).reshape(k, -1))
@@ -1265,7 +1267,7 @@ class GBDT:
         hess = hess.reshape(k, n_pad)
         probe = self._nonfinite_probe(grad, hess)
 
-        with tracing.phase("boosting/bagging"):
+        with telemetry.span("lgbm/iter/bagging", iteration=it):
             bag = self._bagging_weights(self.iter_, grad, hess)
             row_weight = self._row_weight_from_bag(bag)
 
@@ -1276,7 +1278,7 @@ class GBDT:
         grad_f32, hess_f32, row_weight_f32 = grad, hess, row_weight
         qscales = None
         if getattr(self, "_quant_mode", "none") != "none":
-            with tracing.phase("boosting/quantize"):
+            with telemetry.span("boosting/quantize"):
                 grad, hess, row_weight, qscales = _quantize_iter_device(
                     grad, hess, row_weight, self.iter_,
                     seed=self._quant_seed, n=self._n,
@@ -1298,7 +1300,7 @@ class GBDT:
                 and getattr(self, "_supports_pipeline", True)
                 and not os.environ.get("LGBM_TPU_NO_PIPELINE")):
             return self._train_one_iter_pipelined(grad, hess, row_weight,
-                                                  probe, qscales)
+                                                  probe, qscales, t_enter)
         self._raise_if_nonfinite(probe, self.iter_)
 
         # leaving the pipelined path (explicit gradients, a valid set
@@ -1308,6 +1310,7 @@ class GBDT:
 
         could_split_any = False
         for cls in range(k):
+            t_cls = t_enter if cls == 0 else time.perf_counter()
             mask = self._feature_mask()
             qs = None if qscales is None else qscales[cls]
             if getattr(self, "_linear", False):
@@ -1316,10 +1319,9 @@ class GBDT:
                 # replaces the constant leaf outputs with fitted
                 # intercept+slopes and returns the per-row training
                 # values (pre-shrinkage) for the score update
-                with tracing.phase("tree/grow"):
+                with telemetry.span("lgbm/iter/dispatch", iteration=it):
                     state = self._grow(grad[cls], hess[cls], row_weight,
                                        mask, qscale=qs)
-                with tracing.phase("tree/linear_fit"):
                     # the leaf regression consumes the RAW f32 moments:
                     # quantization narrows the HISTOGRAM path only, the
                     # fitted intercept/slope normal equations stay exact
@@ -1328,54 +1330,40 @@ class GBDT:
                         row_weight_f32, state,
                         self.config.tree.linear_lambda,
                         self._grower_cfg, self._linear_k)
-                with tracing.phase("tree/extract"):
-                    small = {key: getattr(state, key)
-                             for key in _SMALL_STATE_KEYS}
-                    small["leaf_value"] = leaf_value
-                    small["leaf_coeff"] = leaf_coeff
-                    small["leaf_features_inner"] = feats
-                    host_state = _HostState(jax.device_get(small))
-                    tree = Tree.from_grower_state(host_state,
-                                                  self.train_data)
-                self._log_pass_economics(host_state)
+                small = {key: getattr(state, key)
+                         for key in _SMALL_STATE_KEYS}
+                small["leaf_value"] = leaf_value
+                small["leaf_coeff"] = leaf_coeff
+                small["leaf_features_inner"] = feats
+                tree, timing = self._fetch_tree(
+                    small, it, time.perf_counter() - t_cls)
                 if tree.num_leaves > 1:
-                    tree.apply_shrinkage(self.shrinkage_rate)
-                    with tracing.phase("boosting/update_score"):
-                        self._score = self._score.at[cls].add(
-                            jnp.float32(self.shrinkage_rate) * vals)
+                    self._score = self._score.at[cls].add(
+                        jnp.float32(self.shrinkage_rate) * vals)
             elif self._dist_grower is None:
                 # serial learner: grow + score update as ONE device
                 # program, then ONE host fetch of the small tree arrays
-                with tracing.phase("tree/grow"):
+                with telemetry.span("lgbm/iter/dispatch", iteration=it):
                     self._score, small = _grow_and_update(
                         self._score, self._binned, grad[cls], hess[cls],
                         row_weight, jnp.asarray(mask), self.shrinkage_rate,
                         self._n,
                         [self._fmeta[key] for key in FMETA_KEYS], cls,
                         self._grower_cfg, qscale=qs)
-                with tracing.phase("tree/extract"):
-                    host_state = _HostState(jax.device_get(small))
-                    tree = Tree.from_grower_state(host_state,
-                                                  self.train_data)
-                self._log_pass_economics(host_state)
-                if tree.num_leaves > 1:
-                    tree.apply_shrinkage(self.shrinkage_rate)
+                tree, timing = self._fetch_tree(
+                    small, it, time.perf_counter() - t_cls)
             else:
-                with tracing.phase("tree/grow"):
+                with telemetry.span("lgbm/iter/dispatch", iteration=it):
                     state = self._grow(grad[cls], hess[cls], row_weight,
                                        mask, qscale=qs)
-                with tracing.phase("tree/extract"):
-                    small = {key: getattr(state, key)
-                             for key in _SMALL_STATE_KEYS}
-                    host_state = _HostState(jax.device_get(small))
-                    tree = Tree.from_grower_state(host_state,
-                                                  self.train_data)
-                self._log_pass_economics(host_state)
+                small = {key: getattr(state, key)
+                         for key in _SMALL_STATE_KEYS}
+                tree, timing = self._fetch_tree(
+                    small, it, time.perf_counter() - t_cls)
                 if tree.num_leaves > 1:
-                    tree.apply_shrinkage(self.shrinkage_rate)
                     # train score update via leaf ids (UpdateScore,
                     # gbdt.cpp:521)
-                    with tracing.phase("boosting/update_score"):
+                    with telemetry.span("boosting/update_score"):
                         # padded to the configured leaf count: a table
                         # sized by THIS tree's leaves would compile a new
                         # gather for every distinct tree size
@@ -1402,11 +1390,12 @@ class GBDT:
                     self._pending_bias = 0.0
                     self.init_score_bias = 0.0
             self.models.append(tree)
+            self._log_pass_economics(*timing)
 
         return self._finish_iter(could_split_any)
 
     def _train_one_iter_pipelined(self, grad, hess, row_weight,
-                                  probe=None, qscales=None) -> bool:
+                                  probe, qscales, t_enter) -> bool:
         """Serial-learner iteration with the tree fetch pipelined one
         iteration behind the device dispatch (see __init__ note). The
         stop/rollback decision therefore lags one iteration: a
@@ -1417,7 +1406,6 @@ class GBDT:
         by finalize_training()."""
         import jax.numpy as jnp
 
-        from .. import tracing
         from ..learner.grow import FMETA_KEYS
 
         if getattr(self, "_stopped", False):
@@ -1428,20 +1416,23 @@ class GBDT:
             self._stopped = False
             return True
         mask = self._feature_mask()
-        with tracing.phase("tree/grow"):
+        with telemetry.span("lgbm/iter/dispatch", iteration=self.iter_):
             self._score, small = _grow_and_update(
                 self._score, self._binned, grad[0], hess[0],
                 row_weight, jnp.asarray(mask), self.shrinkage_rate,
                 self._n, [self._fmeta[key] for key in FMETA_KEYS], 0,
                 self._grower_cfg,
                 qscale=None if qscales is None else qscales[0])
+        dispatch_s = time.perf_counter() - t_enter
         # fetch + build the PREVIOUS tree while this one runs on device
         ok_prev = self._flush_pending()
         # stash the DISPATCH-TIME shrinkage (a learning-rate schedule
         # changes self.shrinkage_rate before the flush happens one
-        # iteration later) and the dispatch-time non-finite probe and
-        # iteration index, fetched together with the small tree arrays
-        self._pending_small = (small, self.shrinkage_rate, probe, self.iter_)
+        # iteration later), the dispatch-time non-finite probe and
+        # iteration index, fetched together with the small tree arrays,
+        # and the host seconds this call took to enqueue the tree
+        self._pending_small = (small, self.shrinkage_rate, probe, self.iter_,
+                               dispatch_s)
         self.iter_ += 1
         if not ok_prev:
             # previous iteration produced no split: unwind the
@@ -1451,11 +1442,12 @@ class GBDT:
             # score, so roll it back the way rollback_one_iter does —
             # materialize and subtract its traversal values — instead of
             # assuming the delta was zero.
-            small, shrink, probe, it = self._pending_small
+            small, shrink, probe, it, dispatch_s = self._pending_small
             self._pending_small = None
             self._raise_if_nonfinite(probe, it)
             self.iter_ -= 1
-            tree = self._materialize_small(small, shrink, fold_bias=False)
+            tree, timing = self._fetch_tree(small, it, dispatch_s, shrink)
+            self._log_pass_economics(*timing)
             if tree.num_leaves > 1:
                 neg = copy.deepcopy(tree)
                 neg.leaf_value = -neg.leaf_value
@@ -1469,63 +1461,93 @@ class GBDT:
             return True
         return False
 
-    def _materialize_small(self, small, shrink, fold_bias=True):
-        """Device small-state -> host Tree (+ shrinkage and, for kept
-        trees, the one-time boost-from-average bias fold) — the single
-        copy both the pipelined flush and its rollback path use."""
+    def _fetch_tree(self, small, iteration, dispatch_s, shrink=None,
+                    fold_bias=False):
+        """Device small-state -> host Tree with its shrinkage (the
+        dispatch-time one where the pipelined path stashed it) and,
+        where asked, the one-time boost-from-average bias fold — the
+        single copy every training path uses. The `device_get` is the
+        host's wait for the device and has a span of its own. Returns
+        the tree and the arguments of `_log_pass_economics`, a call the
+        caller makes once the tree is appended; `dispatch_s` is the host
+        seconds the tree's enqueue took and is only passed through."""
         import jax
 
-        from .. import tracing
-        with tracing.phase("tree/extract"):
+        t0 = time.perf_counter()
+        with telemetry.span("lgbm/iter/fetch", iteration=iteration):
             host_state = _HostState(jax.device_get(small))
+        t1 = time.perf_counter()
+        with telemetry.span("lgbm/iter/build_tree", iteration=iteration):
             tree = Tree.from_grower_state(host_state, self.train_data)
-        if tree.num_leaves > 1:
-            tree.apply_shrinkage(shrink)
-            if fold_bias and \
-                    abs(getattr(self, "_pending_bias", 0.0)) > _K_EPSILON:
-                tree.add_bias(self._pending_bias)
-                self._pending_bias = 0.0
-                self.init_score_bias = 0.0
-        self._log_pass_economics(host_state)
-        return tree
+            if tree.num_leaves > 1:
+                tree.apply_shrinkage(
+                    self.shrinkage_rate if shrink is None else shrink)
+                if fold_bias and \
+                        abs(getattr(self, "_pending_bias", 0.0)) > _K_EPSILON:
+                    tree.add_bias(self._pending_bias)
+                    self._pending_bias = 0.0
+                    self.init_score_bias = 0.0
+        return tree, (host_state, dispatch_s, t1 - t0, t1)
 
-    def _log_pass_economics(self, host_state) -> None:
-        """Schedule observability (scripts/profile_train.py + bench.py):
-        append (passes, table high-water, rows fed to histogram
-        contractions, per-device collective elements) per tree —
-        rows_contracted is the compaction economics headline (full passes
-        report ~passes * N), comm_elems the histogram-merge volume the
-        scatter schedule exists to shrink."""
-        from .. import tracing
+    def _log_pass_economics(self, host_state, dispatch_s=0.0,
+                            fetch_wait_s=0.0, t_fetched=None) -> None:
+        """Append this tree's `telemetry.TreeRecord` to `pass_log` (read
+        by the run log and by benchmarks/layer_metrics/) and feed the
+        `tree/*` counters from it. rows_contracted is the compaction
+        economics headline (full passes report ~passes * N), comm_elems
+        the histogram-merge volume the scatter schedule exists to shrink;
+        full against compacted passes are told from `pass_rows`, which
+        the fetch already carried. Called once the tree is appended:
+        `build_tree_s` runs from the end of the fetch to here."""
         if not hasattr(self, "pass_log"):
             self.pass_log = []
-        rows_contracted = float(getattr(host_state, "rows_contracted", 0.0))
+        num_passes = int(host_state.num_passes)
         comm_elems = float(getattr(host_state, "comm_elems", 0.0))
-        # element count -> wire bytes: every exchanged histogram element
-        # is 4 bytes (f32, or the exact int32 domain under
-        # tpu_hist_quantize — where the constant-hessian channel elision
-        # already shrank comm_elems itself by red_ch/3)
-        comm_bytes = comm_elems * 4.0
-        self.pass_log.append((int(host_state.num_passes),
-                              int(host_state.next_free),
-                              rows_contracted, comm_elems, comm_bytes))
-        tracing.counter("tree/num_passes", int(host_state.num_passes))
-        tracing.counter("tree/rows_contracted", rows_contracted)
-        tracing.counter("tree/comm_elems", comm_elems)
-        tracing.counter("tree/comm_bytes", comm_bytes)
+        cfg = self._grower_cfg if self._dist_grower is None \
+            else self._dist_grower.cfg
+        shards = max(1, cfg.num_data_shards)
+        cap = shards * compact_capacity(cfg, self._n_pad // shards)
+        full, compacted, gathered = telemetry.layers.split_passes(
+            getattr(host_state, "pass_rows", ()), num_passes, cap)
+        rec = telemetry.TreeRecord(
+            num_passes=num_passes,
+            table_high_water=int(host_state.next_free),
+            rows_contracted=float(getattr(host_state, "rows_contracted",
+                                          0.0)),
+            comm_elems=comm_elems,
+            # element count -> wire bytes: every exchanged histogram
+            # element is 4 bytes (f32, or the exact int32 domain under
+            # tpu_hist_quantize — where the constant-hessian channel
+            # elision already shrank comm_elems itself by red_ch/3)
+            comm_bytes=comm_elems * 4.0,
+            full_passes=full, compact_passes=compacted,
+            rows_indexed=compacted * self._n_pad, rows_gathered=gathered,
+            dispatch_s=dispatch_s, fetch_wait_s=fetch_wait_s,
+            build_tree_s=0.0 if t_fetched is None
+            else time.perf_counter() - t_fetched)
+        self.pass_log.append(rec)
+        telemetry.counter_add("tree/num_passes", rec.num_passes)
+        telemetry.counter_add("tree/rows_contracted", rec.rows_contracted)
+        telemetry.counter_add("tree/comm_elems", rec.comm_elems)
+        telemetry.counter_add("tree/comm_bytes", rec.comm_bytes)
+        telemetry.counter_add("tree/compact_passes", rec.compact_passes)
+        telemetry.counter_add("tree/rows_gathered", rec.rows_gathered)
 
     def _flush_pending(self) -> bool:
         """Materialize the pipelined tree, if any. Returns False when the
         tree could not split (its iteration is rolled back here)."""
         if self._pending_small is None:
             return True
-        small, shrink, probe, it = self._pending_small
+        small, shrink, probe, it, dispatch_s = self._pending_small
         self._pending_small = None
         self._raise_if_nonfinite(probe, it)
-        tree = self._materialize_small(small, shrink)
+        tree, timing = self._fetch_tree(small, it, dispatch_s, shrink,
+                                        fold_bias=True)
         if tree.num_leaves > 1:
             self.models.append(tree)
             self._bump_model_version()
+        self._log_pass_economics(*timing)
+        if tree.num_leaves > 1:
             # a splitting tree clears any stale stop latch: the latch
             # exists to carry a pending stop across a drain, not to
             # poison later successful iterations (a fresh bag can open
@@ -1630,8 +1652,7 @@ class GBDT:
                                  raw)
 
     def _update_valid_scores(self, cls: int, tree) -> None:
-        from .. import tracing
-        with tracing.phase("boosting/update_valid_score"):
+        with telemetry.span("boosting/update_valid_score"):
             dtree = tree.to_device() if self.valid_sets else None
             vraws = getattr(self, "_valid_raw", None)
             for vi in range(len(self.valid_sets)):
@@ -1666,26 +1687,28 @@ class GBDT:
         import jax
         import jax.numpy as jnp
 
-        from .. import tracing
         from ..learner.grow import FMETA_KEYS
 
         k = self.num_tree_per_iteration
+        it = self.iter_
         masks = np.stack([self._feature_mask() for _ in range(k)])
-        with tracing.phase("tree/grow"):
+        with telemetry.span("lgbm/iter/dispatch", iteration=it):
             self._score, small = _grow_and_update_multi(
                 self._score, self._binned, grad, hess, row_weight,
                 jnp.asarray(masks), self.shrinkage_rate, self._n,
                 [self._fmeta[key] for key in FMETA_KEYS],
                 self._grower_cfg, qscales=qscales)
-        with tracing.phase("tree/extract"):
+        with telemetry.span("lgbm/iter/fetch", iteration=it):
             host = jax.device_get(small)
         could_split_any = False
         for cls in range(k):
             host_state = _HostState({key: v[cls] for key, v in host.items()})
-            tree = Tree.from_grower_state(host_state, self.train_data)
+            with telemetry.span("lgbm/iter/build_tree", iteration=it):
+                tree = Tree.from_grower_state(host_state, self.train_data)
+                if tree.num_leaves > 1:
+                    tree.apply_shrinkage(self.shrinkage_rate)
             if tree.num_leaves > 1:
                 could_split_any = True
-                tree.apply_shrinkage(self.shrinkage_rate)
                 self._update_valid_scores(cls, tree)
             self.models.append(tree)
 
@@ -1826,7 +1849,6 @@ class GBDT:
         re-running the comparison."""
         import jax.numpy as jnp
 
-        from .. import tracing
         from ..serving.forest import pad_rows
         key = ("value", total, k, mode)
         delta = cache.gate_delta(key)
@@ -1856,9 +1878,8 @@ class GBDT:
                             if n_cal else 1.0)
             delta = delta / scale
             cache.record_gate(key, delta)
-            from .. import telemetry
             telemetry.gauge_set("serving/quantize_gate_delta", delta)
-            tracing.counter("predict/quant_gate_runs", 1)
+            telemetry.counter_add("predict/quant_gate_runs", 1)
             log.debug("Quantize gate (%s, %d trees): relative raw-score "
                       "delta %.3g on %d calibration rows", mode, total,
                       delta, n_cal)
@@ -1889,7 +1910,6 @@ class GBDT:
         value(s); `fetch(sl, nrows, dev)` materializes them."""
         import jax.numpy as jnp
 
-        from .. import tracing
         from ..serving.forest import pad_rows
         n = data.shape[0]
         pipeline = bool(self.config.io.tpu_predict_pipeline)
@@ -1898,7 +1918,7 @@ class GBDT:
             nrows = min(chunk, n - i)
             bucket = self._bucket_size(nrows, chunk)
             dj = jnp.asarray(pad_rows(data[i:i + nrows], bucket))
-            tracing.counter("predict/chunks", 1)
+            telemetry.counter_add("predict/chunks", 1)
             dev = dispatch(dj)
             if pending is not None:
                 fetch(*pending)

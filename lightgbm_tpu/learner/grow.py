@@ -99,6 +99,7 @@ from ..binning import MISSING_NAN, MISSING_ZERO
 from ..ops import histogram as hist_ops
 from ..ops import split as split_ops
 from ..ops.split import leaf_output
+from ..telemetry.layers import scope
 
 
 class GrowerConfig(NamedTuple):
@@ -364,6 +365,7 @@ def _extract_feature_hist(group_hist, sum_g, sum_h, count, fmeta, cfg):
     return jnp.where(at_default[:, :, None], rest, fh)
 
 
+@scope("lgbm/split/scan")
 def _leaf_best_split(hist, sum_g, sum_h, count, depth, feature_mask, fmeta,
                      cfg, gp):
     """Best (gain, feature, ...) for one leaf from its (local) histogram.
@@ -420,6 +422,7 @@ def _leaf_best_split(hist, sum_g, sum_h, count, depth, feature_mask, fmeta,
             bcast(lg), bcast(lh), bcast(lc))
 
 
+@scope("lgbm/split/scan")
 def _scattered_best_split(hist, sum_g, sum_h, count, depth, feature_mask,
                           fmeta, owned, gs, cfg, gp):
     """Owned-slice split finding for the ReduceScatter histogram schedule.
@@ -482,6 +485,7 @@ def _scattered_best_split(hist, sum_g, sum_h, count, depth, feature_mask,
             bcast(pick(res.left_sum_h)), bcast(pick(res.left_count)))
 
 
+@scope("lgbm/split/scan")
 def _voting_children_best(hists_local, sum_g, sum_h, count, depth,
                           feature_mask, fmeta, cfg, gp):
     """Voting-parallel best splits for a batch of C children
@@ -538,7 +542,8 @@ def _voting_children_best(hists_local, sum_g, sum_h, count, depth,
     # (4) exchange only elected features' group slices
     egrp = fmeta["group"][elected]                            # [C, k]
     slices = jax.vmap(lambda h, g: h[g])(hists_local, egrp)   # [C, k, B, 3]
-    slices = jax.lax.psum(slices, ax)
+    with scope("lgbm/hist/merge"):
+        slices = jax.lax.psum(slices, ax)
     comm = jnp.float32(c * k_sel * bg * 3 + c * gains_local.shape[1])
 
     # (5) global scan of elected features with global sums
@@ -751,6 +756,7 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     # live channels per bin crossing the data-axis collective
     red_ch = 2 if elide_hess else 3
 
+    @scope("lgbm/hist/merge")
     def reduce_hist(h, group_dim=0):
         """Data-axis reduction seam (the ReduceScatter of
         data_parallel_tree_learner.cpp:148-163). hist_scatter reduces
@@ -775,13 +781,15 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                               axis=-1)
         return h
 
-    w3 = jnp.stack([grad * row_weight, hess * row_weight,
-                    (row_weight > 0).astype(jnp.float32)], axis=-1)
+    with scope("lgbm/grow/root_hist"):
+        w3 = jnp.stack([grad * row_weight, hess * row_weight,
+                        (row_weight > 0).astype(jnp.float32)], axis=-1)
 
     # transposed bin matrix for the routing step: row g is the contiguous
     # bin column of stored group g (loop-invariant — XLA hoists it out of
     # the round loop)
-    binned_T = binned.T
+    with scope("lgbm/grow/relabel"):
+        binned_T = binned.T
 
     if (cfg.feature_axis is None
             and len(cfg.group_widths) == local_binned.shape[1]):
@@ -815,42 +823,37 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     # round up to n and force EVERY pass through the slower gather —
     # keep the contiguous full-pass kernel there. A non-positive
     # fraction disables compaction (mirroring >= 1.0 forcing it on).
-    compact = bool(cfg.hist_compact) and cfg.feature_axis is None \
-        and float(cfg.compact_fraction) > 0.0 \
-        and n % cfg.chunk == 0 and n >= 2 * cfg.chunk
-    if compact:
-        cap = max(1, int(n * min(float(cfg.compact_fraction), 1.0)))
-        cap = min(n, ((cap + cfg.chunk - 1) // cfg.chunk) * cfg.chunk)
-        compact = cap >= cfg.chunk
+    cap = compact_capacity(cfg, n)
+    compact = cap > 0
     pass_cap = 4 * L + 64   # == the round_cond hard pass cap
 
     # --- root (BeforeTrain: serial_tree_learner.cpp:234-323) ------------
-    local_root = hist_ops.leaf_histogram(local_binned, w3, B, cfg.chunk,
-                                         bf16=cfg.hist_bf16, n_valid=nv_local,
-                                         group_widths=gw,
-                                         quantize=cfg.hist_quantize)
-    root_hist = reduce_hist(local_root)
-    # global leaf sums: the reference Allreduces (cnt, sum_g, sum_h)
-    # (data_parallel_tree_learner.cpp:117-145); summing any group's bins
-    # gives the same totals. Voting keeps local histograms so it psums
-    # the LOCAL group-0 bin sums. Scatter reads the REDUCED group-0
-    # slice on its owning shard (shard 0, local index 0 — psum_scatter
-    # slices are bitwise equal to the full psum) and broadcasts, so the
-    # bin-sum ORDER matches the allreduce path exactly and totals stay
-    # bit-identical between the two schedules.
-    if voting:
-        root_tot = jax.lax.psum(local_root[0].sum(axis=0), cfg.data_axis)
-    elif scatter:
-        owner0 = jax.lax.axis_index(cfg.data_axis) == 0
-        rt = root_hist[0].sum(axis=0)
-        root_tot = jax.lax.psum(
-            jnp.where(owner0, rt, jnp.zeros_like(rt)), cfg.data_axis)
-    else:
-        root_tot = root_hist[0].sum(axis=0)
-    # quantized modes: totals leave the exact integer domain HERE; every
-    # table aggregate / gain / leaf value downstream is real-unit f32
-    root_tot = dequant(root_tot)
-    root_g, root_h, root_c = root_tot[0], root_tot[1], root_tot[2]
+    with scope("lgbm/grow/root_hist"):
+        local_root = hist_ops.leaf_histogram(
+            local_binned, w3, B, cfg.chunk, bf16=cfg.hist_bf16,
+            n_valid=nv_local, group_widths=gw, quantize=cfg.hist_quantize)
+        root_hist = reduce_hist(local_root)
+        # global leaf sums: the reference Allreduces (cnt, sum_g, sum_h)
+        # (data_parallel_tree_learner.cpp:117-145); summing any group's bins
+        # gives the same totals. Voting keeps local histograms so it psums
+        # the LOCAL group-0 bin sums. Scatter reads the REDUCED group-0
+        # slice on its owning shard (shard 0, local index 0 — psum_scatter
+        # slices are bitwise equal to the full psum) and broadcasts, so the
+        # bin-sum ORDER matches the allreduce path exactly and totals stay
+        # bit-identical between the two schedules.
+        if voting:
+            root_tot = jax.lax.psum(local_root[0].sum(axis=0), cfg.data_axis)
+        elif scatter:
+            owner0 = jax.lax.axis_index(cfg.data_axis) == 0
+            rt = root_hist[0].sum(axis=0)
+            root_tot = jax.lax.psum(
+                jnp.where(owner0, rt, jnp.zeros_like(rt)), cfg.data_axis)
+        else:
+            root_tot = root_hist[0].sum(axis=0)
+        # quantized modes: totals leave the exact integer domain HERE; every
+        # table aggregate / gain / leaf value downstream is real-unit f32
+        root_tot = dequant(root_tot)
+        root_g, root_h, root_c = root_tot[0], root_tot[1], root_tot[2]
     root_comm = jnp.float32(0.0)
     if cfg.data_axis is not None:
         # per-device elements moved: voting ships 3 totals, scatter keeps
@@ -877,162 +880,165 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             root_hist_f, root_g, root_h, root_c, jnp.int32(0), local_fmask,
             local_fmeta, cfg, gp)
 
-    table = _NodeTable.zeros(M)
-    table = table._replace(
-        parent=table.parent.at[0].set(0),
-        sum_g=table.sum_g.at[0].set(root_g),
-        sum_h=table.sum_h.at[0].set(root_h),
-        count=table.count.at[0].set(root_c),
-        gain=table.gain.at[0].set(root_vals[0]),
-        feature=table.feature.at[0].set(root_vals[1]),
-        threshold=table.threshold.at[0].set(root_vals[2]),
-        default_left=table.default_left.at[0].set(root_vals[3]),
-        is_cat=table.is_cat.at[0].set(root_vals[4]),
-        left_g=table.left_g.at[0].set(root_vals[5]),
-        left_h=table.left_h.at[0].set(root_vals[6]),
-        left_c=table.left_c.at[0].set(root_vals[7]),
-        created=table.created.at[0].set(True),
-        frontier=table.frontier.at[0].set(True),
-        leaf_slot=table.leaf_slot.at[0].set(0),
-    )
+    with scope("lgbm/grow/table"):
+        table = _NodeTable.zeros(M)
+        table = table._replace(
+            parent=table.parent.at[0].set(0),
+            sum_g=table.sum_g.at[0].set(root_g),
+            sum_h=table.sum_h.at[0].set(root_h),
+            count=table.count.at[0].set(root_c),
+            gain=table.gain.at[0].set(root_vals[0]),
+            feature=table.feature.at[0].set(root_vals[1]),
+            threshold=table.threshold.at[0].set(root_vals[2]),
+            default_left=table.default_left.at[0].set(root_vals[3]),
+            is_cat=table.is_cat.at[0].set(root_vals[4]),
+            left_g=table.left_g.at[0].set(root_vals[5]),
+            left_h=table.left_h.at[0].set(root_vals[6]),
+            left_c=table.left_c.at[0].set(root_vals[7]),
+            created=table.created.at[0].set(True),
+            frontier=table.frontier.at[0].set(True),
+            leaf_slot=table.leaf_slot.at[0].set(0),
+        )
 
-    if subtract:
-        # under hist_scatter the cache holds owned-slice histograms — the
-        # parent-minus-smaller identity is linear, so it holds slice-wise.
-        # Quantized modes cache the INT32 histograms: parent - child is
-        # then exact, so sum(left) + sum(right) == parent holds bitwise
-        # in the quantized domain (the ISSUE 20 parent-sum contract).
-        hist_cache = jnp.zeros((M, own_g, B, 3),
-                               root_hist.dtype).at[0].set(root_hist)
-    else:
-        hist_cache = jnp.zeros((1,), jnp.float32)
+        if subtract:
+            # under hist_scatter the cache holds owned-slice histograms — the
+            # parent-minus-smaller identity is linear, so it holds slice-wise.
+            # Quantized modes cache the INT32 histograms: parent - child is
+            # then exact, so sum(left) + sum(right) == parent holds bitwise
+            # in the quantized domain (the ISSUE 20 parent-sum contract).
+            hist_cache = jnp.zeros((M, own_g, B, 3),
+                                   root_hist.dtype).at[0].set(root_hist)
+        else:
+            hist_cache = jnp.zeros((1,), jnp.float32)
 
-    neg_inf = jnp.float32(-jnp.inf)
-    # rows the root pass contracted (the full-pass kernels skip whole
-    # all-padding chunks via n_valid, so count only the real rows)
-    full_rows = jnp.float32(n) if nv_local is None \
-        else nv_local.astype(jnp.float32)
-    carry = _Carry(
-        leaf_id=jnp.zeros(n, jnp.int32),
-        table=table,
-        next_free=jnp.int32(1),
-        num_passes=jnp.int32(1),
-        comm_elems=root_comm,
-        rows_contracted=full_rows,
-        pass_rows=jnp.zeros(pass_cap, jnp.int32).at[0].set(
-            full_rows.astype(jnp.int32)),
-        hist_cache=hist_cache,
-        sum_g=jnp.zeros(L, jnp.float32).at[0].set(root_g),
-        sum_h=jnp.zeros(L, jnp.float32).at[0].set(root_h),
-        count=jnp.zeros(L, jnp.float32).at[0].set(root_c),
-        leaf_value=jnp.zeros(L, jnp.float32).at[0].set(
-            leaf_output(root_g, root_h, gp.lambda_l1, gp.lambda_l2)),
-        leaf_depth=jnp.zeros(L, jnp.int32),
-        leaf_parent=jnp.full(L, -1, jnp.int32),
-        node_feature=jnp.zeros(L - 1, jnp.int32),
-        node_threshold=jnp.zeros(L - 1, jnp.int32),
-        node_default_left=jnp.zeros(L - 1, bool),
-        node_is_cat=jnp.zeros(L - 1, bool),
-        node_left=jnp.zeros(L - 1, jnp.int32),
-        node_right=jnp.zeros(L - 1, jnp.int32),
-        node_gain=jnp.zeros(L - 1, jnp.float32),
-        node_value=jnp.zeros(L - 1, jnp.float32),
-        node_count=jnp.zeros(L - 1, jnp.float32),
-        num_leaves_used=jnp.int32(1),
-    )
+        neg_inf = jnp.float32(-jnp.inf)
+        # rows the root pass contracted (the full-pass kernels skip whole
+        # all-padding chunks via n_valid, so count only the real rows)
+        full_rows = jnp.float32(n) if nv_local is None \
+            else nv_local.astype(jnp.float32)
+        carry = _Carry(
+            leaf_id=jnp.zeros(n, jnp.int32),
+            table=table,
+            next_free=jnp.int32(1),
+            num_passes=jnp.int32(1),
+            comm_elems=root_comm,
+            rows_contracted=full_rows,
+            pass_rows=jnp.zeros(pass_cap, jnp.int32).at[0].set(
+                full_rows.astype(jnp.int32)),
+            hist_cache=hist_cache,
+            sum_g=jnp.zeros(L, jnp.float32).at[0].set(root_g),
+            sum_h=jnp.zeros(L, jnp.float32).at[0].set(root_h),
+            count=jnp.zeros(L, jnp.float32).at[0].set(root_c),
+            leaf_value=jnp.zeros(L, jnp.float32).at[0].set(
+                leaf_output(root_g, root_h, gp.lambda_l1, gp.lambda_l2)),
+            leaf_depth=jnp.zeros(L, jnp.int32),
+            leaf_parent=jnp.full(L, -1, jnp.int32),
+            node_feature=jnp.zeros(L - 1, jnp.int32),
+            node_threshold=jnp.zeros(L - 1, jnp.int32),
+            node_default_left=jnp.zeros(L - 1, bool),
+            node_is_cat=jnp.zeros(L - 1, bool),
+            node_left=jnp.zeros(L - 1, jnp.int32),
+            node_right=jnp.zeros(L - 1, jnp.int32),
+            node_gain=jnp.zeros(L - 1, jnp.float32),
+            node_value=jnp.zeros(L - 1, jnp.float32),
+            node_count=jnp.zeros(L - 1, jnp.float32),
+            num_leaves_used=jnp.int32(1),
+        )
 
     def expand(carry: _Carry) -> _Carry:
         """One speculative expansion pass: select up to K unexpanded nodes
         (commit-blocking argmax force-included), route+relabel their rows
         under their cached splits, build both children's histograms in one
         contraction, scan the children's best splits into the table."""
-        t = carry.table
-        eligible = t.created & ~t.expanded & (t.gain > 0.0)
-        # budget-aware speculation throttle: the tree has R = L - used
-        # commits left, so only nodes whose gain ranks within the top R
-        # of the current commit-candidate pool (frontier nodes + created
-        # unexpanded spec nodes) are worth slots. Without this, every
-        # eventual LEAF with positive gain attracts one speculative
-        # expansion that never commits (~2L wasted slots late in
-        # boosting, when gains flatten), the table hits its capacity
-        # reserve, and passes degrade to one forced expansion per commit
-        # (measured: 18 -> 145 passes/tree by iteration 100 at 2M rows).
-        # Like any selection policy this only changes WHICH precompute
-        # happens early — commits stay bit-identical.
-        # rank-count formulation: a node passes iff fewer than R pool
-        # gains strictly beat it (ties all pass — harmless slack) — an
-        # [M, M] compare, ~1M bool ops.
-        R = L - carry.num_leaves_used
-        pool = t.created & (t.gain > 0.0) & (t.frontier | ~t.expanded)
-        pg = jnp.where(pool, t.gain, neg_inf)
-        rank = jnp.sum((pg[None, :] > t.gain[:, None]).astype(jnp.int32),
-                       axis=1)                                # [M]
-        f_gain = jnp.where(t.frontier, t.gain, neg_inf)
-        f_arg = jnp.argmax(f_gain).astype(jnp.int32)
-        # the commit-blocking frontier argmax is EXEMPT from the
-        # throttle: deep spec nodes elsewhere can out-rank every
-        # frontier gain, and throttling the argmax would deadlock the
-        # commit chain — the expansion loop then spins without progress
-        # until the device watchdog kills the worker (observed as a
-        # mid-run "TPU worker crashed" at 2M rows, iteration ~50+).
-        eligible = eligible & ((rank < R)
-                               | (jnp.arange(M, dtype=jnp.int32) == f_arg))
-        # frontier-first selection: unexpanded FRONTIER nodes are the
-        # commit chain's immediate blockers — every one expanded this
-        # pass is a commit the next drain can pop — so they outrank
-        # deeper speculative nodes regardless of raw gain (late-boosting
-        # flat gains otherwise spend the batch on spec descendants while
-        # the drain stalls one forced expansion per round). Selection
-        # policy only: commits stay bit-identical.
-        score = jnp.where(eligible, t.gain, neg_inf)
-        if K >= 12:
-            # wide batches only: narrow batches (wide-shape configs,
-            # K<=8) serve depth-bound trees where the deep chain — not
-            # frontier breadth — is the scarce resource (Bosch-shape
-            # measured slower with the boost)
-            score = jnp.where(eligible & t.frontier,
-                              score + _FRONTIER_BOOST, score)
-        score = score.at[f_arg].set(
-            jnp.where(eligible[f_arg], jnp.inf, score[f_arg]))
-        top_gain, sel = jax.lax.top_k(score, K)
-        valid = top_gain > neg_inf                           # [K]
+        with scope("lgbm/grow/select"):
+            t = carry.table
+            eligible = t.created & ~t.expanded & (t.gain > 0.0)
+            # budget-aware speculation throttle: the tree has R = L - used
+            # commits left, so only nodes whose gain ranks within the top R
+            # of the current commit-candidate pool (frontier nodes + created
+            # unexpanded spec nodes) are worth slots. Without this, every
+            # eventual LEAF with positive gain attracts one speculative
+            # expansion that never commits (~2L wasted slots late in
+            # boosting, when gains flatten), the table hits its capacity
+            # reserve, and passes degrade to one forced expansion per commit
+            # (measured: 18 -> 145 passes/tree by iteration 100 at 2M rows).
+            # Like any selection policy this only changes WHICH precompute
+            # happens early — commits stay bit-identical.
+            # rank-count formulation: a node passes iff fewer than R pool
+            # gains strictly beat it (ties all pass — harmless slack) — an
+            # [M, M] compare, ~1M bool ops.
+            R = L - carry.num_leaves_used
+            pool = t.created & (t.gain > 0.0) & (t.frontier | ~t.expanded)
+            pg = jnp.where(pool, t.gain, neg_inf)
+            rank = jnp.sum((pg[None, :] > t.gain[:, None]).astype(jnp.int32),
+                           axis=1)                                # [M]
+            f_gain = jnp.where(t.frontier, t.gain, neg_inf)
+            f_arg = jnp.argmax(f_gain).astype(jnp.int32)
+            # the commit-blocking frontier argmax is EXEMPT from the
+            # throttle: deep spec nodes elsewhere can out-rank every
+            # frontier gain, and throttling the argmax would deadlock the
+            # commit chain — the expansion loop then spins without progress
+            # until the device watchdog kills the worker (observed as a
+            # mid-run "TPU worker crashed" at 2M rows, iteration ~50+).
+            eligible = eligible & ((rank < R)
+                                   | (jnp.arange(M, dtype=jnp.int32) == f_arg))
+            # frontier-first selection: unexpanded FRONTIER nodes are the
+            # commit chain's immediate blockers — every one expanded this
+            # pass is a commit the next drain can pop — so they outrank
+            # deeper speculative nodes regardless of raw gain (late-boosting
+            # flat gains otherwise spend the batch on spec descendants while
+            # the drain stalls one forced expansion per round). Selection
+            # policy only: commits stay bit-identical.
+            score = jnp.where(eligible, t.gain, neg_inf)
+            if K >= 12:
+                # wide batches only: narrow batches (wide-shape configs,
+                # K<=8) serve depth-bound trees where the deep chain — not
+                # frontier breadth — is the scarce resource (Bosch-shape
+                # measured slower with the boost)
+                score = jnp.where(eligible & t.frontier,
+                                  score + _FRONTIER_BOOST, score)
+            score = score.at[f_arg].set(
+                jnp.where(eligible[f_arg], jnp.inf, score[f_arg]))
+            top_gain, sel = jax.lax.top_k(score, K)
+            valid = top_gain > neg_inf                           # [K]
 
-        # allocate child slots (rank-compacted so padding slots don't
-        # leak table space). Capacity invariant: every future commit may
-        # need one forced expansion of the frontier argmax (2 slots), so
-        # SPECULATIVE allocations must leave 2*(L - num_leaves_used)
-        # slots in reserve — the forced expansion itself may dip into
-        # the reserve. This keeps the commit chain unblockable and the
-        # bit-identical-to-sequential guarantee unconditional, for any
-        # table fill pattern.
-        rank = jnp.cumsum(valid.astype(jnp.int32)) - valid.astype(jnp.int32)
-        cl = carry.next_free + 2 * rank
-        cr = cl + 1
-        reserve = 2 * (L - carry.num_leaves_used)
-        is_forced = eligible[f_arg] & (sel == f_arg)
-        # (measured dead end, kept as a note: tying cumulative slot
-        # spend to commit progress — e.g. 4 slots per committed leaf —
-        # bounds the table mathematically but chokes the broad
-        # speculation that flat-gain trees NEED to keep commits batched:
-        # passes got WORSE, 105 -> 147 at iterations 100+. Generous
-        # tables beat tight budgets here.)
-        valid = valid & jnp.where(is_forced, cr < M, cr + reserve < M)
-        cl_eff = jnp.where(valid, cl, M)
-        cr_eff = jnp.where(valid, cr, M)
-        sel_eff = jnp.where(valid, sel, M)
-        next_free = carry.next_free + 2 * jnp.sum(valid.astype(jnp.int32))
+            # allocate child slots (rank-compacted so padding slots don't
+            # leak table space). Capacity invariant: every future commit may
+            # need one forced expansion of the frontier argmax (2 slots), so
+            # SPECULATIVE allocations must leave 2*(L - num_leaves_used)
+            # slots in reserve — the forced expansion itself may dip into
+            # the reserve. This keeps the commit chain unblockable and the
+            # bit-identical-to-sequential guarantee unconditional, for any
+            # table fill pattern.
+            rank = jnp.cumsum(valid.astype(jnp.int32)) \
+                - valid.astype(jnp.int32)
+            cl = carry.next_free + 2 * rank
+            cr = cl + 1
+            reserve = 2 * (L - carry.num_leaves_used)
+            is_forced = eligible[f_arg] & (sel == f_arg)
+            # (measured dead end, kept as a note: tying cumulative slot
+            # spend to commit progress — e.g. 4 slots per committed leaf —
+            # bounds the table mathematically but chokes the broad
+            # speculation that flat-gain trees NEED to keep commits batched:
+            # passes got WORSE, 105 -> 147 at iterations 100+. Generous
+            # tables beat tight budgets here.)
+            valid = valid & jnp.where(is_forced, cr < M, cr + reserve < M)
+            cl_eff = jnp.where(valid, cl, M)
+            cr_eff = jnp.where(valid, cr, M)
+            sel_eff = jnp.where(valid, sel, M)
+            next_free = carry.next_free + 2 * jnp.sum(valid.astype(jnp.int32))
 
-        # histogram ids: direct mode builds BOTH children; subtraction
-        # mode builds only each node's SMALLER child (the larger comes
-        # from parent - smaller below, feature_histogram.hpp:64-70)
-        sel_c = jnp.clip(sel, 0, M - 1)
-        if subtract:
-            small_left = t.left_c[sel_c] * 2.0 <= t.count[sel_c]  # [K]
-            hist_ids = jnp.where(valid,
-                                 jnp.where(small_left, cl, cr), -1)
-        else:
-            hist_ids = jnp.concatenate([jnp.where(valid, cl, -1),
-                                        jnp.where(valid, cr, -1)])
+            # histogram ids: direct mode builds BOTH children; subtraction
+            # mode builds only each node's SMALLER child (the larger comes
+            # from parent - smaller below, feature_histogram.hpp:64-70)
+            sel_c = jnp.clip(sel, 0, M - 1)
+            if subtract:
+                small_left = t.left_c[sel_c] * 2.0 <= t.count[sel_c]  # [K]
+                hist_ids = jnp.where(valid,
+                                     jnp.where(small_left, cl, cr), -1)
+            else:
+                hist_ids = jnp.concatenate([jnp.where(valid, cl, -1),
+                                            jnp.where(valid, cr, -1)])
 
         def route(lid, col_of_group):
             """Apply the K selected splits to a leaf-label vector
@@ -1068,8 +1074,9 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                                 lid)
             return lid
 
-        leaf_id = route(carry.leaf_id, lambda grp: jax.lax.dynamic_slice(
-            binned_T, (grp, 0), (1, n))[0])
+        with scope("lgbm/grow/relabel"):
+            leaf_id = route(carry.leaf_id, lambda grp: jax.lax.dynamic_slice(
+                binned_T, (grp, 0), (1, n))[0])
 
         if compact:
             # member rows of THIS pass's selected nodes are exactly the
@@ -1079,34 +1086,37 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             # Zero-weight (out-of-bag / padding) rows contribute zero to
             # every channel either way; excluding them keeps small
             # bagged nodes inside the buffer.
-            member = (leaf_id >= carry.next_free) & (w3[:, 2] > 0.0)
-            cnt = jnp.sum(member.astype(jnp.int32))
-            use_compact = cnt <= cap
+            with scope("lgbm/grow/compact_index"):
+                member = (leaf_id >= carry.next_free) & (w3[:, 2] > 0.0)
+                cnt = jnp.sum(member.astype(jnp.int32))
+                use_compact = cnt <= cap
 
             def gathered(_):
                 # stable compaction: cumsum ranks keep row order, so the
                 # gathered chunks sum rows in their original relative
                 # order. Built INSIDE the branch: cond executes only the
                 # taken side, so full passes skip the cumsum + scatter.
-                pos = jnp.cumsum(member.astype(jnp.int32)) - 1
-                rows_buf = jnp.zeros(cap, jnp.int32).at[
-                    jnp.where(member, pos, cap)].set(
-                        jnp.arange(n, dtype=jnp.int32), mode="drop")
+                with scope("lgbm/grow/compact_index"):
+                    pos = jnp.cumsum(member.astype(jnp.int32)) - 1
+                    rows_buf = jnp.zeros(cap, jnp.int32).at[
+                        jnp.where(member, pos, cap)].set(
+                            jnp.arange(n, dtype=jnp.int32), mode="drop")
                 return hist_ops.gathered_leaves_histogram(
                     local_binned, w3, leaf_id, rows_buf, hist_ids, B,
                     cfg.chunk, bf16=cfg.hist_bf16, n_valid=cnt,
                     group_widths=gw, quantize=cfg.hist_quantize)
 
-            hists = jax.lax.cond(
-                use_compact,
-                gathered,
-                lambda _: hist_ops.batched_leaves_histogram(
-                    local_binned, w3, leaf_id, hist_ids, B, cfg.chunk,
-                    bf16=cfg.hist_bf16, n_valid=nv_local,
-                    group_widths=gw, quantize=cfg.hist_quantize),
-                None)
-            rows_pass = jnp.where(use_compact, cnt.astype(jnp.float32),
-                                  full_rows)
+            with scope("lgbm/hist/contract"):
+                hists = jax.lax.cond(
+                    use_compact,
+                    gathered,
+                    lambda _: hist_ops.batched_leaves_histogram(
+                        local_binned, w3, leaf_id, hist_ids, B, cfg.chunk,
+                        bf16=cfg.hist_bf16, n_valid=nv_local,
+                        group_widths=gw, quantize=cfg.hist_quantize),
+                    None)
+                rows_pass = jnp.where(use_compact, cnt.astype(jnp.float32),
+                                      full_rows)
         else:
             hists = hist_ops.batched_leaves_histogram(
                 local_binned, w3, leaf_id, hist_ids, B, cfg.chunk,
@@ -1122,23 +1132,25 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         if subtract:
             # larger child = parent - smaller (the cache holds every
             # created node's histogram; parents are always present)
-            parent_h = carry.hist_cache[sel_c]               # [K, fl, B, 3]
-            other = parent_h - hists
-            sl4 = small_left[:, None, None, None]
-            hists = jnp.concatenate([jnp.where(sl4, hists, other),
-                                     jnp.where(sl4, other, hists)])
+            with scope("lgbm/grow/subtract"):
+                parent_h = carry.hist_cache[sel_c]           # [K, fl, B, 3]
+                other = parent_h - hists
+                sl4 = small_left[:, None, None, None]
+                hists = jnp.concatenate([jnp.where(sl4, hists, other),
+                                         jnp.where(sl4, other, hists)])
             # [2K, fl, B, 3] — same (left-block, right-block) layout as
             # the direct path from here on
 
         # children aggregates from the parents' cached split stats
-        pg, ph, pc = t.sum_g[sel_c], t.sum_h[sel_c], t.count[sel_c]
-        lg, lh = t.left_g[sel_c], t.left_h[sel_c]
-        lcc = t.left_c[sel_c]
-        cdepth = t.depth[sel_c] + 1
-        all_g = jnp.concatenate([lg, pg - lg])
-        all_h = jnp.concatenate([lh, ph - lh])
-        all_c = jnp.concatenate([lcc, pc - lcc])
-        all_d = jnp.concatenate([cdepth, cdepth])
+        with scope("lgbm/grow/table"):
+            pg, ph, pc = t.sum_g[sel_c], t.sum_h[sel_c], t.count[sel_c]
+            lg, lh = t.left_g[sel_c], t.left_h[sel_c]
+            lcc = t.left_c[sel_c]
+            cdepth = t.depth[sel_c] + 1
+            all_g = jnp.concatenate([lg, pg - lg])
+            all_h = jnp.concatenate([lh, ph - lh])
+            all_c = jnp.concatenate([lcc, pc - lcc])
+            all_d = jnp.concatenate([cdepth, cdepth])
 
         # split scoring reads real-unit f32; the int32 histograms stay
         # exact for the cache/subtraction identity above
@@ -1164,39 +1176,40 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             vals2 = split_fn(hists_f, all_g, all_h, all_c, all_d)
         gain2, feat2, thr2, dl2, cat2, lg2, lh2, lc2 = vals2
 
-        idx = jnp.concatenate([cl_eff, cr_eff])              # [2K], M = drop
-        par2 = jnp.concatenate([sel_eff, sel_eff])
-        hist_cache = carry.hist_cache
-        if subtract:
-            # children become candidate parents: retain their histograms
-            hist_cache = hist_cache.at[idx].set(hists, mode="drop")
-        t = t._replace(
-            parent=t.parent.at[idx].set(par2, mode="drop"),
-            depth=t.depth.at[idx].set(all_d, mode="drop"),
-            sum_g=t.sum_g.at[idx].set(all_g, mode="drop"),
-            sum_h=t.sum_h.at[idx].set(all_h, mode="drop"),
-            count=t.count.at[idx].set(all_c, mode="drop"),
-            gain=t.gain.at[idx].set(gain2, mode="drop"),
-            feature=t.feature.at[idx].set(feat2, mode="drop"),
-            threshold=t.threshold.at[idx].set(thr2, mode="drop"),
-            default_left=t.default_left.at[idx].set(dl2, mode="drop"),
-            is_cat=t.is_cat.at[idx].set(cat2, mode="drop"),
-            left_g=t.left_g.at[idx].set(lg2, mode="drop"),
-            left_h=t.left_h.at[idx].set(lh2, mode="drop"),
-            left_c=t.left_c.at[idx].set(lc2, mode="drop"),
-            created=t.created.at[idx].set(True, mode="drop"),
-            expanded=t.expanded.at[sel_eff].set(True, mode="drop"),
-            child_l=t.child_l.at[sel_eff].set(cl, mode="drop"),
-            child_r=t.child_r.at[sel_eff].set(cr, mode="drop"),
-        )
-        return carry._replace(
-            leaf_id=leaf_id, table=t, next_free=next_free,
-            num_passes=carry.num_passes + 1,
-            comm_elems=carry.comm_elems + comm,
-            rows_contracted=carry.rows_contracted + rows_pass,
-            pass_rows=carry.pass_rows.at[carry.num_passes].set(
-                rows_pass.astype(jnp.int32), mode="drop"),
-            hist_cache=hist_cache)
+        with scope("lgbm/grow/table"):
+            idx = jnp.concatenate([cl_eff, cr_eff])          # [2K], M = drop
+            par2 = jnp.concatenate([sel_eff, sel_eff])
+            hist_cache = carry.hist_cache
+            if subtract:
+                # children become candidate parents: retain their histograms
+                hist_cache = hist_cache.at[idx].set(hists, mode="drop")
+            t = t._replace(
+                parent=t.parent.at[idx].set(par2, mode="drop"),
+                depth=t.depth.at[idx].set(all_d, mode="drop"),
+                sum_g=t.sum_g.at[idx].set(all_g, mode="drop"),
+                sum_h=t.sum_h.at[idx].set(all_h, mode="drop"),
+                count=t.count.at[idx].set(all_c, mode="drop"),
+                gain=t.gain.at[idx].set(gain2, mode="drop"),
+                feature=t.feature.at[idx].set(feat2, mode="drop"),
+                threshold=t.threshold.at[idx].set(thr2, mode="drop"),
+                default_left=t.default_left.at[idx].set(dl2, mode="drop"),
+                is_cat=t.is_cat.at[idx].set(cat2, mode="drop"),
+                left_g=t.left_g.at[idx].set(lg2, mode="drop"),
+                left_h=t.left_h.at[idx].set(lh2, mode="drop"),
+                left_c=t.left_c.at[idx].set(lc2, mode="drop"),
+                created=t.created.at[idx].set(True, mode="drop"),
+                expanded=t.expanded.at[sel_eff].set(True, mode="drop"),
+                child_l=t.child_l.at[sel_eff].set(cl, mode="drop"),
+                child_r=t.child_r.at[sel_eff].set(cr, mode="drop"),
+            )
+            return carry._replace(
+                leaf_id=leaf_id, table=t, next_free=next_free,
+                num_passes=carry.num_passes + 1,
+                comm_elems=carry.comm_elems + comm,
+                rows_contracted=carry.rows_contracted + rows_pass,
+                pass_rows=carry.pass_rows.at[carry.num_passes].set(
+                    rows_pass.astype(jnp.int32), mode="drop"),
+                hist_cache=hist_cache)
 
     # --- commit (Train: serial_tree_learner.cpp:152-205) ----------------
     # strict best-first: pop the frontier argmax, write the tree node,
@@ -1285,21 +1298,23 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         def drain_cond(carry):
             return (carry.num_leaves_used - start < C) & _can_commit(carry)
 
-        return jax.lax.while_loop(drain_cond, commit_one, carry)
+        with scope("lgbm/grow/commit"):
+            return jax.lax.while_loop(drain_cond, commit_one, carry)
 
     def round_cond(carry: _Carry):
-        t = carry.table
-        f_gain = jnp.where(t.frontier, t.gain, neg_inf)
-        growing = (carry.num_leaves_used < L) & (jnp.max(f_gain) > 0.0)
-        # safety nets only: the reservation rule in expand() guarantees
-        # the blocking argmax always has room (progress), and a tree can
-        # never need more rounds than commits (each round commits >= 1
-        # via the forced expansion) — the hard cap turns any future
-        # no-progress bug into a truncated tree instead of an infinite
-        # device loop that gets the TPU worker killed.
-        f_arg = jnp.argmax(f_gain)
-        progress = t.expanded[f_arg] | (carry.next_free + 1 < M)
-        return growing & progress & (carry.num_passes < 4 * L + 64)
+        with scope("lgbm/grow/commit"):
+            t = carry.table
+            f_gain = jnp.where(t.frontier, t.gain, neg_inf)
+            growing = (carry.num_leaves_used < L) & (jnp.max(f_gain) > 0.0)
+            # safety nets only: the reservation rule in expand() guarantees
+            # the blocking argmax always has room (progress), and a tree can
+            # never need more rounds than commits (each round commits >= 1
+            # via the forced expansion) — the hard cap turns any future
+            # no-progress bug into a truncated tree instead of an infinite
+            # device loop that gets the TPU worker killed.
+            f_arg = jnp.argmax(f_gain)
+            progress = t.expanded[f_arg] | (carry.next_free + 1 < M)
+            return growing & progress & (carry.num_passes < 4 * L + 64)
 
     carry = jax.lax.while_loop(round_cond, round_body, carry)
 
@@ -1309,21 +1324,22 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     # (ancestors stop at resolved nodes so a jump can never skip the
     # frontier into the committed region); spec depth is bounded by the
     # number of allocations (M/2), so ceil(log2(M))+1 hops always resolve.
-    t = carry.table
-    slot_map = jnp.where(t.frontier, t.leaf_slot, -1)
-    anc = jnp.where(t.frontier, jnp.arange(M, dtype=jnp.int32), t.parent)
-    hops = int(M).bit_length() + 1
+    with scope("lgbm/grow/finalize"):
+        t = carry.table
+        slot_map = jnp.where(t.frontier, t.leaf_slot, -1)
+        anc = jnp.where(t.frontier, jnp.arange(M, dtype=jnp.int32), t.parent)
+        hops = int(M).bit_length() + 1
 
-    def hop(_, sm_anc):
-        sm, a = sm_anc
-        sm = jnp.where(sm >= 0, sm, sm[a])
-        a = jnp.where(sm >= 0, jnp.arange(M, dtype=jnp.int32),
-                      jnp.where(sm[a] >= 0, a, a[a]))
-        return sm, a
+        def hop(_, sm_anc):
+            sm, a = sm_anc
+            sm = jnp.where(sm >= 0, sm, sm[a])
+            a = jnp.where(sm >= 0, jnp.arange(M, dtype=jnp.int32),
+                          jnp.where(sm[a] >= 0, a, a[a]))
+            return sm, a
 
-    slot_map, _ = jax.lax.fori_loop(0, hops, hop, (slot_map, anc))
-    slot_map = jnp.clip(slot_map, 0, L - 1)
-    leaf_slot_of_row = slot_map[jnp.clip(carry.leaf_id, 0, M - 1)]
+        slot_map, _ = jax.lax.fori_loop(0, hops, hop, (slot_map, anc))
+        slot_map = jnp.clip(slot_map, 0, L - 1)
+        leaf_slot_of_row = slot_map[jnp.clip(carry.leaf_id, 0, M - 1)]
 
     # the contraction counters are per-shard (each shard compacts its own
     # rows and may even take a different path per pass); sum them once so
@@ -1396,6 +1412,20 @@ def leaf_path_features(leaf_parent, node_feature, node_left, node_right,
         return feats
 
     return jax.vmap(one_leaf)(leaf_parent.astype(jnp.int32))
+
+
+def compact_capacity(cfg: GrowerConfig, n: int) -> int:
+    """Rows the gather-compaction buffer holds for `n` (per-shard) rows
+    under `cfg`, 0 where grow_tree keeps every pass on the full kernel
+    (see the notes above its use). Also read on the host, to tell full
+    from compacted passes in `pass_rows` (telemetry.layers.split_passes)."""
+    if not (bool(cfg.hist_compact) and cfg.feature_axis is None
+            and float(cfg.compact_fraction) > 0.0
+            and n % cfg.chunk == 0 and n >= 2 * cfg.chunk):
+        return 0
+    cap = max(1, int(n * min(float(cfg.compact_fraction), 1.0)))
+    cap = min(n, ((cap + cfg.chunk - 1) // cfg.chunk) * cfg.chunk)
+    return cap if cap >= cfg.chunk else 0
 
 
 def shard_group_widths(group_widths, num_shards: int):

@@ -48,6 +48,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.layers import scope
+
 
 def _hi_lo(w):
     """Split f32 into two bf16s with hi+lo ~= w to f32 precision."""
@@ -330,6 +332,7 @@ def _quant_merge(hist, quantize, f, num_bins, c_ids=None):
 
 @functools.partial(jax.jit, static_argnames=("num_bins", "chunk", "bf16",
                                              "group_widths", "quantize"))
+@scope("lgbm/hist/contract")
 def leaf_histogram(binned: jnp.ndarray, weights: jnp.ndarray,
                    num_bins: int, chunk: int = 16384,
                    bf16: bool = True, n_valid=None,
@@ -399,6 +402,7 @@ def leaf_histogram(binned: jnp.ndarray, weights: jnp.ndarray,
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "chunk", "bf16",
                                     "group_widths", "quantize"))
+@scope("lgbm/hist/contract")
 def batched_leaves_histogram(binned: jnp.ndarray, weights: jnp.ndarray,
                              leaf_id: jnp.ndarray, ids: jnp.ndarray,
                              num_bins: int, chunk: int = 16384,
@@ -471,6 +475,7 @@ def batched_leaves_histogram(binned: jnp.ndarray, weights: jnp.ndarray,
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "chunk", "bf16",
                                     "group_widths", "quantize"))
+@scope("lgbm/hist/contract")
 def gathered_leaves_histogram(binned: jnp.ndarray, weights: jnp.ndarray,
                               leaf_id: jnp.ndarray, rows: jnp.ndarray,
                               ids: jnp.ndarray, num_bins: int,
@@ -518,9 +523,13 @@ def gathered_leaves_histogram(binned: jnp.ndarray, weights: jnp.ndarray,
     def one(c):
         r = jax.lax.dynamic_slice(rows, (c * chunk,), (chunk,))
         live = (c * chunk + jnp.arange(chunk, dtype=jnp.int32)) < nv
-        w_chunk = jnp.where(live[:, None], weights[r], 0.0)
-        b_rows = binned[r]                                     # [chunk, F]
-        member = (leaf_id[r][:, None] == ids[None, :]) \
+        def take(a):
+            with scope("lgbm/hist/gather"):
+                return a[r]
+
+        w_chunk = jnp.where(live[:, None], take(weights), 0.0)
+        b_rows = take(binned)                                  # [chunk, F]
+        member = (take(leaf_id)[:, None] == ids[None, :]) \
             & live[:, None]                                    # [C, K]
         if q:
             u = _quant_u(w_chunk, quantize, member)

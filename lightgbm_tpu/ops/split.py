@@ -28,6 +28,7 @@ from typing import NamedTuple
 import jax.numpy as jnp
 
 from ..binning import MISSING_NAN, MISSING_NONE, MISSING_ZERO
+from ..telemetry.layers import scope
 
 K_EPSILON = 1e-15
 K_MIN_SCORE = -jnp.inf
@@ -77,6 +78,7 @@ def leaf_output(sum_g, sum_h, l1: float, l2: float):
     return -jnp.sign(sum_g) * reg / (sum_h + l2)
 
 
+@scope("lgbm/split/scan")
 def find_best_splits(hist: jnp.ndarray,
                      parent_sum_g: jnp.ndarray,
                      parent_sum_h: jnp.ndarray,

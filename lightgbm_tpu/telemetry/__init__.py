@@ -1,10 +1,16 @@
 """Unified telemetry subsystem (subsumes the old flat `tracing.py`).
 
-Four pieces, one import surface:
+Six pieces, one import surface:
 
 - `metrics` — labeled counters/gauges/bucketed histograms + span-scoped
-  timers (`span(name, block=...)` charges async device work via
-  block_until_ready). Zero-allocation when disabled.
+  timers of host time (`span(name, **ids)`), which also land in the
+  profiler's trace while a profiler session is open and never wait for
+  the device. Zero-allocation when disabled.
+- `layers` — the names of this program's layers: `jax.named_scope`
+  names inside the jitted programs (`scope()`), the host spans of one
+  boosting iteration, and `TreeRecord`, the per-tree `pass_log` entry.
+- `devtrace` — from a profiler trace (`.xplane.pb`) to device seconds by
+  scope, host seconds by span and idle gaps by span.
 - `runlog` — the structured JSONL run log: header + one record per
   boosting iteration + events + summary, written alongside PR 3's
   checkpoints so a preempted run leaves a readable trail.
@@ -23,6 +29,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
+from .layers import ITER_SPANS, SCOPES, TreeRecord, scope
 from .metrics import (DEFAULT_TIME_BUCKETS, Counter, Gauge, Histogram,
                       Registry, block, counter_add, current_site, enable,
                       enabled, gauge_set, heartbeat, observe, registry,
@@ -34,6 +41,7 @@ from .runlog import (SCHEMA_VERSION, RunLog, TrainRecorder, read_records,
 __all__ = [
     "DEFAULT_TIME_BUCKETS", "Counter", "Gauge", "Histogram", "Registry",
     "RunLog", "TrainRecorder", "CompileObserver", "SCHEMA_VERSION",
+    "ITER_SPANS", "SCOPES", "TreeRecord", "scope",
     "active_recorder", "block", "counter_add", "current_site", "enable",
     "enabled", "gauge_set", "heartbeat", "observe", "observer",
     "install_observer", "registry", "reset", "read_records",

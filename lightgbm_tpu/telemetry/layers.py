@@ -1,0 +1,116 @@
+"""The names this program gives its layers, in one place.
+
+Three vocabularies, each read by `telemetry/devtrace.py` and by the
+benchmark's per-layer readers:
+
+- `SCOPES` — `jax.named_scope` names inside the jitted programs
+  (`scope(name)`). A scope is trace-time metadata only: it lands in the
+  HLO's `op_name` and from there in the profiler's device events, so a
+  device operation can be charged to the layer that emitted it whatever
+  number XLA gives its fusion. It adds no operation and changes no
+  fusion; jax's persistent compile cache keys on the program WITHOUT this
+  metadata, so an executable cached before the scopes existed is loaded
+  as it was, names missing.
+- `ITER_SPANS` — host spans of one boosting iteration
+  (`telemetry.span(name, iteration=i)`), written into the profiler's own
+  trace so they share a clock with the device. They tell HOST time: none
+  of them waits for the device except `lgbm/iter/fetch`, which IS the
+  wait.
+- `TreeRecord` — the per-tree entry of `GBDT.pass_log`.
+"""
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+SCOPES = (
+    "lgbm/gradients",         # objective gradients (boosting/gbdt.py)
+    "lgbm/grow/root_hist",    # the root's full pass and its totals
+    "lgbm/grow/select",       # gain ranking, top_k, child slot allocation
+    "lgbm/grow/relabel",      # route(): rows of the selected nodes -> children
+    "lgbm/grow/compact_index",  # member mask, cumsum, scatter of row indices
+    "lgbm/hist/gather",       # bins and (g, h, w) gathered through the index
+    "lgbm/hist/contract",     # the one-hot contraction, full or gathered
+    "lgbm/hist/merge",        # data-axis psum / psum_scatter of histograms
+    "lgbm/grow/subtract",     # larger child = parent - smaller
+    "lgbm/split/scan",        # ops/split.find_best_splits and its vmaps
+    "lgbm/grow/table",        # node-table and histogram-cache writes
+    "lgbm/grow/commit",       # the drain: frontier argmax -> tree node
+    "lgbm/grow/finalize",     # slot-map hops, rows -> committed leaf slots
+    "lgbm/score/update",      # leaf values onto the training score
+)
+
+ITER_SPANS = (
+    "lgbm/iter/gradients",
+    "lgbm/iter/bagging",
+    "lgbm/iter/dispatch",     # the enqueue of the grow(+update) program
+    "lgbm/iter/fetch",        # device_get of a tree's small state: the wait
+    "lgbm/iter/build_tree",   # Tree.from_grower_state, shrinkage, bookkeeping
+)
+
+PREFIX = "lgbm/"
+UNSCOPED = "unscoped"
+
+
+class scope:
+    """`jax.named_scope(name)` for a name of `SCOPES`, and no other; a
+    context manager, or a decorator that opens it around every call."""
+
+    def __init__(self, name: str):
+        if name not in SCOPES:
+            raise KeyError(f"{name!r} is not in telemetry.layers.SCOPES")
+        self.name = name
+        self._cm = None
+
+    def __enter__(self):
+        import jax
+        self._cm = jax.named_scope(self.name)
+        return self._cm.__enter__()
+
+    def __exit__(self, *exc):
+        return self._cm.__exit__(*exc)
+
+    def __call__(self, fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with scope(self.name):
+                return fn(*args, **kwargs)
+        return scoped
+
+
+class TreeRecord(NamedTuple):
+    """One tree's schedule economics and host timing. The first five
+    fields are the historical `pass_log` tuple, in its order; readers
+    that index `e[0]`, `e[2]` or take `list(e)` keep working.
+
+    Counts come from state the tree fetch already carries (`pass_rows`,
+    the rows each pass contracted) and the static schedule; the three
+    times are `perf_counter` differences on the host. Under a data axis
+    `pass_rows` is summed over shards that each choose their own path, so
+    full/compact is then told by the summed capacity and is exact only
+    when the shards agree."""
+    num_passes: int
+    table_high_water: int
+    rows_contracted: float
+    comm_elems: float
+    comm_bytes: float
+    full_passes: int = 0        # contraction ran over all valid rows
+    compact_passes: int = 0     # contraction ran over a gathered subset
+    rows_indexed: int = 0       # padded rows scanned by index builds
+    rows_gathered: int = 0      # rows the compacted passes contracted
+    dispatch_s: float = 0.0     # train_one_iter entry -> grow enqueue returned
+    fetch_wait_s: float = 0.0   # the device_get of this tree's small state
+    build_tree_s: float = 0.0   # end of the fetch -> tree appended
+
+
+def split_passes(pass_rows, num_passes: int, cap: int):
+    """(full_passes, compact_passes, rows_gathered) from the per-pass row
+    counts. `cap` is the compaction buffer's capacity in rows, 0 where
+    the schedule has compaction off. Pass 0 is the root's and always
+    full; a later pass was compacted iff it contracted at most `cap`
+    rows (the grower takes the gathered path iff the members fit the
+    buffer, and records the valid-row count otherwise)."""
+    later = [int(r) for r in pass_rows[1:num_passes]]
+    gathered = [r for r in later if cap and r <= cap]
+    return (min(num_passes, 1) + len(later) - len(gathered),
+            len(gathered), sum(gathered))

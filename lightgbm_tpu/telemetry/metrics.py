@@ -6,12 +6,14 @@ replacement the ROADMAP items need: labeled counters/gauges and BUCKETED
 histograms (serving latency as a real p50/p95/p99 distribution, not a
 running mean, following the per-phase accounting of the GBDT accelerator
 literature — XGBoost-GPU 1806.11248 §5, booster accelerators
-2011.02022 §4), plus `span()` timers that charge asynchronously
-dispatched device work to the right phase via `block_until_ready`.
+2011.02022 §4), plus `span()` timers of HOST time that also land in the
+profiler's trace, on the device's clock, whenever a profiler session is
+open. A span never waits for the device.
 
-Cost discipline: with telemetry disabled every entry point is a single
-flag test returning a module-level singleton — no allocation, no locks
-(tests/test_telemetry.py probes the disabled path with tracemalloc).
+Cost discipline: with telemetry disabled and no profiler session open
+every entry point is a flag test returning a module-level singleton — no
+allocation, no locks (tests/test_telemetry.py probes the disabled path
+with tracemalloc).
 Enabled-path instruments append to plain dict/float slots under the GIL;
 the only lock taken per event is the histogram's (shared with the
 serving threads).
@@ -23,6 +25,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, Iterable, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 LabelItems = Tuple[Tuple[str, str], ...]
 
@@ -313,15 +317,16 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Wall-clock span charged to a phase accumulator. `block` is an
-    optional array/pytree block_until_ready'd before the clock stops, so
-    async device work lands in the right phase."""
+    """Enabled-path span: host wall-clock charged to a phase
+    accumulator, and the same interval written into the profiler's trace
+    (a no-op while no profiler session is open). It never waits for the
+    device: device time is the device trace's to tell."""
 
-    __slots__ = ("name", "block", "t0")
+    __slots__ = ("name", "ann", "t0")
 
-    def __init__(self, name: str, block=None):
+    def __init__(self, name: str, ids):
         self.name = name
-        self.block = block
+        self.ann = TraceAnnotation(name, **ids)
         self.t0 = 0.0
 
     def __enter__(self):
@@ -329,32 +334,37 @@ class _Span:
         if stack is None:
             stack = _local.spans = []
         stack.append(self.name)
+        self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        try:
-            if self.block is not None:
-                import jax
-                jax.block_until_ready(self.block)
-        finally:
-            acc = _registry.phase(self.name)
-            acc.total += time.perf_counter() - self.t0
-            acc.count += 1
-            _local.spans.pop()
+        acc = _registry.phase(self.name)
+        acc.total += time.perf_counter() - self.t0
+        acc.count += 1
+        self.ann.__exit__(*exc)
+        _local.spans.pop()
         return False
 
 
-def span(name: str, block=None):
-    """Context manager timing a named phase (tracing.phase semantics);
-    returns the shared no-op singleton when disabled."""
-    if not _enabled:
-        return _NULL_SPAN
-    return _Span(name, block)
+def span(name: str, **ids):
+    """Context manager around one named piece of host work. It always
+    lands in the profiler's trace as a `TraceAnnotation` carrying `ids`
+    (e.g. `iteration=i`: spans of one tree share it) when a profiler
+    session is open; it accumulates host seconds into the registry
+    (tracing.phase semantics) only when telemetry is enabled. With both
+    off it is the shared no-op singleton."""
+    if _enabled:
+        return _Span(name, ids)
+    if TraceAnnotation.is_enabled():
+        return TraceAnnotation(name, **ids)
+    return _NULL_SPAN
 
 
 def block(x):
-    """Block on device values inside an open span (when enabled)."""
+    """block_until_ready when telemetry is enabled, for scripts that time
+    a device result by hand. Never called under a span of the training
+    path: that would serialise the pipelined loop it measures."""
     if _enabled and x is not None:
         import jax
         jax.block_until_ready(x)

@@ -7,8 +7,9 @@ compilation — `/jax/core/compile/backend_compile_duration` fires once
 per backend compile with its wall time — but carries no clue WHICH
 jitted entry point compiled. This observer supplies the attribution:
 compile events are charged to the innermost open telemetry span
-(`metrics.current_site()` — `tree/grow`, `predict/dispatch`, ...), so
-the run log can say "iteration 0 spent 31s compiling under tree/grow".
+(`metrics.current_site()` — `lgbm/iter/dispatch`, `predict/dispatch`,
+...), so the run log can say "iteration 0 spent 31s compiling under
+lgbm/iter/dispatch".
 
 Retrace counting: the first compile at a site is the expected trace;
 every further one is a RETRACE (a new input signature reached the same

@@ -3,24 +3,22 @@
 This module used to hold the TIMETAG-style global accumulators
 (reference `gbdt.cpp:53-62`); the real implementation now lives in
 `lightgbm_tpu/telemetry/` (labeled registry, run log, compile observer,
-Prometheus export). Every historical entry point keeps its exact
-signature and semantics:
+Prometheus export). The historical entry points that still have a
+caller keep their names:
 
-- `phase(name, block=...)` — span-scoped wall timer (block_until_ready
-  on `block` before the clock stops)
+- `phase(name)` — span-scoped timer of host time (it never waits for
+  the device)
 - `counter(name, value)` / `counters()` — accumulate / read
   `{name: (total, events)}`
 - `totals()` — `{phase: (seconds, count)}`
 - `enable/enabled/reset/dump/block` — as before; `LGBM_TPU_TIMETAG=1`
   still enables at import and dumps at exit
-- `trace_to(dir)` — jax.profiler xplane trace wrapper
 
 New code should import `lightgbm_tpu.telemetry` directly.
 """
 from __future__ import annotations
 
 import atexit
-import contextlib
 from typing import Dict, Tuple
 
 from . import telemetry as _t
@@ -50,17 +48,9 @@ def counters() -> Dict[str, Tuple[float, int]]:
     return out
 
 
-def phase(name: str, block=None):
-    """Accumulate wall time under `name` (telemetry.span)."""
-    return _t.span(name, block=block)
-
-
-@contextlib.contextmanager
-def trace_to(trace_dir: str):
-    """jax.profiler trace wrapper; writes an xplane.pb artifact."""
-    import jax
-    with jax.profiler.trace(trace_dir):
-        yield
+def phase(name: str):
+    """Accumulate host time under `name` (telemetry.span)."""
+    return _t.span(name)
 
 
 @atexit.register

@@ -1,175 +1,130 @@
-"""Profile one training run on the attached accelerator and write the
-kernel-level evidence for the histogram path (VERDICT r1 item 8: prove the
-one-hot contraction fuses — no materialized [chunk, F, B] intermediate —
-and measure the histogram op's effective bandwidth).
+"""Trace N warm training iterations of one configuration on the attached
+accelerator and print where the device and the host spent them, by this
+program's layers (`lightgbm_tpu/telemetry/devtrace.py` does the
+reduction; this file only drives the run).
 
-Writes:
-  profiles/train_profile.json — top device ops by total time + the
-      isolated histogram-op timing with effective HBM GB/s
-  profiles/README.md          — human summary
-  profiles/trace/             — the raw jax.profiler xplane artifact
+    python scripts/profile_train.py --config benchmarks/configs/higgs-10m5x28.json
 
-Usage: python scripts/profile_train.py
+`--config` is a benchmark configuration file (`rows`, `features`,
+`params`, `generator`); `--rows` overrides its row count, for a
+rehearsal. The layer table goes to standard output and the whole
+reduction, with the per-tree records of the traced trees, to
+`<out>/<name>.json` (`--out`, default `chiprun_out/`, the directory the
+chip tool brings back; `--name`, default `profile_train`). `--stats N`
+also prints every stat of the first N device events, and `--keep-trace`
+keeps the `.xplane.pb`: the by-hand look that tells which stat carries
+the scope on a new libtpu (`devtrace.SCOPE_STATS`).
 """
 from __future__ import annotations
 
-import collections
-import glob
+import argparse
 import json
 import os
+import shutil
 import sys
-import time
+import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 
-import numpy as np  # noqa: E402
 
-
-def main():
-    import jax
-    import jax.numpy as jnp
-
-    import lightgbm_tpu as lgb
-    from lightgbm_tpu.ops import histogram as hist_ops
-
-    out_dir = os.path.join(REPO, "profiles")
-    trace_dir = os.path.join(out_dir, "trace")
-    os.makedirs(trace_dir, exist_ok=True)
-
-    # --- IN-TRAINING histogram pass cost --------------------------------
-    # measured through the grower itself with fresh gradients each rep:
-    # the runtime content-caches identical dispatches, and isolated
-    # microbenchmarks compile to different buffer placements than the
-    # training loop, so only the in-loop number is honest
-    from lightgbm_tpu.learner.grow import FMETA_KEYS, GrowerConfig, make_grower
-    N, F, B, K = 524288, 28, 64, 12
-    chunk = 32768
-    rng = np.random.RandomState(0)
-    binned = jnp.asarray(rng.randint(0, B, size=(N, F)).astype(np.uint8))
-    fmeta = {"num_bin": jnp.full(F, B, jnp.int32),
-             "missing_type": jnp.zeros(F, jnp.int32),
-             "default_bin": jnp.zeros(F, jnp.int32),
-             "is_categorical": jnp.zeros(F, bool),
-             "group": jnp.arange(F, dtype=jnp.int32),
-             "offset": jnp.zeros(F, jnp.int32),
-             "is_bundled": jnp.zeros(F, bool)}
-    cfg = GrowerConfig(num_leaves=255, max_bins=B, chunk=chunk,
-                       lambda_l1=0.0, lambda_l2=0.0, min_gain_to_split=0.0,
-                       min_data_in_leaf=1, min_sum_hessian_in_leaf=100.0,
-                       max_depth=-1, batch_k=K)
-    grower = make_grower(cfg)
-    ones = jnp.ones(N, jnp.float32)
-    fmask = jnp.ones(F, bool)
-
-    def grow_once(i):
-        g = (binned[:, i % F] / (B / 2.0) - 1.0).astype(jnp.float32) \
-            + 0.3 * jnp.asarray(rng.randn(N).astype(np.float32))
-        st = grower(binned, g, ones, ones, fmask, fmeta)
-        jax.block_until_ready(st.node_feature)
-        return int(st.num_passes)
-
-    grow_once(0)  # compile
-    t0 = time.perf_counter()
-    passes = sum(grow_once(i) for i in range(1, 4))
-    tree_s = (time.perf_counter() - t0) / 3
-    hist_s = (time.perf_counter() - t0) / passes  # upper bound per pass
-    # bytes one pass MUST move if the one-hot is fused: read binned (u8)
-    # + weights + leaf ids + bits once, write [2K, F, B, 3] f32
-    essential_bytes = (N * F * 1 + N * 3 * 4 + N * 4 + N * 1
-                       + 2 * K * F * B * 3 * 4)
-    # bytes if the one-hot were materialized in HBM instead (bf16
-    # [chunk, F, B] written + read per chunk, both bf16 passes)
-    onehot_bytes = 2 * 2 * N * F * B * 2
-    eff_gbs = essential_bytes / hist_s / 1e9
-
-    # --- profiled training iteration ------------------------------------
-    X = np.asarray(rng.randn(N, F), np.float32)
-    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float32)
-    params = {"objective": "binary", "verbose": -1, "max_bin": 63,
-              "num_leaves": 255, "min_sum_hessian_in_leaf": 100.0,
-              "min_data_in_leaf": 1}
-    ds = lgb.Dataset(X, y, params=dict(params))
-    warm = lgb.train(dict(params), ds, num_boost_round=2,
-                     verbose_eval=False)
-    with jax.profiler.trace(trace_dir):
-        lgb.train(dict(params), ds, num_boost_round=3, verbose_eval=False)
-
+def print_stats(path: str, count: int) -> None:
     from jax.profiler import ProfileData
-    pbs = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                           recursive=True))
-    tot = collections.Counter()
-    cnt = collections.Counter()
-    device_total_ns = 0
-    for pb in pbs[-1:]:
-        pd = ProfileData.from_serialized_xspace(open(pb, "rb").read())
-        for plane in pd.planes:
-            if "TPU" not in plane.name and "tpu" not in plane.name \
-                    and "GPU" not in plane.name:
+    from lightgbm_tpu.telemetry import devtrace
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            print(f"plane {plane.name!r} line {line.name!r}: "
+                  f"{len(list(line.events))} events", file=sys.stderr)
+            if not (plane.name.startswith("/device:")
+                    and line.name.lower() == devtrace.OP_LINE):
                 continue
-            for line in plane.lines:
-                for ev in line.events:
-                    tot[ev.name] += ev.duration_ns
-                    cnt[ev.name] += 1
-                    device_total_ns += ev.duration_ns
+            for ev in list(line.events)[:count]:
+                print(json.dumps({"name": ev.name, "stats": {
+                    k: (v if isinstance(v, (int, float, str)) else repr(v))
+                    for k, v in ev.stats}})[:2000], file=sys.stderr)
 
-    top = [{"op": name[:120], "total_ms": round(ns / 1e6, 3),
-            "count": cnt[name]} for name, ns in tot.most_common(20)]
-    result = {
-        "platform": jax.devices()[0].platform,
-        "histogram_op": {
-            "rows": N, "features": F, "bins": B, "children": 2 * K,
-            "chunk": chunk,
-            "seconds_per_tree": round(tree_s, 4),
-            "passes_per_tree": round(passes / 3, 1),
-            "seconds_per_pass_upper_bound": round(hist_s, 6),
-            "essential_bytes_per_pass": essential_bytes,
-            "effective_gb_per_s_lower_bound": round(eff_gbs, 1),
-            "materialized_onehot_bytes": onehot_bytes,
-            "onehot_fused": bool(hist_s * eff_gbs * 1e9 < onehot_bytes / 4),
-        },
-        "top_device_ops": top,
-    }
-    with open(os.path.join(out_dir, "train_profile.json"), "w") as fh:
-        json.dump(result, fh, indent=1)
 
-    fused_note = ("each pass moves far fewer bytes than a materialized "
-                  "one-hot would require, so the one-hot feeds the "
-                  "contraction without an HBM intermediate"
-                  if result["histogram_op"]["onehot_fused"] else
-                  "WARNING: timing is consistent with a materialized "
-                  "one-hot intermediate")
-    with open(os.path.join(out_dir, "README.md"), "w") as fh:
-        fh.write(f"""# Training profile ({result['platform']})
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--config", default=os.path.join(
+        REPO, "benchmarks", "configs", "higgs-10m5x28.json"))
+    ap.add_argument("--rows", type=int, default=0)
+    ap.add_argument("--seed", type=int, default=7)
+    ap.add_argument("--warmup", type=int, default=2)
+    ap.add_argument("--iterations", type=int, default=2)
+    ap.add_argument("--out", default=os.path.join(REPO, "chiprun_out"))
+    ap.add_argument("--stats", type=int, default=0)
+    ap.add_argument("--param", action="append", default=[],
+                    metavar="KEY=VALUE", help="override one parameter of "
+                    "the configuration (an experiment, not the cell)")
+    ap.add_argument("--name", default="profile_train")
+    ap.add_argument("--keep-trace", action="store_true",
+                    help="copy the .xplane.pb beside the JSON")
+    args = ap.parse_args(argv)
 
-Generated by `python scripts/profile_train.py`. All timings are measured
-THROUGH the jitted tree grower with fresh inputs per repetition — the
-runtime content-caches identical dispatches and isolated microbenchmarks
-compile to different buffer placements, so naive op timings mislead.
+    import jax
+    import datagen
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.telemetry import devtrace
 
-## Histogram passes (batched_leaves_histogram, in-training)
+    # jax's persistent compile cache keys a program WITHOUT its metadata,
+    # so an executable cached before a scope was added or renamed would be
+    # loaded as it was, names missing. A profile is only as good as its
+    # names: key on the metadata here (one compile the first time).
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
-- {N} rows x {F} features x {B} bins, {2 * K} child histograms/pass
-- **{tree_s:.3f} s per 255-leaf tree**, {passes / 3:.0f} data passes/tree
-  -> **<= {hist_s * 1e3:.2f} ms/pass** (tree time / passes; includes the
-  split scans and commit bookkeeping riding the same loop)
-- effective bandwidth >= **{eff_gbs:.0f} GB/s** over the essential
-  {essential_bytes / 1e6:.0f} MB/pass (binned matrix + weights + outputs)
-- a materialized bf16 one-hot would move >= {onehot_bytes / 1e9:.1f} GB
-  per pass; {fused_note}
+    with open(args.config) as fh:
+        config = json.load(fh)
+    rows = args.rows or int(config["rows"])
+    config["params"].update(kv.split("=", 1) for kv in args.param)
+    X, y = datagen.generator(config["generator"])(
+        rows, int(config["features"]), args.seed)
+    booster = lgb.Booster(dict(config["params"]),
+                          lgb.Dataset(X, y, params=dict(config["params"])))
+    inner = booster._inner
 
-## Top device ops (3 boosting iterations)
+    def drain():
+        booster.current_iteration()       # flushes the pipelined tree
+        jax.block_until_ready(inner._score)
 
-| total ms | count | op |
-|---|---|---|
-""")
-        for row in top[:12]:
-            fh.write(f"| {row['total_ms']} | {row['count']} "
-                     f"| `{row['op'][:80]}` |\n")
-    print(json.dumps(result["histogram_op"]))
-    for row in top[:8]:
-        print(row)
+    for _ in range(args.warmup):
+        booster.update()
+    drain()
+    trace_dir = tempfile.mkdtemp(prefix="profile_train_")
+    try:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(args.iterations):
+                booster.update()
+            drain()
+        xplane = devtrace.newest_xplane(trace_dir)
+        if args.stats:
+            print_stats(xplane, args.stats)
+        os.makedirs(args.out, exist_ok=True)
+        if args.keep_trace:
+            shutil.copy(xplane, os.path.join(args.out,
+                                             args.name + ".xplane.pb"))
+        reduced = devtrace.reduce_xplane(xplane)
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+
+    dev = jax.devices()[0]
+    reduced.update({
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": jax.device_count()},
+        "rows": rows, "features": int(config["features"]),
+        "params": config["params"],
+        "traced_trees": [r._asdict()
+                         for r in inner.pass_log[args.warmup:]],
+    })
+    with open(os.path.join(args.out, args.name + ".json"), "w") as fh:
+        json.dump(reduced, fh, indent=1)
+    print(f"{dev.platform} {dev.device_kind}: {rows} x {config['features']},"
+          f" {args.iterations} traced iterations, device busy "
+          f"{reduced['busy_s']:.4f} s of {reduced['window_s']:.4f} s")
+    print(devtrace.layer_table(reduced))
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())
