@@ -218,14 +218,14 @@ class Smoke:
               and info["groups"] == FEATURES,
               f"histogram width is not {FEATURES} x max_bin=63: {sched}")
         check(info["subtract"], f"sibling subtraction not selected: {sched}")
-        # compaction needs >= 2 row chunks (gbdt.py schedule selection):
-        # always true at the default rows, not at a CPU rehearsal's
-        two_chunks = info["rows_padded"] >= 2 * info["chunk"]
-        check(two_chunks or self.args.rows < DEFAULT_ROWS,
-              f"default rows must span >= 2 chunks: {sched}")
-        check(info["compact"] == two_chunks,
-              f"gather-compaction selected={info['compact']} with "
-              f"{info['rows_padded']} padded rows / chunk {info['chunk']}")
+        # at this width a full pass is cheaper than the index build of a
+        # compacted one (grow.compact_threshold), so the schedule keeps
+        # every pass full and the program has no compacted branch
+        check(info["compact_model"]["fraction"] == 0.0
+              and not info["compact"],
+              f"gather-compaction selected={info['compact']} at "
+              f"{FEATURES} x 63, where the pass-cost model says "
+              f"{info['compact_model']}")
         check(len(leaves) == ITERATIONS and min(leaves) > 1,
               f"expected {ITERATIONS} trees that all split, got {leaves}")
         check(all(np.isfinite(losses))

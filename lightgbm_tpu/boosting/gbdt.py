@@ -24,7 +24,8 @@ from .. import telemetry
 from ..testing import faults
 from ..config import Config
 from ..dataset import Dataset, Metadata
-from ..learner.grow import GrowerConfig, compact_capacity, grow_tree
+from ..learner.grow import (COMPACT_FRACTION_MAX, GrowerConfig,
+                            compact_capacity, compact_threshold, grow_tree)
 from ..metrics import Metric, create_metric, default_metric_for_objective
 from ..objectives import ObjectiveFunction
 from ..ops.predict import predict_leaf_binned, predict_value_binned
@@ -791,27 +792,33 @@ class GBDT:
         # the measured multiclass optimum is a smaller table
         table_mult = min(12, mult_fit) if subtract else \
             (6 if k_cls > 1 else 12)
-        # gather-compacted small-node contraction: on wherever rows are
-        # locally resident (serial + data/voting learners); the grower
-        # additionally refuses it under feature_axis. The threshold is a
-        # pure scheduling choice — for any value the grown trees match
-        # the full-pass grower on order-invariant sums (grow.py notes).
-        # Single-chunk runs have nothing to skip — the gather would only
-        # add a second compiled kernel per signature — so the
-        # auto-schedule keeps them on the full pass (measured: the win
-        # is already 2.3x at 2 chunks / 100k CPU rows, see
-        # profiles/README.md). Multiclass is excluded like subtraction:
-        # the vmap over class trees batches the per-pass cond predicate,
-        # which under jax's cond batching rule executes BOTH histogram
-        # kernels every pass — a strict pessimization.
+        # gather-compacted small-node contraction: wherever rows are
+        # locally resident (serial + data/voting learners; the grower
+        # additionally refuses it under feature_axis) and the shape's
+        # pass costs say it can pay. The threshold is a pure scheduling
+        # choice — for any value the grown trees match the full-pass
+        # grower on order-invariant sums (grow.py notes) — so unless the
+        # user gave one it is the break-even of a full pass against an
+        # index build plus gathers (grow.compact_threshold: the constants
+        # are chip readings, PERF.md section 6, PR 27). A narrow table
+        # never reaches it: at 28 groups x 63 bins the index build alone
+        # costs 2.7 full passes, and the fraction comes out 0.
+        # Single-chunk runs have nothing to skip. Multiclass is excluded
+        # like subtraction: the vmap over class trees batches the
+        # per-pass cond predicate, which under jax's cond batching rule
+        # executes BOTH histogram kernels every pass.
         # the grower re-guards on PER-SHARD rows (each shard compacts its
-        # own block), so gate on the same quantity or the schedule log
-        # would claim compact=True while the grower silently declines
-        shard_rows = self._n_pad
+        # own block), so model and gate the same quantity or the schedule
+        # log would claim compact=True while the grower silently declines
+        shards = 1
         if self._tree_learner_kind in ("data", "voting"):
-            shard_rows = self._n_pad // max(
-                1, local_dev if nproc > 1 else ndev)
-        compact_frac = float(self.config.tree.tpu_compact_threshold)
+            shards = max(1, local_dev if nproc > 1 else ndev)
+        shard_rows = self._n_pad // shards
+        compact_costs = compact_threshold(
+            g_cnt, self._max_bins, n // shards, shard_rows)
+        compact_frac = (float(self.config.tree.tpu_compact_threshold)
+                        if "tpu_compact_threshold" in self.config.raw_params
+                        else compact_costs.fraction)
         compact = (self.config.tree.tpu_hist_compact
                    and compact_frac > 0.0
                    and self._tree_learner_kind != "feature"
@@ -824,6 +831,8 @@ class GBDT:
             subtract = _os.environ["LGBM_TPU_FORCE_SUBTRACT"] == "1"
         if _os.environ.get("LGBM_TPU_FORCE_COMPACT"):   # debug override
             compact = _os.environ["LGBM_TPU_FORCE_COMPACT"] == "1"
+            if compact and compact_frac <= 0.0:  # forced past the model
+                compact_frac = COMPACT_FRACTION_MAX
         if "tpu_batch_k" in self.config.raw_params:
             batch_k = self.config.tree.tpu_batch_k
         elif subtract:
@@ -884,10 +893,12 @@ class GBDT:
             # domain, where trees are bit-identical for ANY batch_k.
             batch_k = max(1, (batch_k * 5) // 3)
         log.info("Schedule: groups=%d max_bin=%d wide=%s subtract=%s "
-                 "compact=%s@%.2f batch_k=%d table_mult=%d chunk=%d "
+                 "compact=%s@%.3f (ns a row: full=%.1f index=%.1f "
+                 "gather=%.1f) batch_k=%d table_mult=%d chunk=%d "
                  "quantize=%s qmax=%d",
                  g_cnt, self._max_bins, wide, subtract, compact,
-                 compact_frac, batch_k, table_mult, self._chunk,
+                 compact_frac, compact_costs.full_ns, compact_costs.index_ns,
+                 compact_costs.gather_ns, batch_k, table_mult, self._chunk,
                  quant_mode, quant_qmax)
         # execution-schedule summary for the telemetry run-log header
         # (telemetry/runlog.py): the knobs that explain this run's pass
@@ -905,6 +916,9 @@ class GBDT:
             "groups": int(g_cnt), "max_bin": int(self._max_bins),
             "wide": bool(wide), "subtract": bool(subtract),
             "compact": bool(compact), "compact_fraction": compact_frac,
+            # the pass-cost model's answer for this (per-shard) shape,
+            # beside what was used: they differ when the user set one
+            "compact_model": compact_costs._asdict(),
             "batch_k": int(batch_k), "table_mult": int(table_mult),
             "chunk": int(self._chunk), "rows": int(n),
             "rows_padded": int(n_pad),

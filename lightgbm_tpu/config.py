@@ -357,7 +357,9 @@ class TreeConfig:
     tpu_hist_compact: bool = True
     # switch threshold and compaction-buffer capacity as a row fraction
     # (rounded up to a chunk multiple; >= 1.0 forces compaction,
-    # <= 0 disables it)
+    # <= 0 disables it). Honoured when the user gives it; unset, the
+    # schedule takes the break-even of the shape's pass costs, at most
+    # 0.25 and 0 on a narrow table (learner/grow.compact_threshold)
     tpu_compact_threshold: float = 0.25
     # data-parallel histogram merge collective (parallel/learners.py +
     # learner/grow.py): "scatter" (default) ReduceScatters the per-pass

@@ -282,7 +282,13 @@ DESCRIPTIONS = {
     "tpu_compact_threshold": "row fraction below which a pass takes the "
                              "compacted path (also sizes the gather "
                              "buffer; >= 1.0 forces compaction, <= 0 "
-                             "disables it)",
+                             "disables it). When unset it is chosen from "
+                             "the shape's pass costs: the break-even of "
+                             "a full pass against an index build plus "
+                             "gathers, at most 0.25, and 0 (no "
+                             "compaction) on narrow tables such as 28 "
+                             "features x 63 bins; an explicit value is "
+                             "used as given",
     "tpu_hist_reduce": "data-parallel histogram merge collective: "
                        "scatter (default) ReduceScatters the histogram "
                        "over the stored-group axis so each device owns "

@@ -10,6 +10,7 @@ import pytest
 import jax.numpy as jnp
 
 import lightgbm_tpu as lgb
+import lightgbm_tpu.basic  # noqa: F401  (`lgb.basic` resolves only once imported)
 from lightgbm_tpu.learner.grow import GrowerConfig, grow_tree
 
 

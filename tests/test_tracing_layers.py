@@ -86,7 +86,7 @@ def lowered():
     """Debug text of the serial programs (compaction and subtraction on)
     and of the data-parallel grower; the serial text also without debug
     info, once with scopes and once with `jax.named_scope` made a no-op."""
-    inner = _booster(tpu_hist_chunk=2048)._inner
+    inner = _booster(tpu_hist_chunk=2048, tpu_compact_threshold=0.25)._inner
     assert inner._grower_cfg.hist_compact and inner._grower_cfg.hist_subtract
     grads, grow = _lower_serial(inner)
     out = {"serial": grads.as_text(debug_info=True)
@@ -281,9 +281,15 @@ def test_split_passes(pass_rows, num_passes, cap, want):
     assert layers.split_passes(pass_rows, num_passes, cap) == want
 
 
-@pytest.mark.parametrize("chunk,compacts", [(2048, True), (65536, False)])
-def test_tree_record_identities(chunk, compacts):
-    booster = _booster(tpu_hist_chunk=chunk)
+@pytest.mark.parametrize("params,compacts", [
+    ({"tpu_hist_chunk": 2048, "tpu_compact_threshold": 0.25}, True),
+    ({"tpu_hist_chunk": 65536, "tpu_compact_threshold": 0.25}, False),
+    # unset: 8 groups x 63 bins is narrower than any table whose full
+    # pass costs more than the index build (grow.compact_threshold)
+    ({"tpu_hist_chunk": 2048}, False),
+])
+def test_tree_record_identities(params, compacts):
+    booster = _booster(**params)
     for _ in range(3):
         booster.update()
     booster.current_iteration()
