@@ -170,7 +170,13 @@ def synth_higgs(n, f, seed=0):
 
 def synth_epsilon(n, f=2000, seed=1):
     """Epsilon-like: dense WIDE float features (Epsilon is 400k x 2000
-    normalized dense). Exercises the group-block-tiled histogram pass."""
+    normalized dense). Exercises the group-block-tiled histogram pass.
+
+    This is the original, for `bench.py`'s own legs. The benchmark's copy
+    is `benchmarks/generators/synth_epsilon.py`: the same label model,
+    seeded otherwise (there the seed orders the columns of one fixed data
+    set, here it draws the values and the weights), so the two give
+    different data for the same seed and are not to be compared."""
     rng = np.random.RandomState(seed)
     X = rng.randn(n, f).astype(np.float32)
     w = rng.randn(24)

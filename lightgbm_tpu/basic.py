@@ -204,7 +204,12 @@ class Dataset:
                 if self.label is None and label is not None:
                     self.label = label
                 data = arr
-        else:
+        elif not (isinstance(data, np.ndarray) and data.ndim == 2
+                  and data.dtype == np.float32):
+            # a float32 table stays as it is: ingest widens each chunk as
+            # it bins it (float32 -> float64 is exact, so the bins are the
+            # same), where a float64 copy of 1M x 2000 floats was 16.8 GB
+            # of host memory and a third of construct() (PERF.md, PR 28)
             data = _data_to_2d(data)
         if self.used_indices is not None:
             data = data[self.used_indices]

@@ -14,7 +14,7 @@ import contextlib
 import copy
 import functools
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -24,8 +24,9 @@ from .. import telemetry
 from ..testing import faults
 from ..config import Config
 from ..dataset import Dataset, Metadata
-from ..learner.grow import (COMPACT_FRACTION_MAX, GrowerConfig,
-                            compact_capacity, compact_threshold, grow_tree)
+from ..learner.grow import (COMPACT_FRACTION_MAX, CompactChoice,
+                            GrowerConfig, compact_capacity,
+                            compact_threshold, grow_tree)
 from ..metrics import Metric, create_metric, default_metric_for_objective
 from ..objectives import ObjectiveFunction
 from ..ops.predict import predict_leaf_binned, predict_value_binned
@@ -38,6 +39,107 @@ _K_EPSILON = 1e-15
 # Deliberately modest: a near-HBM-sized cache (Epsilon-shape at 2 GiB
 # measured) thrashes the while-loop carry and stalls training outright
 _SUBTRACT_CACHE_BUDGET = 256 << 20
+
+
+class Schedule(NamedTuple):
+    """`pick_schedule`'s answer: what `GBDT.init` hands the grower."""
+    wide: bool                 # groups x bins > 8192: channel-cost-bound
+    subtract: bool             # the sibling-subtraction histogram cache
+    table_mult: int            # node-table slots per configured leaf
+    compact: bool              # gather-compacted small-node passes
+    compact_fraction: float    # of the padded rows (grow.compact_capacity)
+    compact_model: CompactChoice   # the pass-cost model's own answer
+    batch_k: int               # nodes expanded per histogram pass
+
+
+def pick_schedule(groups: int, max_bins: int, rows: int, rows_padded: int,
+                  chunk: int, *, num_leaves: int, classes: int = 1,
+                  learner: str = "serial", bundled: bool = False,
+                  hist_subtract: bool = True, hist_compact: bool = True,
+                  compact_fraction: Optional[float] = None,
+                  batch_k: Optional[int] = None,
+                  table_mult: Optional[int] = None,
+                  force_subtract: Optional[bool] = None,
+                  force_compact: Optional[bool] = None) -> Schedule:
+    """The execution schedule as a function of the shape: stored groups,
+    bins of the widest group, rows and padded rows of ONE shard, and the
+    histogram chunk (`ingest.landing.plan_row_layout` gives the last
+    two). Trees are bit-identical for any `batch_k`; subtraction and
+    compaction only change float32 summation order. The keyword
+    arguments after `bundled` are what the user set (`tpu_hist_subtract`,
+    `tpu_hist_compact`, `tpu_compact_threshold`, `tpu_batch_k`) or forced
+    for debugging; None leaves the choice to the shape.
+
+    "Wide" shapes (large groups x bins) are channel-cost-bound in the
+    histogram contraction (the [G*B, chunk] x [chunk, S] matmul's FLOPs
+    scale with S), narrow ones MXU-tile-bound: Bosch-shape (~22k)
+    measured fastest at narrow batches, HIGGS/Expo (~2k) at full-tile
+    ones."""
+    wide = groups * max_bins > 8192
+    # sibling subtraction: the per-node [M, G, B, 3] histogram cache must
+    # fit the budget (vmap'd class trees each carry their own cache).
+    # Node-table size rides the same budget: generous tables keep
+    # late-boosting speculation wide (grow.py table notes) — use the
+    # largest table_mult in [4, 12] whose cache still fits; without the
+    # cache the table is [M]-scalar cheap, so take the max.
+    slot_bytes = classes * groups * max_bins * 3 * 4
+    mult_fit = int((_SUBTRACT_CACHE_BUDGET // max(slot_bytes, 1) - 52)
+                   // max(num_leaves, 1))
+    subtract = (hist_subtract
+                and learner == "serial"
+                # vmap'd class trees each carry a cache: the x classes
+                # scatter/memory traffic measured a net LOSS on the
+                # multiclass shape (0.62 vs 0.89 Mrow-iters/s)
+                and classes == 1
+                and mult_fit >= 6)
+    if table_mult is None:
+        # vmap'd class trees multiply every [M]-sized table op by the
+        # classes: the measured multiclass optimum is a smaller table
+        table_mult = min(12, mult_fit) if subtract else \
+            (6 if classes > 1 else 12)
+    # gather-compacted small-node contraction: wherever rows are locally
+    # resident (serial + data/voting learners; the grower additionally
+    # refuses it under feature_axis) and the shape's pass costs say it
+    # can pay. The threshold is a pure scheduling choice — for any value
+    # the grown trees match the full-pass grower on order-invariant sums
+    # (grow.py notes) — so unless the user gave one it is the break-even
+    # of a full pass against an index build plus gathers
+    # (grow.compact_threshold: the constants are chip readings, PERF.md
+    # section 6, PR 27). A narrow table never reaches it: at 28 groups x
+    # 63 bins the index build alone costs 2.7 full passes, and the
+    # fraction comes out 0. Single-chunk runs have nothing to skip.
+    # Multiclass is excluded like subtraction: the vmap over class trees
+    # batches the per-pass cond predicate, which under jax's cond
+    # batching rule executes BOTH histogram kernels every pass.
+    model = compact_threshold(groups, max_bins, rows, rows_padded)
+    if compact_fraction is None:
+        compact_fraction = model.fraction
+    compact = (hist_compact
+               and compact_fraction > 0.0
+               and learner != "feature"
+               and classes == 1
+               and rows_padded >= 2 * chunk)
+    if force_subtract is not None:
+        subtract = force_subtract
+    if force_compact is not None:
+        compact = force_compact
+        if compact and compact_fraction <= 0.0:  # forced past the model
+            compact_fraction = COMPACT_FRACTION_MAX
+    if batch_k is None:
+        if subtract:
+            # one smaller-child channel set per node: 25*(3+2) fills the
+            # 128-lane tile; wide shapes stay narrow (channel-cost-bound
+            # passes + depth-bound trees — K=8 matches the channel cost
+            # of the round-4 K=4 direct path while expanding 2x nodes)
+            batch_k = 8 if wide else 24
+        else:
+            # Bosch-class data (wide AND heavily EFB-bundled — sparse
+            # one-hot blocks) measured fastest at K=4: deep depth-bound
+            # trees, channel-cost-bound passes. Unbundled wide shapes
+            # (Epsilon) keep the full-tile default.
+            batch_k = 4 if (wide and bundled) else 12
+    return Schedule(wide, subtract, int(table_mult), compact,
+                    float(compact_fraction), model, int(batch_k))
 
 
 _forest_jit_cache: Dict[str, object] = {}
@@ -759,95 +861,42 @@ class GBDT:
             log.warning("tpu_hist_pallas is retired: the hand-written "
                         "kernel measured slower than the XLA path "
                         "(profiles/README.md); using the XLA kernels")
-        # --- execution-schedule auto-selection ----------------------------
-        # (bit-identical trees for any batch_k; subtraction/compaction only
-        # change f32 summation order). "wide" shapes (large groups*bins)
-        # are channel-cost-bound in the histogram contraction, narrow
-        # shapes are MXU-tile-bound — different best batch widths.
+        # --- execution-schedule auto-selection (pick_schedule, above) ------
+        # the grower re-guards compaction on PER-SHARD rows (each shard
+        # compacts its own block), so model and gate the same quantity or
+        # the schedule log would claim compact=True while the grower
+        # silently declines
         L_cfg = self.config.tree.num_leaves
         g_cnt = max(1, int(train_data.num_groups))
-        # "wide" = the histogram contraction is channel-cost-bound (the
-        # [G*B, chunk] x [chunk, S] matmul's FLOPs scale with S) rather
-        # than tile-bound; Bosch-shape (~22k) measured fastest at narrow
-        # batches, HIGGS/Expo (~2k) at full-tile ones
-        wide = g_cnt * self._max_bins > 8192
         k_cls = self.num_tree_per_iteration
-        # sibling subtraction: per-node [M, G, B, 3] histogram cache must
-        # fit the budget (vmap'd class trees each carry their own cache).
-        # Node-table size rides the same budget: generous tables keep
-        # late-boosting speculation wide (grow.py table notes) — use the
-        # largest table_mult in [4, 12] whose cache still fits; without
-        # the cache the table is [M]-scalar cheap, so take the max.
-        slot_bytes = k_cls * g_cnt * self._max_bins * 3 * 4
-        mult_fit = int((_SUBTRACT_CACHE_BUDGET // max(slot_bytes, 1) - 52)
-                       // max(L_cfg, 1))
-        subtract = (self.config.tree.tpu_hist_subtract
-                    and self._tree_learner_kind == "serial"
-                    # vmap'd class trees each carry a cache: the x k_cls
-                    # scatter/memory traffic measured a net LOSS on the
-                    # multiclass shape (0.62 vs 0.89 Mrow-iters/s)
-                    and k_cls == 1
-                    and mult_fit >= 6)
-        # vmap'd class trees multiply every [M]-sized table op by k_cls:
-        # the measured multiclass optimum is a smaller table
-        table_mult = min(12, mult_fit) if subtract else \
-            (6 if k_cls > 1 else 12)
-        # gather-compacted small-node contraction: wherever rows are
-        # locally resident (serial + data/voting learners; the grower
-        # additionally refuses it under feature_axis) and the shape's
-        # pass costs say it can pay. The threshold is a pure scheduling
-        # choice — for any value the grown trees match the full-pass
-        # grower on order-invariant sums (grow.py notes) — so unless the
-        # user gave one it is the break-even of a full pass against an
-        # index build plus gathers (grow.compact_threshold: the constants
-        # are chip readings, PERF.md section 6, PR 27). A narrow table
-        # never reaches it: at 28 groups x 63 bins the index build alone
-        # costs 2.7 full passes, and the fraction comes out 0.
-        # Single-chunk runs have nothing to skip. Multiclass is excluded
-        # like subtraction: the vmap over class trees batches the
-        # per-pass cond predicate, which under jax's cond batching rule
-        # executes BOTH histogram kernels every pass.
-        # the grower re-guards on PER-SHARD rows (each shard compacts its
-        # own block), so model and gate the same quantity or the schedule
-        # log would claim compact=True while the grower silently declines
         shards = 1
         if self._tree_learner_kind in ("data", "voting"):
             shards = max(1, local_dev if nproc > 1 else ndev)
-        shard_rows = self._n_pad // shards
-        compact_costs = compact_threshold(
-            g_cnt, self._max_bins, n // shards, shard_rows)
-        compact_frac = (float(self.config.tree.tpu_compact_threshold)
-                        if "tpu_compact_threshold" in self.config.raw_params
-                        else compact_costs.fraction)
-        compact = (self.config.tree.tpu_hist_compact
-                   and compact_frac > 0.0
-                   and self._tree_learner_kind != "feature"
-                   and k_cls == 1
-                   and shard_rows >= 2 * self._chunk)
         import os as _os
-        if _os.environ.get("LGBM_TPU_TABLE_MULT"):      # debug override
-            table_mult = int(_os.environ["LGBM_TPU_TABLE_MULT"])
-        if _os.environ.get("LGBM_TPU_FORCE_SUBTRACT"):  # debug override
-            subtract = _os.environ["LGBM_TPU_FORCE_SUBTRACT"] == "1"
-        if _os.environ.get("LGBM_TPU_FORCE_COMPACT"):   # debug override
-            compact = _os.environ["LGBM_TPU_FORCE_COMPACT"] == "1"
-            if compact and compact_frac <= 0.0:  # forced past the model
-                compact_frac = COMPACT_FRACTION_MAX
-        if "tpu_batch_k" in self.config.raw_params:
-            batch_k = self.config.tree.tpu_batch_k
-        elif subtract:
-            # one smaller-child channel set per node: 25*(3+2) fills the
-            # 128-lane tile; wide shapes stay narrow (channel-cost-bound
-            # passes + depth-bound trees — K=8 matches the channel cost
-            # of the round-4 K=4 direct path while expanding 2x nodes)
-            batch_k = 8 if wide else 24
-        else:
-            # Bosch-class data (wide AND heavily EFB-bundled — sparse
-            # one-hot blocks) measured fastest at K=4: deep depth-bound
-            # trees, channel-cost-bound passes. Unbundled wide shapes
-            # (Epsilon) keep the full-tile default.
-            bundled = g_cnt < 0.8 * max(1, train_data.num_features)
-            batch_k = 4 if (wide and bundled) else 12
+        raw = self.config.raw_params
+
+        def forced(name):                   # debug override: "1", "0", unset
+            value = _os.environ.get(name)
+            return (value == "1") if value else None
+        picked = pick_schedule(
+            g_cnt, self._max_bins, n // shards, self._n_pad // shards,
+            self._chunk, num_leaves=L_cfg, classes=k_cls,
+            learner=self._tree_learner_kind,
+            bundled=g_cnt < 0.8 * max(1, train_data.num_features),
+            hist_subtract=self.config.tree.tpu_hist_subtract,
+            hist_compact=self.config.tree.tpu_hist_compact,
+            compact_fraction=(float(self.config.tree.tpu_compact_threshold)
+                              if "tpu_compact_threshold" in raw else None),
+            batch_k=(self.config.tree.tpu_batch_k
+                     if "tpu_batch_k" in raw else None),
+            table_mult=(int(_os.environ["LGBM_TPU_TABLE_MULT"])  # debug
+                        if _os.environ.get("LGBM_TPU_TABLE_MULT") else None),
+            force_subtract=forced("LGBM_TPU_FORCE_SUBTRACT"),
+            force_compact=forced("LGBM_TPU_FORCE_COMPACT"))
+        wide, subtract, table_mult = (picked.wide, picked.subtract,
+                                      picked.table_mult)
+        compact, compact_frac = picked.compact, picked.compact_fraction
+        compact_costs, batch_k = picked.compact_model, picked.batch_k
         # --- quantized-gradient training (tpu_hist_quantize, ISSUE 20) ---
         from ..ops.histogram import TRAIN_QUANTIZE_MODES, train_qmax
         quant_mode = str(self.config.tree.tpu_hist_quantize or "none").lower()

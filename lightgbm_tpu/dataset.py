@@ -122,6 +122,9 @@ class Dataset:
         self.device_binned = None
         self.device_layout = None
         self._num_rows: int = 0
+        # telemetry.ConstructRecord: host seconds of the build by phase
+        # (ingest/build.build_inner); None for a dataset made otherwise
+        self.construct_record = None
 
     # ------------------------------------------------------------------
     @classmethod

@@ -16,6 +16,8 @@ construction bit-for-bit (tests/test_ingest.py holds the matrix).
 """
 from __future__ import annotations
 
+import contextlib
+import time
 from typing import Callable, List, Optional, Sequence
 
 import numpy as np
@@ -28,6 +30,30 @@ from .landing import HostLanding
 #: feature-count floor for parallel per-feature binning inside a chunk
 _POOL_MIN_FEATURES = 4
 _POOL_MIN_ROWS = 100_000
+
+
+class _Phases:
+    """Host seconds of one build by `telemetry.DATASET_SPANS` name: a
+    `perf_counter` pair around each phase, always taken, inside the host
+    span of the same name (a no-op unless telemetry or a profiler session
+    is on). Nothing here waits for the device."""
+
+    def __init__(self):
+        self.seconds = dict.fromkeys(telemetry.DATASET_SPANS, 0.0)
+
+    @contextlib.contextmanager
+    def __call__(self, name: str):
+        t = time.perf_counter()
+        try:
+            with telemetry.span(name):
+                yield
+        finally:
+            self.seconds[name] += time.perf_counter() - t
+
+    def record(self, values: int) -> "telemetry.ConstructRecord":
+        return telemetry.ConstructRecord(
+            *(self.seconds[name] for name in telemetry.DATASET_SPANS),
+            values=int(values))
 
 
 def build_inner(source: ChunkSource, *,
@@ -64,6 +90,7 @@ def build_inner(source: ChunkSource, *,
     ds.feature_names = list(feature_names) if feature_names is not None \
         else [f"Column_{i}" for i in range(f)]
     telemetry.counter_add("ingest/builds", 1)
+    phase = _Phases()
 
     # ------------------------------------------------------------- pass 1
     if reference is not None:
@@ -75,14 +102,15 @@ def build_inner(source: ChunkSource, *,
         ds.groups = reference.groups
         sketch = None
     else:
-        sketch = sketch_pass(
-            source, max_bin=max_bin, min_data_in_bin=min_data_in_bin,
-            min_split_data=min_split_data,
-            bin_construct_sample_cnt=bin_construct_sample_cnt,
-            seed=data_random_seed,
-            categorical_features=categorical_features,
-            use_missing=use_missing, zero_as_missing=zero_as_missing,
-            mappers=list(mappers) if mappers is not None else None)
+        with phase("lgbm/dataset/sketch"):
+            sketch = sketch_pass(
+                source, max_bin=max_bin, min_data_in_bin=min_data_in_bin,
+                min_split_data=min_split_data,
+                bin_construct_sample_cnt=bin_construct_sample_cnt,
+                seed=data_random_seed,
+                categorical_features=categorical_features,
+                use_missing=use_missing, zero_as_missing=zero_as_missing,
+                mappers=list(mappers) if mappers is not None else None)
         ds.mappers = sketch.mappers
         ds.used_features = [j for j, m in enumerate(ds.mappers)
                             if not m.is_trivial]
@@ -98,13 +126,14 @@ def build_inner(source: ChunkSource, *,
     # ------------------------------------------------ EFB bundle layout
     if ds.groups is None:
         from ..efb import find_groups_sampled
-        sample_cols = bin_sample_columns(sketch, used)
-        ds.groups = find_groups_sampled(
-            sample_cols, default_bins, num_bins,
-            enable_bundle=enable_bundle,
-            max_conflict_rate=max_conflict_rate,
-            sparse_threshold=sparse_threshold)
-        del sample_cols
+        with phase("lgbm/dataset/groups"):
+            sample_cols = bin_sample_columns(sketch, used)
+            ds.groups = find_groups_sampled(
+                sample_cols, default_bins, num_bins,
+                enable_bundle=enable_bundle,
+                max_conflict_rate=max_conflict_rate,
+                sparse_threshold=sparse_threshold)
+            del sample_cols
     if sketch is not None:
         sketch.efb_rows = None  # free the sample before landing rows
 
@@ -129,7 +158,7 @@ def build_inner(source: ChunkSource, *,
     collect_raw = keep_raw and not isinstance(source, ArraySource)
     raw_blocks: List[np.ndarray] = []
     try:
-        with telemetry.span("ingest/pass2"):
+        with phase("lgbm/dataset/bin"), telemetry.span("ingest/pass2"):
             lo = 0
             for chunk, chunk_labels in source.chunks():
                 m = len(chunk)
@@ -152,11 +181,11 @@ def build_inner(source: ChunkSource, *,
             if lo != n:
                 log.fatal("Source reported %d rows but streamed %d"
                           % (n, lo))
+            landed = landing.finish()
     finally:
         if pool is not None:
             pool.shutdown()
 
-    landed = landing.finish()
     if isinstance(landed, np.ndarray):
         ds.binned = landed
     else:  # device-resident (ShardedLanding): row-padded jax.Array
@@ -183,6 +212,8 @@ def build_inner(source: ChunkSource, *,
         ds.metadata.set_group(group)
     if init_score is not None:
         ds.metadata.set_init_score(init_score)
+    ds.construct_record = phase.record(n * len(used))
+    telemetry.record_construct(ds.construct_record)
     return ds
 
 

@@ -70,8 +70,12 @@ class ArraySource(ChunkSource):
         data = np.asarray(data)
         if data.ndim != 2:
             log.fatal("ArraySource needs a 2-dimensional matrix")
-        # float64 once (copy only if the dtype differs), chunk views after
-        self.data = data.astype(np.float64, copy=False)
+        # float64 once (copy only if the dtype differs), chunk views
+        # after. float32 is kept: its consumers (the row gatherers of pass
+        # 1, `BinMapper.values_to_bins` in pass 2) widen what they touch,
+        # exactly, so a wide table is never held twice
+        self.data = data if data.dtype == np.float32 \
+            else data.astype(np.float64, copy=False)
         self.chunk_rows = max(1, int(chunk_rows))
 
     def num_rows(self) -> int:

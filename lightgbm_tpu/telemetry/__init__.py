@@ -8,7 +8,10 @@ Six pieces, one import surface:
   the device. Zero-allocation when disabled.
 - `layers` — the names of this program's layers: `jax.named_scope`
   names inside the jitted programs (`scope()`), the host spans of one
-  boosting iteration, and `TreeRecord`, the per-tree `pass_log` entry.
+  boosting iteration and of one dataset construction, `TreeRecord`, the
+  per-tree `pass_log` entry, and `ConstructRecord`, the phases of one
+  dataset's build (its `construct_record`; `last_construct()` for the
+  one this process built last).
 - `devtrace` — from a profiler trace (`.xplane.pb`) to device seconds by
   scope, host seconds by span and idle gaps by span.
 - `runlog` — the structured JSONL run log: header + one record per
@@ -29,7 +32,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-from .layers import ITER_SPANS, SCOPES, TreeRecord, scope
+from .layers import (DATASET_SPANS, ITER_SPANS, SCOPES, ConstructRecord,
+                     TreeRecord, scope)
 from .metrics import (DEFAULT_TIME_BUCKETS, Counter, Gauge, Histogram,
                       Registry, block, counter_add, current_site, enable,
                       enabled, gauge_set, heartbeat, observe, registry,
@@ -41,7 +45,8 @@ from .runlog import (SCHEMA_VERSION, RunLog, TrainRecorder, read_records,
 __all__ = [
     "DEFAULT_TIME_BUCKETS", "Counter", "Gauge", "Histogram", "Registry",
     "RunLog", "TrainRecorder", "CompileObserver", "SCHEMA_VERSION",
-    "ITER_SPANS", "SCOPES", "TreeRecord", "scope",
+    "DATASET_SPANS", "ITER_SPANS", "SCOPES", "ConstructRecord",
+    "TreeRecord", "scope", "last_construct", "record_construct",
     "active_recorder", "block", "counter_add", "current_site", "enable",
     "enabled", "gauge_set", "heartbeat", "observe", "observer",
     "install_observer", "registry", "reset", "read_records",
@@ -63,6 +68,22 @@ def set_active_recorder(rec: Optional["TrainRecorder"]) -> None:
 
 def active_recorder() -> Optional["TrainRecorder"]:
     return _ACTIVE_RECORDER
+
+
+# the `construct_record` of the dataset this process built last
+# (ingest/build.py writes it): for a caller that no longer holds the
+# dataset, as a benchmark reader after the run. Any later build replaces
+# it, a validation set's too: who holds the dataset reads the dataset's
+_LAST_CONSTRUCT: Optional[ConstructRecord] = None
+
+
+def record_construct(rec: ConstructRecord) -> None:
+    global _LAST_CONSTRUCT
+    _LAST_CONSTRUCT = rec
+
+
+def last_construct() -> Optional[ConstructRecord]:
+    return _LAST_CONSTRUCT
 
 
 def start_run(gbdt, params: Dict[str, Any]) -> Optional[TrainRecorder]:

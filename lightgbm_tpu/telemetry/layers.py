@@ -16,6 +16,9 @@ benchmark's per-layer readers:
   trace so they share a clock with the device. They tell HOST time: none
   of them waits for the device except `lgbm/iter/fetch`, which IS the
   wait.
+- `DATASET_SPANS` — host spans of one `Dataset` construction
+  (`ingest/build.build_inner`), by phase. Their seconds are also kept,
+  always, as the `ConstructRecord` on the dataset they built.
 - `TreeRecord` — the per-tree entry of `GBDT.pass_log`.
 """
 from __future__ import annotations
@@ -46,6 +49,12 @@ ITER_SPANS = (
     "lgbm/iter/dispatch",     # the enqueue of the grow(+update) program
     "lgbm/iter/fetch",        # device_get of a tree's small state: the wait
     "lgbm/iter/build_tree",   # Tree.from_grower_state, shrinkage, bookkeeping
+)
+
+DATASET_SPANS = (
+    "lgbm/dataset/sketch",    # pass 1 over the rows, bin finding per column
+    "lgbm/dataset/groups",    # the sample binned, efb.find_groups_sampled
+    "lgbm/dataset/bin",       # pass 2: value-to-bin, bundling, the landing
 )
 
 PREFIX = "lgbm/"
@@ -101,6 +110,19 @@ class TreeRecord(NamedTuple):
     dispatch_s: float = 0.0     # train_one_iter entry -> grow enqueue returned
     fetch_wait_s: float = 0.0   # the device_get of this tree's small state
     build_tree_s: float = 0.0   # end of the fetch -> tree appended
+
+
+class ConstructRecord(NamedTuple):
+    """Host seconds of one `ingest/build.build_inner` by phase, one field
+    per name of `DATASET_SPANS`: `perf_counter` differences that are
+    always taken (three pairs a dataset) and never wait for the device.
+    It is the `construct_record` of the dataset it tells of. The upload
+    of a host-landed matrix is the trainer's (`GBDT.init`) and is in none
+    of them."""
+    sketch_s: float
+    groups_s: float
+    bin_s: float
+    values: int                 # rows x used columns, what pass 2 binned
 
 
 def split_passes(pass_rows, num_passes: int, cap: int):

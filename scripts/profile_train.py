@@ -23,6 +23,7 @@ import os
 import shutil
 import sys
 import tempfile
+import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -66,6 +67,7 @@ def main(argv=None) -> int:
     import jax
     import datagen
     import lightgbm_tpu as lgb
+    from lightgbm_tpu import telemetry
     from lightgbm_tpu.telemetry import devtrace
 
     # jax's persistent compile cache keys a program WITHOUT its metadata,
@@ -80,9 +82,24 @@ def main(argv=None) -> int:
     config["params"].update(kv.split("=", 1) for kv in args.param)
     X, y = datagen.generator(config["generator"])(
         rows, int(config["features"]), args.seed)
-    booster = lgb.Booster(dict(config["params"]),
-                          lgb.Dataset(X, y, params=dict(config["params"])))
+    # set-up, as the benchmark's `dataset.construct_s` splits it: the
+    # host's seconds in `construct()` (its phases are the ConstructRecord)
+    # and from there to the binned matrix being on the device (`Booster`:
+    # row padding, upload, GBDT.init)
+    jax.devices()   # the client's start-up (~8 s on the chip) is not set-up
+    t = time.perf_counter()
+    ds = lgb.Dataset(X, y, params=dict(config["params"])).construct()
+    construct_host_s = time.perf_counter() - t
+    t = time.perf_counter()
+    booster = lgb.Booster(dict(config["params"]), ds)
     inner = booster._inner
+    jax.block_until_ready(inner._binned)
+    setup = dict(ds._lazy_init().construct_record._asdict(),
+                 construct_host_s=construct_host_s,
+                 booster_to_device_s=time.perf_counter() - t)
+    print("set-up, host seconds: " + ", ".join(
+        f"{k} {v:.2f}" if k != "values" else f"{v} values"
+        for k, v in setup.items()), flush=True)
 
     def drain():
         booster.current_iteration()       # flushes the pipelined tree
@@ -116,6 +133,11 @@ def main(argv=None) -> int:
         "params": config["params"],
         "traced_trees": [r._asdict()
                          for r in inner.pass_log[args.warmup:]],
+        # the dataset layer's host phases (set-up, not in the trace) and
+        # the schedule the program picked for this shape
+        "construct": setup,
+        "schedule": {k: v for k, v in inner._schedule_info.items()
+                     if k != "grower"},
     })
     with open(os.path.join(args.out, args.name + ".json"), "w") as fh:
         json.dump(reduced, fh, indent=1)

@@ -1,0 +1,377 @@
+"""The wide dense deployment (`epsilon-400kx2000`, cell
+`epsilon-train-1chip`) at a size a test run can hold: the schedule the
+program picks for such a shape, the benchmark's generator, the dataset
+layer's `ConstructRecord`, the readers the cell brought, and the program
+against the benchmark's plain reference on a table wide enough to take
+the cell's KIND of schedule (no subtraction cache, gather-compaction on)
+with no `tpu_*` option. Nothing here is a device measurement."""
+import json
+import os
+import sys
+import time
+
+import numpy as np
+import pytest
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu import telemetry
+from lightgbm_tpu.boosting.gbdt import pick_schedule
+from lightgbm_tpu.ingest.landing import plan_row_layout
+from lightgbm_tpu.learner.grow import COMPACT_FRACTION_MAX
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmarks")
+for path in (ROOT, BENCH):
+    if path not in sys.path:
+        sys.path.insert(0, path)
+
+import datagen  # noqa: E402
+import run as harness  # noqa: E402
+
+CELL = "epsilon-train-1chip"
+
+
+def _config():
+    with open(os.path.join(BENCH, "configs", "epsilon-400kx2000.json")) as fh:
+        return json.load(fh)
+
+
+# ---------------------------------------------------------------------------
+# (b) the schedule as a function of the shape, no data built
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("rows", [1_048_576, 1_310_720, "the configuration's"])
+def test_the_cells_shape_takes_the_wide_schedule(rows):
+    config = _config()
+    if not isinstance(rows, int):
+        rows = int(config["rows"])
+    groups, bins = int(config["features"]), int(config["params"]["max_bin"])
+    layout = plan_row_layout(rows, groups, bins)
+    assert layout.chunk == 8192
+    assert layout.n_pad == rows, "the rung leaves no padded rows"
+    picked = pick_schedule(groups, bins, rows, layout.n_pad, layout.chunk,
+                           num_leaves=int(config["params"]["num_leaves"]))
+    assert picked.wide and not picked.subtract
+    assert picked.compact
+    assert picked.compact_fraction == COMPACT_FRACTION_MAX == 0.25
+    assert picked.batch_k == 12 and picked.table_mult == 12
+    assert not any(key.startswith("tpu_") for key in config["params"])
+
+
+def test_the_other_cells_shape_sits_on_the_other_side():
+    """`higgs-train-1chip`: the subtraction cache fits and the index build
+    never pays, so the two cells guard the two sides of both switches."""
+    layout = plan_row_layout(21_000_000, 28, 63)
+    picked = pick_schedule(28, 63, 21_000_000, layout.n_pad, layout.chunk,
+                           num_leaves=255)
+    assert (layout.chunk, layout.n_pad) == (65536, 25_165_824)
+    assert picked.subtract and not picked.compact and not picked.wide
+    assert picked.batch_k == 24 and picked.compact_fraction == 0.0
+
+
+@pytest.mark.parametrize("features,subtract,compact", [
+    (137, True, False), (200, True, True), (224, True, True),
+    (225, False, True), (700, False, True)])
+def test_where_the_two_switches_turn(features, subtract, compact):
+    """At max_bin 63 and 255 leaves the 256 MB cache budget is missed
+    from 225 stored groups, and the pass-cost model compacts from 158."""
+    rows = 1 << 20
+    layout = plan_row_layout(rows, features, 63)
+    picked = pick_schedule(features, 63, rows, layout.n_pad, layout.chunk,
+                           num_leaves=255)
+    assert (picked.subtract, picked.compact) == (subtract, compact)
+
+
+def test_what_the_user_set_wins_over_the_shape():
+    shape = (2000, 63, 1 << 20, 1 << 20, 8192)
+    assert not pick_schedule(*shape, num_leaves=255,
+                             hist_compact=False).compact
+    given = pick_schedule(*shape, num_leaves=255, compact_fraction=0.1,
+                          batch_k=5)
+    assert given.compact and given.compact_fraction == 0.1
+    assert given.batch_k == 5 and given.compact_model.fraction == 0.25
+    # one chunk of rows has nothing to skip
+    assert not pick_schedule(2000, 63, 8192, 8192, 8192,
+                             num_leaves=255).compact
+
+
+# ---------------------------------------------------------------------------
+# (c) the generator
+# ---------------------------------------------------------------------------
+def test_synth_epsilon_reorders_one_data_set():
+    generate = datagen.generator("synth_epsilon")
+    rows, features = 4000, 40
+    Xa, ya = generate(rows, features, 7)
+    Xb, yb = generate(rows, features, 3000000019)
+    assert Xa.dtype == ya.dtype == np.float32 and Xa.shape == (rows, features)
+    np.testing.assert_array_equal(ya, yb)
+    assert not np.array_equal(Xa, Xb)
+    # the same columns, in another order
+    order_a = np.lexsort(Xa[:8])
+    order_b = np.lexsort(Xb[:8])
+    np.testing.assert_array_equal(Xa[:, order_a], Xb[:, order_b])
+    Xc, yc = generate(rows, features, 7, base_seed=99)
+    assert not np.array_equal(np.sort(Xa, axis=1), np.sort(Xc, axis=1))
+    assert not np.array_equal(ya, yc)
+    for y in (ya, yc):
+        assert 0.45 < float(y.mean()) < 0.55
+    # a label the features explain: the 24 linear columns carry it
+    Xd, yd = generate(20000, 30, 1)
+    agree = max(np.mean((Xd[:, j] > 0) == (yd > 0)) for j in range(30))
+    assert agree > 0.6
+
+
+# ---------------------------------------------------------------------------
+# (d) the dataset layer's phases
+# ---------------------------------------------------------------------------
+def test_construct_record_accounts_for_construct():
+    rng = np.random.default_rng(5)
+    X = rng.standard_normal((200_000, 48)).astype(np.float32)
+    X[:, 7] = 1.0                               # a trivial column: unused
+    y = (X[:, 0] > 0).astype(np.float32)
+    params = {"objective": "binary", "max_bin": 63, "verbose": -1}
+    lgb.Dataset(X[:2000], y[:2000], params=params).construct()  # imports
+    t = time.perf_counter()
+    ds = lgb.Dataset(X, y, params=params).construct()
+    host_s = time.perf_counter() - t
+    rec = ds._lazy_init().construct_record
+    assert isinstance(rec, telemetry.ConstructRecord)
+    assert rec is telemetry.last_construct()
+    assert rec._fields == ("sketch_s", "groups_s", "bin_s", "values")
+    assert len(telemetry.DATASET_SPANS) == 3
+    assert rec.values == 200_000 * 47 == X.shape[0] * len(
+        ds._lazy_init().used_features)
+    phases = rec.sketch_s + rec.groups_s + rec.bin_s
+    assert min(rec[:3]) >= 0.0 and rec.sketch_s > 0 and rec.bin_s > 0
+    assert phases <= host_s
+    assert phases >= 0.95 * host_s, (rec, host_s)
+    # a float32 table is binned as it is, chunk by chunk, to the same
+    # bins a float64 copy of it gets
+    wide64 = lgb.Dataset(X[:30000].astype(np.float64), y[:30000],
+                         params=params).construct()._lazy_init()
+    wide32 = lgb.Dataset(X[:30000], y[:30000],
+                         params=params).construct()._lazy_init()
+    np.testing.assert_array_equal(wide32.binned, wide64.binned)
+    for m32, m64 in zip(wide32.mappers, wide64.mappers):
+        np.testing.assert_array_equal(m32.bin_upper_bound,
+                                      m64.bin_upper_bound)
+    # a validation set reuses the training set's bins: no sketch, no
+    # groups; its record is its own, and the training set keeps its own
+    valid = lgb.Dataset(X[:5000], y[:5000], reference=ds).construct()
+    valid = valid._lazy_init().construct_record
+    assert valid.sketch_s == valid.groups_s == 0.0 and valid.bin_s > 0
+    assert valid.values == 5000 * 47
+    assert valid is telemetry.last_construct()
+    assert ds._lazy_init().construct_record is rec
+
+
+def test_dataset_spans_land_in_the_profiler_trace(tmp_path):
+    """Telemetry off: a profiler session alone turns the spans on, and the
+    old names (`ingest/pass1`, `ingest/pass2`) still arrive beside them."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    assert not telemetry.enabled()
+    rng = np.random.default_rng(6)
+    X = rng.standard_normal((3000, 6))
+    with jax.profiler.trace(str(tmp_path)):
+        lgb.Dataset(X, (X[:, 0] > 0).astype(np.float32)).construct()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events}
+    assert set(telemetry.DATASET_SPANS) <= names
+    assert {"ingest/pass1", "ingest/pass2"} <= names
+    # and with telemetry on the same names accumulate host seconds
+    telemetry.enable(True)
+    try:
+        telemetry.reset()
+        lgb.Dataset(X, (X[:, 0] > 0).astype(np.float32)).construct()
+        phases = telemetry.registry().phases
+        assert set(telemetry.DATASET_SPANS) <= set(phases)
+        assert "ingest/pass2" in phases
+    finally:
+        telemetry.reset()
+        telemetry.enable(False)
+
+
+# ---------------------------------------------------------------------------
+# (e) the readers the cell brought
+# ---------------------------------------------------------------------------
+def _reader(name):
+    return datagen.load_file_module(
+        os.path.join(BENCH, "layer_metrics", name + ".py"),
+        "reader_" + name.replace(".", "_")).read
+
+
+def test_the_new_readers_on_a_hand_made_ctx(monkeypatch):
+    rec = telemetry.TreeRecord(30, 700, 5e6, 0.0, 0.0, full_passes=9,
+                               compact_passes=21, rows_indexed=21000,
+                               rows_gathered=1500, dispatch_s=0.25,
+                               fetch_wait_s=4.0, build_tree_s=0.5)
+    other = rec._replace(compact_passes=23, rows_gathered=2500)
+    new = {"rows": 500, "pass_log_window": [list(rec), list(other)]}
+    old = {"rows": 500, "pass_log_window": [list(rec)[:5]]}
+    assert _reader("grower.gathered_per_row")(new) == pytest.approx(4.0)
+    assert _reader("grower.gathered_per_row")(old) is None
+    assert _reader("grower.gathered_per_row")({}) is None
+    # a schedule that compacts nothing reads 0, not nothing
+    full = rec._replace(compact_passes=0, rows_gathered=0)
+    none = {"rows": 500, "pass_log_window": [list(full)]}
+    assert _reader("grower.gathered_per_row")(none) == 0.0
+
+    # the run's table: 500 rows of 40 features, 38 of them used
+    run = {"rows": 500, "features": 40}
+    built = telemetry.ConstructRecord(12.5, 1.0, 80.25, values=500 * 38)
+    monkeypatch.setattr(telemetry, "_LAST_CONSTRUCT", built)
+    assert _reader("dataset.sketch_s")(run) == 12.5
+    assert _reader("dataset.bin_s")(run) == 80.25
+    # another dataset built since (a validation set, a control) is not the
+    # run's: nothing to read, where a wrong number would be
+    for values in (120 * 38, 500 * 41):
+        monkeypatch.setattr(telemetry, "_LAST_CONSTRUCT",
+                            built._replace(values=values))
+        assert _reader("dataset.sketch_s")(run) is None
+    # a program that built no dataset, or has no such record (the parent)
+    monkeypatch.setattr(telemetry, "_LAST_CONSTRUCT", None)
+    assert _reader("dataset.sketch_s")(run) is None
+    monkeypatch.delattr(telemetry, "last_construct")
+    assert _reader("dataset.sketch_s")(run) is None
+    assert _reader("dataset.bin_s")(run) is None
+
+
+def test_benchmark_json_names_the_cell_and_its_readers():
+    bench = harness.load_json(ROOT, "BENCHMARK.json")
+    loaded = harness.load_cell(CELL)
+    assert loaded["entry"]["config"] == "epsilon-400kx2000"
+    assert loaded["entry"]["traffic"] == "train_steady"
+    assert loaded["entry"]["chips"] == 1
+    assert loaded["config"]["rows"] in (1_048_576, 1_310_720)
+    assert loaded["config"]["reduced"] == ["rows"]
+    higgs = harness.load_cell("higgs-train-1chip")["config"]
+    assert loaded["config"]["params"] == higgs["params"]
+    assert loaded["config"]["guarantees"] == higgs["guarantees"]
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[-3:] == ["grower.gathered_per_row",
+                          "dataset.sketch_s", "dataset.bin_s"]
+    for m in bench["per_layer"]:
+        assert "workloads" not in m
+        assert os.path.isfile(os.path.join(BENCH, "layer_metrics",
+                                           m["name"] + ".py"))
+    assert set(loaded["cell"]["limits"]) == set(
+        harness.load_cell("higgs-train-1chip")["cell"]["limits"])
+
+
+@pytest.mark.parametrize("name", ["split_gap", "gain_gap", "leaf_gap",
+                                  "score_gap", "loss_gap", "bin_pop_gap"])
+def test_each_limit_lies_between_this_shapes_two_readings(name):
+    """PERF.md section 2's rule, on the readings the cell's file carries:
+    room over the largest sound reading and under the smallest control
+    reading (or fault reading, where the control has none), and the
+    control over the limit on every data set it ran on."""
+    cell = harness.load_cell(CELL)["cell"]
+    limit, read = cell["limits"][name], cell["limits_set_from"]
+    assert 3 * read["lower_largest_sound_reading"][name] < limit
+    upper = read["upper_smallest_control_reading"][name]
+    faults = [f[name] for f in read["smallest_fault_reading"].values()
+              if name in f]
+    if upper > 0:
+        assert 2.5 * limit < upper
+    else:                               # split_gap: no control reading
+        assert faults and 2.5 * limit < min(faults)
+    assert all(limit < f for f in faults)
+    for value in read.get("control_per_data_set", {}).get(name, []):
+        assert value > limit
+
+
+# ---------------------------------------------------------------------------
+# (a) the program against the plain reference under the cell's KIND of
+# schedule and the cell's limits, as benchmarks/test_correct.py does at
+# HIGGS width
+# ---------------------------------------------------------------------------
+# Wide enough that the program, asked for nothing, leaves the subtraction
+# cache out (225+ stored groups at max_bin 63 and 255 leaves) and turns
+# gather-compaction on (158+ groups AND two histogram chunks of rows: the
+# chunk is 2^30 / (groups x bins) rounded down to a power of two, at
+# least 8,192, so few rows need many features).
+WIDE_FEATURES, WIDE_ROWS = 1056, 16384
+
+
+class _Wide:
+    """One prepared data set shared by the variants, as `readings.py`
+    shares one between a seed's; the sound run is made once."""
+
+    def __init__(self):
+        loaded = harness.load_cell(CELL)
+        self.mode = harness.load_mode(loaded["traffic"])
+        # the cell's traffic with one warm-up step, so that a run is two
+        # steps: the reference follows the first from a zero score and the
+        # window's one tree from the program's score at its opening
+        traffic = dict(loaded["traffic"], warmup_iterations=1)
+        self.base = {
+            "cell": loaded["cell"], "traffic": traffic,
+            "config": dict(loaded["config"], features=WIDE_FEATURES),
+            "seed": 3000000019, "seconds": 0.0, "trace": False,
+            "rows": WIDE_ROWS, "t_start": time.perf_counter(),
+            "limits": loaded["cell"]["limits"], "rehearsal": True}
+        self.prepared = self.mode.prepare(self.base)
+        self._sound = None
+
+    def run(self, **extra):
+        return self.mode.run(dict(self.base, prepared=self.prepared,
+                                  **extra))
+
+    def sound(self):
+        if self._sound is None:
+            self._sound = self.run(control=True)
+        return self._sound
+
+
+@pytest.fixture(scope="module")
+def wide():
+    return _Wide()
+
+
+def test_wide_program_is_correct_and_control_is_not(wide):
+    out = wide.sound()
+    schedule = out["schedule"]
+    assert not schedule["subtract"] and schedule["compact"]
+    assert schedule["compact_fraction"] == 0.25
+    assert (schedule["batch_k"], schedule["chunk"]) == (12, 8192)
+    assert schedule["rows_padded"] == WIDE_ROWS
+    assert out["correct"], out["compared"]
+    assert not out["control_correct"], out["control_compared"]
+    # the mechanism the cell is for did run
+    compact = [e[telemetry.TreeRecord._fields.index("compact_passes")]
+               for e in out["pass_log_window"]]
+    assert min(compact) > 0
+
+
+def test_wide_broken_timed_path_is_not_correct(wide):
+    out = wide.run(fault="half_batch")
+    assert not out["correct"], out["compared"]
+    failed = [n for n, row in out["compared"].items()
+              if not row["value"] <= row["limit"]]
+    assert len(failed) >= 2, out["compared"]
+
+
+def test_wide_trees_do_not_depend_on_compaction(wide):
+    """Compaction is a pure scheduling choice: switched off, the same
+    splits, and leaf values up to float32 summation order."""
+    with_compaction = wide.sound()["trees_window"]
+    params = dict(wide.base["config"]["params"], tpu_hist_compact=False)
+    booster = lgb.Booster(params, wide.prepared["ds"])
+    for _ in range(1 + len(with_compaction)):
+        booster.update()
+    booster.current_iteration()
+    inner = booster._inner
+    assert not inner._schedule_info["compact"]
+    assert not inner._schedule_info["subtract"]
+    assert all(rec.compact_passes == 0 for rec in inner.pass_log)
+    without = [wide.mode.tree_arrays(t) for t in inner.models[1:]]
+    assert len(without) == len(with_compaction) >= 1
+    for a, b in zip(with_compaction, without):
+        for key in ("split_feature", "threshold", "left_child",
+                    "right_child", "internal_count", "leaf_count"):
+            np.testing.assert_array_equal(a[key], b[key])
+        np.testing.assert_allclose(a["leaf_value"], b["leaf_value"],
+                                   rtol=1e-4, atol=1e-7)
