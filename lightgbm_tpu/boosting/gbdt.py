@@ -29,6 +29,7 @@ from ..learner.grow import (COMPACT_FRACTION_MAX, CompactChoice,
                             compact_threshold, grow_tree)
 from ..metrics import Metric, create_metric, default_metric_for_objective
 from ..objectives import ObjectiveFunction
+from ..ops.lookup import row_lookup
 from ..ops.predict import predict_leaf_binned, predict_value_binned
 from ..tree import Tree
 
@@ -322,7 +323,7 @@ def _grow_and_update_impl(score, binned, grad, hess, row_weight, fmask,
                           qscale=None):
     """grow one tree + train-score update, fused into ONE device program.
 
-    The per-tree path (grow -> leaf gather -> score add) is one dispatch
+    The per-tree path (grow -> leaf lookup -> score add) is one dispatch
     that returns only the small tree arrays, so the host pays one
     dispatch + one device_get per tree instead of an eager op chain."""
     import jax.numpy as jnp
@@ -334,7 +335,8 @@ def _grow_and_update_impl(score, binned, grad, hess, row_weight, fmask,
         leaf_vals = state.leaf_value * shrinkage
         delta = jnp.where(
             grew,
-            leaf_vals[jnp.clip(state.leaf_id, 0, cfg.num_leaves - 1)], 0.0)
+            row_lookup(leaf_vals,
+                       jnp.clip(state.leaf_id, 0, cfg.num_leaves - 1)), 0.0)
         score = score.at[cls].add(delta)
     small = {k: getattr(state, k) for k in _SMALL_STATE_KEYS}
     return score, small
@@ -428,8 +430,9 @@ def _grow_and_update_multi_impl(score, binned, grads, hesses, row_weight,
 
     def upd(lv, lid, grew):
         vals = lv * shrinkage
-        return jnp.where(grew,
-                         vals[jnp.clip(lid, 0, cfg.num_leaves - 1)], 0.0)
+        return jnp.where(
+            grew,
+            row_lookup(vals, jnp.clip(lid, 0, cfg.num_leaves - 1)), 0.0)
 
     with telemetry.scope("lgbm/score/update"):
         delta = jax.vmap(upd)(state.leaf_value, state.leaf_id,

@@ -99,6 +99,7 @@ import numpy as np
 from ..binning import MISSING_NAN, MISSING_ZERO
 from ..ops import histogram as hist_ops
 from ..ops import split as split_ops
+from ..ops.lookup import row_lookup
 from ..ops.split import leaf_output
 from ..telemetry.layers import scope
 
@@ -1340,7 +1341,8 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
 
         slot_map, _ = jax.lax.fori_loop(0, hops, hop, (slot_map, anc))
         slot_map = jnp.clip(slot_map, 0, L - 1)
-        leaf_slot_of_row = slot_map[jnp.clip(carry.leaf_id, 0, M - 1)]
+        leaf_slot_of_row = row_lookup(
+            slot_map, jnp.clip(carry.leaf_id, 0, M - 1))
 
     # the contraction counters are per-shard (each shard compacts its own
     # rows and may even take a different path per pass); sum them once so

@@ -43,6 +43,7 @@ from typing import NamedTuple, Tuple
 import numpy as np
 
 from .grow import GrowParams, GrowerConfig, grow_tree
+from ..ops.lookup import row_lookup
 
 MODE_PLAIN = "plain"
 MODE_BAGGING = "bagging"
@@ -202,7 +203,8 @@ class SweepGrower:
 
             def upd(lv, lid, grew):
                 vals = lv * pm_k.shrinkage
-                return jnp.where(grew, vals[jnp.clip(lid, 0, L - 1)], 0.0)
+                return jnp.where(
+                    grew, row_lookup(vals, jnp.clip(lid, 0, L - 1)), 0.0)
 
             delta = jax.vmap(upd)(state.leaf_value, state.leaf_id,
                                   state.num_leaves_used > 1)

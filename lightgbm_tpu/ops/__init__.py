@@ -1,1 +1,1 @@
-from . import histogram, split, predict  # noqa: F401
+from . import histogram, lookup, split, predict  # noqa: F401

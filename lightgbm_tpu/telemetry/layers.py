@@ -39,8 +39,10 @@ SCOPES = (
     "lgbm/split/scan",        # ops/split.find_best_splits and its vmaps
     "lgbm/grow/table",        # node-table and histogram-cache writes
     "lgbm/grow/commit",       # the drain: frontier argmax -> tree node
-    "lgbm/grow/finalize",     # slot-map hops, rows -> committed leaf slots
-    "lgbm/score/update",      # leaf values onto the training score
+    "lgbm/grow/finalize",     # slot-map hops, then rows -> committed leaf
+                              # slots by one-hot lookup (ops/lookup.py)
+    "lgbm/score/update",      # leaf values onto the training score, by
+                              # one-hot lookup
 )
 
 ITER_SPANS = (
