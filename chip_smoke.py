@@ -194,7 +194,7 @@ class Smoke:
 
     def data(self):
         import lightgbm_tpu as lgb
-        from bench import synth_higgs
+        from scripts.synth_data import synth_higgs
         a = self.args
         X, y = synth_higgs(a.rows + a.holdout, FEATURES, seed=a.seed)
         self.X_hold, self.y_hold = X[a.rows:], y[a.rows:]
@@ -219,7 +219,7 @@ class Smoke:
               f"histogram width is not {FEATURES} x max_bin=63: {sched}")
         check(info["subtract"], f"sibling subtraction not selected: {sched}")
         # at this width a full pass is cheaper than the index build of a
-        # compacted one (grow.compact_threshold), so the schedule keeps
+        # compacted one (schedule.compact_threshold), so the schedule keeps
         # every pass full and the program has no compacted branch
         check(info["compact_model"]["fraction"] == 0.0
               and not info["compact"],

@@ -75,7 +75,8 @@ def _device_landing_factory(params: Dict[str, Any]):
         return None
 
     def factory(num_rows, num_groups, dtype, max_group_bin):
-        from .ingest import ShardedLanding, plan_row_layout
+        from .ingest import ShardedLanding
+        from .learner.schedule import plan_row_layout
         layout = plan_row_layout(
             num_rows, num_groups, max_group_bin,
             tpu_hist_chunk=int(params.get("tpu_hist_chunk", 65536)),

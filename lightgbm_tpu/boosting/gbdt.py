@@ -14,7 +14,7 @@ import contextlib
 import copy
 import functools
 import time
-from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -24,9 +24,9 @@ from .. import telemetry
 from ..testing import faults
 from ..config import Config
 from ..dataset import Dataset, Metadata
-from ..learner.grow import (COMPACT_FRACTION_MAX, CompactChoice,
-                            GrowerConfig, compact_capacity,
-                            compact_threshold, grow_tree)
+from ..learner.grow import GrowerConfig, grow_tree
+from ..learner.schedule import (compact_capacity, pick_schedule,
+                                plan_row_layout, schedule_info)
 from ..metrics import Metric, create_metric, default_metric_for_objective
 from ..objectives import ObjectiveFunction
 from ..ops.lookup import row_lookup
@@ -34,114 +34,6 @@ from ..ops.predict import predict_leaf_binned, predict_value_binned
 from ..tree import Tree
 
 _K_EPSILON = 1e-15
-
-# ceiling for the sibling-subtraction histogram cache ([M, G, B, 3] f32
-# per class tree); beyond it the grower builds both children directly.
-# Deliberately modest: a near-HBM-sized cache (Epsilon-shape at 2 GiB
-# measured) thrashes the while-loop carry and stalls training outright
-_SUBTRACT_CACHE_BUDGET = 256 << 20
-
-
-class Schedule(NamedTuple):
-    """`pick_schedule`'s answer: what `GBDT.init` hands the grower."""
-    wide: bool                 # groups x bins > 8192: channel-cost-bound
-    subtract: bool             # the sibling-subtraction histogram cache
-    table_mult: int            # node-table slots per configured leaf
-    compact: bool              # gather-compacted small-node passes
-    compact_fraction: float    # of the padded rows (grow.compact_capacity)
-    compact_model: CompactChoice   # the pass-cost model's own answer
-    batch_k: int               # nodes expanded per histogram pass
-
-
-def pick_schedule(groups: int, max_bins: int, rows: int, rows_padded: int,
-                  chunk: int, *, num_leaves: int, classes: int = 1,
-                  learner: str = "serial", bundled: bool = False,
-                  hist_subtract: bool = True, hist_compact: bool = True,
-                  compact_fraction: Optional[float] = None,
-                  batch_k: Optional[int] = None,
-                  table_mult: Optional[int] = None,
-                  force_subtract: Optional[bool] = None,
-                  force_compact: Optional[bool] = None) -> Schedule:
-    """The execution schedule as a function of the shape: stored groups,
-    bins of the widest group, rows and padded rows of ONE shard, and the
-    histogram chunk (`ingest.landing.plan_row_layout` gives the last
-    two). Trees are bit-identical for any `batch_k`; subtraction and
-    compaction only change float32 summation order. The keyword
-    arguments after `bundled` are what the user set (`tpu_hist_subtract`,
-    `tpu_hist_compact`, `tpu_compact_threshold`, `tpu_batch_k`) or forced
-    for debugging; None leaves the choice to the shape.
-
-    "Wide" shapes (large groups x bins) are channel-cost-bound in the
-    histogram contraction (the [G*B, chunk] x [chunk, S] matmul's FLOPs
-    scale with S), narrow ones MXU-tile-bound: Bosch-shape (~22k)
-    measured fastest at narrow batches, HIGGS/Expo (~2k) at full-tile
-    ones."""
-    wide = groups * max_bins > 8192
-    # sibling subtraction: the per-node [M, G, B, 3] histogram cache must
-    # fit the budget (vmap'd class trees each carry their own cache).
-    # Node-table size rides the same budget: generous tables keep
-    # late-boosting speculation wide (grow.py table notes) — use the
-    # largest table_mult in [4, 12] whose cache still fits; without the
-    # cache the table is [M]-scalar cheap, so take the max.
-    slot_bytes = classes * groups * max_bins * 3 * 4
-    mult_fit = int((_SUBTRACT_CACHE_BUDGET // max(slot_bytes, 1) - 52)
-                   // max(num_leaves, 1))
-    subtract = (hist_subtract
-                and learner == "serial"
-                # vmap'd class trees each carry a cache: the x classes
-                # scatter/memory traffic measured a net LOSS on the
-                # multiclass shape (0.62 vs 0.89 Mrow-iters/s)
-                and classes == 1
-                and mult_fit >= 6)
-    if table_mult is None:
-        # vmap'd class trees multiply every [M]-sized table op by the
-        # classes: the measured multiclass optimum is a smaller table
-        table_mult = min(12, mult_fit) if subtract else \
-            (6 if classes > 1 else 12)
-    # gather-compacted small-node contraction: wherever rows are locally
-    # resident (serial + data/voting learners; the grower additionally
-    # refuses it under feature_axis) and the shape's pass costs say it
-    # can pay. The threshold is a pure scheduling choice — for any value
-    # the grown trees match the full-pass grower on order-invariant sums
-    # (grow.py notes) — so unless the user gave one it is the break-even
-    # of a full pass against an index build plus gathers
-    # (grow.compact_threshold: the constants are chip readings, PERF.md
-    # section 6, PR 27). A narrow table never reaches it: at 28 groups x
-    # 63 bins the index build alone costs 2.7 full passes, and the
-    # fraction comes out 0. Single-chunk runs have nothing to skip.
-    # Multiclass is excluded like subtraction: the vmap over class trees
-    # batches the per-pass cond predicate, which under jax's cond
-    # batching rule executes BOTH histogram kernels every pass.
-    model = compact_threshold(groups, max_bins, rows, rows_padded)
-    if compact_fraction is None:
-        compact_fraction = model.fraction
-    compact = (hist_compact
-               and compact_fraction > 0.0
-               and learner != "feature"
-               and classes == 1
-               and rows_padded >= 2 * chunk)
-    if force_subtract is not None:
-        subtract = force_subtract
-    if force_compact is not None:
-        compact = force_compact
-        if compact and compact_fraction <= 0.0:  # forced past the model
-            compact_fraction = COMPACT_FRACTION_MAX
-    if batch_k is None:
-        if subtract:
-            # one smaller-child channel set per node: 25*(3+2) fills the
-            # 128-lane tile; wide shapes stay narrow (channel-cost-bound
-            # passes + depth-bound trees — K=8 matches the channel cost
-            # of the round-4 K=4 direct path while expanding 2x nodes)
-            batch_k = 8 if wide else 24
-        else:
-            # Bosch-class data (wide AND heavily EFB-bundled — sparse
-            # one-hot blocks) measured fastest at K=4: deep depth-bound
-            # trees, channel-cost-bound passes. Unbundled wide shapes
-            # (Epsilon) keep the full-tile default.
-            batch_k = 4 if (wide and bundled) else 12
-    return Schedule(wide, subtract, int(table_mult), compact,
-                    float(compact_fraction), model, int(batch_k))
-
 
 _forest_jit_cache: Dict[str, object] = {}
 
@@ -669,7 +561,6 @@ class GBDT:
         if nproc > 1 and self._tree_learner_kind not in ("data", "voting"):
             log.fatal("Multi-host training requires tree_learner=data or "
                       "voting (got %s)" % self._tree_learner_kind)
-        local_dev = max(1, ndev // nproc)
         # arm the collective watchdog + heartbeat lease for this run
         # (parallel/watchdog.py): every host-level collective from here
         # on — including this init's own allgathers below — runs under
@@ -698,13 +589,8 @@ class GBDT:
                 _os.path.join(hb_dir, f"heartbeat_r{rank}.json"))
             telemetry.heartbeat(0, phase="init", rank=rank)
 
-        # row-padding plan: chunk capped by the group-block budget, rows
-        # padded to a chunk (x shard) multiple, padded size bucketed into
-        # coarse power-of-two granules so nearby row counts share one
-        # compiled signature (full rationale in ingest/landing.py, where
-        # the plan lives so the streaming ingest subsystem can land
-        # per-device shards that are byte-compatible with this init)
-        from ..ingest.landing import plan_row_layout
+        # row-padding plan (learner/schedule.py, which the streaming ingest
+        # shares so its per-device shards land byte-compatible with this)
         layout = plan_row_layout(
             n, train_data.num_groups, train_data.max_num_bin(),
             tpu_hist_chunk=self.config.tree.tpu_hist_chunk,
@@ -720,6 +606,7 @@ class GBDT:
             with watchdog.deadline("gbdt.init.pad_sync"):
                 n_pad = int(multihost_utils.process_allgather(
                     jnp.asarray(np.int64(n_pad))).max())
+            layout = layout._replace(n_pad=n_pad)
         self._n = n
         self._n_pad = n_pad
 
@@ -860,46 +747,6 @@ class GBDT:
                 m.init(train_data.metadata, n)
                 self.metrics.append(m)
 
-        if self.config.tree.tpu_hist_pallas:
-            log.warning("tpu_hist_pallas is retired: the hand-written "
-                        "kernel measured slower than the XLA path "
-                        "(profiles/README.md); using the XLA kernels")
-        # --- execution-schedule auto-selection (pick_schedule, above) ------
-        # the grower re-guards compaction on PER-SHARD rows (each shard
-        # compacts its own block), so model and gate the same quantity or
-        # the schedule log would claim compact=True while the grower
-        # silently declines
-        L_cfg = self.config.tree.num_leaves
-        g_cnt = max(1, int(train_data.num_groups))
-        k_cls = self.num_tree_per_iteration
-        shards = 1
-        if self._tree_learner_kind in ("data", "voting"):
-            shards = max(1, local_dev if nproc > 1 else ndev)
-        import os as _os
-        raw = self.config.raw_params
-
-        def forced(name):                   # debug override: "1", "0", unset
-            value = _os.environ.get(name)
-            return (value == "1") if value else None
-        picked = pick_schedule(
-            g_cnt, self._max_bins, n // shards, self._n_pad // shards,
-            self._chunk, num_leaves=L_cfg, classes=k_cls,
-            learner=self._tree_learner_kind,
-            bundled=g_cnt < 0.8 * max(1, train_data.num_features),
-            hist_subtract=self.config.tree.tpu_hist_subtract,
-            hist_compact=self.config.tree.tpu_hist_compact,
-            compact_fraction=(float(self.config.tree.tpu_compact_threshold)
-                              if "tpu_compact_threshold" in raw else None),
-            batch_k=(self.config.tree.tpu_batch_k
-                     if "tpu_batch_k" in raw else None),
-            table_mult=(int(_os.environ["LGBM_TPU_TABLE_MULT"])  # debug
-                        if _os.environ.get("LGBM_TPU_TABLE_MULT") else None),
-            force_subtract=forced("LGBM_TPU_FORCE_SUBTRACT"),
-            force_compact=forced("LGBM_TPU_FORCE_COMPACT"))
-        wide, subtract, table_mult = (picked.wide, picked.subtract,
-                                      picked.table_mult)
-        compact, compact_frac = picked.compact, picked.compact_fraction
-        compact_costs, batch_k = picked.compact_model, picked.batch_k
         # --- quantized-gradient training (tpu_hist_quantize, ISSUE 20) ---
         from ..ops.histogram import TRAIN_QUANTIZE_MODES, train_qmax
         quant_mode = str(self.config.tree.tpu_hist_quantize or "none").lower()
@@ -936,58 +783,39 @@ class GBDT:
         # key chains — the sweep==solo byte-identity contract holds
         # under quantization too
         self._quant_seed = int(self.config.io.data_random_seed)
-        if quant_mode == "int8" and "tpu_batch_k" not in self.config.raw_params:
-            # int8 contracts 3 channels per node id instead of the bf16
-            # hi+lo path's 5, so the same 128-lane MXU output tile (and,
-            # on CPU, the same one-hot operand materialization) covers
-            # 5/3 more leaves per pass. Widening the batch is free on
-            # correctness: quantized histograms live in the exact int32
-            # domain, where trees are bit-identical for ANY batch_k.
-            batch_k = max(1, (batch_k * 5) // 3)
+
+        # --- the execution schedule (learner/schedule.py) ----------------
+        # each data/voting shard compacts its own block, so the schedule
+        # is asked about ONE shard's rows: what it models is what the
+        # grower's own guard sees
+        g_cnt = max(1, int(train_data.num_groups))
+        shards = layout.row_multiple // layout.chunk  # this process's
+        picked = pick_schedule(
+            g_cnt, self._max_bins, n // shards, n_pad // shards,
+            layout.chunk, num_leaves=self.config.tree.num_leaves,
+            classes=self.num_tree_per_iteration,
+            learner=self._tree_learner_kind,
+            bundled=g_cnt < 0.8 * max(1, train_data.num_features),
+            quantize=quant_mode,
+            compact_fraction=(
+                float(self.config.tree.tpu_compact_threshold)
+                if "tpu_compact_threshold" in self.config.raw_params
+                else None))
+        costs = picked.compact_model
         log.info("Schedule: groups=%d max_bin=%d wide=%s subtract=%s "
                  "compact=%s@%.3f (ns a row: full=%.1f index=%.1f "
                  "gather=%.1f) batch_k=%d table_mult=%d chunk=%d "
                  "quantize=%s qmax=%d",
-                 g_cnt, self._max_bins, wide, subtract, compact,
-                 compact_frac, compact_costs.full_ns, compact_costs.index_ns,
-                 compact_costs.gather_ns, batch_k, table_mult, self._chunk,
-                 quant_mode, quant_qmax)
-        # execution-schedule summary for the telemetry run-log header
-        # (telemetry/runlog.py): the knobs that explain this run's pass
-        # economics, host-readable without re-deriving the auto-selection
-        self._schedule_info = {
-            "tree_learner": self._tree_learner_kind,
-            "num_shards": int(ndev), "num_processes": int(nproc),
-            # data-parallel histogram-merge collective + per-device owned
-            # histogram slice (scatter: groups/ndev after padding; other
-            # schedules score the full group set everywhere)
-            "hist_reduce": (hist_reduce if use_scatter else "allreduce")
-            if self._tree_learner_kind == "data" else None,
-            "owned_groups": int(g_pad // ndev) if use_scatter
-            else int(g_cnt),
-            "groups": int(g_cnt), "max_bin": int(self._max_bins),
-            "wide": bool(wide), "subtract": bool(subtract),
-            "compact": bool(compact), "compact_fraction": compact_frac,
-            # the pass-cost model's answer for this (per-shard) shape,
-            # beside what was used: they differ when the user set one
-            "compact_model": compact_costs._asdict(),
-            "batch_k": int(batch_k), "table_mult": int(table_mult),
-            "chunk": int(self._chunk), "rows": int(n),
-            "rows_padded": int(n_pad),
-            "hist_quantize": quant_mode, "hist_qmax": int(quant_qmax),
-            "hist_hess_const": bool(quant_hess_const),
-        }
+                 g_cnt, self._max_bins, picked.wide, picked.subtract,
+                 picked.compact, picked.compact_fraction, costs.full_ns,
+                 costs.index_ns, costs.gather_ns, picked.batch_k,
+                 picked.table_mult, layout.chunk, quant_mode, quant_qmax)
         self._grower_cfg = GrowerConfig(
             num_leaves=self.config.tree.num_leaves,
             max_bins=self._max_bins,
             feature_bins=int(train_data.num_bins_per_feature().max(initial=1)),
-            batch_k=batch_k,
-            hist_subtract=subtract,
-            hist_compact=compact,
-            compact_fraction=compact_frac,
-            table_mult=table_mult,
+            **picked.grower_fields(layout.chunk),
             hist_bf16=self.config.tree.tpu_hist_bf16,
-            chunk=self._chunk,
             lambda_l1=self.config.tree.lambda_l1,
             lambda_l2=self.config.tree.lambda_l2,
             min_gain_to_split=self.config.tree.min_gain_to_split,
@@ -1080,10 +908,14 @@ class GBDT:
 
         self._feature_rng = np.random.RandomState(self.config.tree.feature_fraction_seed)
 
-        # final grower schedule (group widths may have been re-planned by
-        # the feature-parallel padding above) for the run-log header
-        from ..learner.grow import schedule_summary
-        self._schedule_info["grower"] = schedule_summary(self._grower_cfg)
+        # the run-log header's record of the schedule, made last: the
+        # feature-parallel padding above may have re-planned group widths
+        self._schedule_info = schedule_info(
+            picked, layout, self._grower_cfg, rows=n, groups=g_cnt,
+            tree_learner=self._tree_learner_kind, num_processes=nproc,
+            hist_reduce=((hist_reduce if use_scatter else "allreduce")
+                         if self._tree_learner_kind == "data" else None),
+            owned_groups=g_pad // ndev if use_scatter else g_cnt)
 
         # boost from average (gbdt.cpp:358-378): the score bump happens at
         # init; the bias itself is folded into the first trained tree via
@@ -1360,11 +1192,9 @@ class GBDT:
             return self._train_one_iter_multi(grad, hess, row_weight,
                                               qscales)
 
-        import os
         if (self._dist_grower is None and k == 1 and not self.valid_sets
                 and gradients is None
-                and getattr(self, "_supports_pipeline", True)
-                and not os.environ.get("LGBM_TPU_NO_PIPELINE")):
+                and getattr(self, "_supports_pipeline", True)):
             return self._train_one_iter_pipelined(grad, hess, row_weight,
                                                   probe, qscales, t_enter)
         self._raise_if_nonfinite(probe, self.iter_)

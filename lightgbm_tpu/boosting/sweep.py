@@ -36,6 +36,7 @@ import numpy as np
 from .. import log, tracing
 from ..config import Config, key_alias_transform
 from ..learner.grow import GrowParams
+from ..learner.schedule import subtract_cache_fits
 from ..learner.sweep import (MODE_BAGGING, MODE_GOSS, MODE_PLAIN,
                              SweepGrower, SweepModelParams)
 from ..objectives import create_objective
@@ -189,19 +190,16 @@ class SweepTrainer:
         # budget, so re-check it at K x and drop subtraction — with the
         # byte-identity caveat logged — only when it cannot fit.
         self.cfg = gb._grower_cfg
-        if self.cfg.hist_subtract:
-            from .gbdt import _SUBTRACT_CACHE_BUDGET
-            g_cnt = max(1, int(gb.train_data.num_groups))
-            slot_bytes = self.kc * g_cnt * gb._max_bins * 3 * 4
-            slots = self.cfg.table_mult * lead_cfg.tree.num_leaves + 52
-            if slots * slot_bytes * K > _SUBTRACT_CACHE_BUDGET:
-                log.warning(
-                    "Sweep: %d sibling-subtraction caches exceed the "
-                    "device budget; disabling subtraction for the sweep. "
-                    "Trees then match serial training only up to f32 "
-                    "summation order (set tpu_hist_subtract=false on the "
-                    "serial side for strict byte comparisons).", K)
-                self.cfg = self.cfg._replace(hist_subtract=False)
+        if self.cfg.hist_subtract and not subtract_cache_fits(
+                max(1, int(gb.train_data.num_groups)), gb._max_bins,
+                lead_cfg.tree.num_leaves, self.cfg.table_mult,
+                classes=self.kc, copies=K):
+            log.warning(
+                "Sweep: %d sibling-subtraction caches exceed the "
+                "device budget; disabling subtraction for the sweep. "
+                "Trees then match serial training only up to f32 "
+                "summation order.", K)
+            self.cfg = self.cfg._replace(hist_subtract=False)
 
         mode = MODE_PLAIN
         bag_freq = int(lead_cfg.boosting.bagging_freq)

@@ -125,14 +125,12 @@ _FINGERPRINT_EXCLUDE = {
 # every tpu_* field must appear in exactly one of the two sets, so a
 # new knob cannot ship with its resume semantics undecided.
 _FINGERPRINT_INCLUDED = {
-    # histogram numerics/order: precision, bf16 accumulation, batched
-    # grow order, compaction and subtraction reshape the f32 summation
-    # tree (subtract/compact are bit-identical TODAY, but that identity
+    # histogram numerics/order: bf16 accumulation, the chunk and the
+    # compaction threshold reshape the f32 summation tree (compaction
+    # is bit-identical on order-invariant sums TODAY, but that identity
     # is a test-enforced property of the current kernels, not a
-    # contract — keep them fingerprinted so resume never blends paths)
-    "tpu_hist_chunk", "tpu_double_precision", "tpu_batch_k",
-    "tpu_hist_bf16", "tpu_hist_subtract", "tpu_hist_compact",
-    "tpu_compact_threshold", "tpu_hist_pallas",
+    # contract — keep it fingerprinted so resume never blends paths)
+    "tpu_hist_chunk", "tpu_hist_bf16", "tpu_compact_threshold",
     # quantized-gradient training (ISSUE 20): stochastically-rounded
     # integer gradients change every histogram sum and therefore every
     # split — resume must never blend a quantized trajectory with an

@@ -332,34 +332,22 @@ class TreeConfig:
     # on narrow shapes (r4, the group-block plan bounds the working set
     # so the chunk no longer needs to)
     tpu_hist_chunk: int = 65536
-    tpu_double_precision: bool = False
-    # speculative-expansion width (learner/grow.py): nodes expanded per
-    # histogram pass; 1 = one data pass per split. 12 fills the 128-lane
-    # MXU output tile (2*12*(3+2) channels) and measured fastest on-chip
-    tpu_batch_k: int = 12
     # bf16 hi+lo MXU histogram contraction (ops/histogram.py)
     tpu_hist_bf16: bool = True
-    # sibling subtraction via a per-node histogram cache (the reference
-    # HistogramPool + FeatureHistogram::Subtract economics,
-    # feature_histogram.hpp:64-70,380-548): build only the smaller
-    # child's histogram per expansion. Auto-disabled when the cache
-    # would exceed its device-memory budget (boosting/gbdt.py).
-    tpu_hist_subtract: bool = True
     # gather-compacted small-node contraction (learner/grow.py): when
-    # one expansion pass's selected nodes jointly hold at most
-    # tpu_compact_threshold * N in-bag rows, compact their row indices
-    # and contract only the gathered subset — late-tree passes then cost
+    # one expansion pass's selected nodes jointly hold at most this row
+    # fraction of the in-bag rows, compact their row indices and
+    # contract only the gathered subset — late-tree passes then cost
     # O(rows-in-selected-nodes) instead of O(N) (the reference's
     # DataPartition economics, data_partition.hpp:94-170). On for the
     # serial and data/voting-parallel learners; the feature-parallel
     # learner ignores it (routing reads the replicated matrix through a
-    # traced shard offset)
-    tpu_hist_compact: bool = True
-    # switch threshold and compaction-buffer capacity as a row fraction
-    # (rounded up to a chunk multiple; >= 1.0 forces compaction,
-    # <= 0 disables it). Honoured when the user gives it; unset, the
-    # schedule takes the break-even of the shape's pass costs, at most
-    # 0.25 and 0 on a narrow table (learner/grow.compact_threshold)
+    # traced shard offset). The value is the switch threshold and the
+    # compaction-buffer capacity as a row fraction (rounded up to a chunk
+    # multiple; >= 1.0 forces compaction, <= 0 disables it). Honoured
+    # when the user gives it; unset, the schedule takes the break-even
+    # of the shape's pass costs, at most 0.25 and 0 on a narrow table
+    # (learner/schedule.compact_threshold)
     tpu_compact_threshold: float = 0.25
     # data-parallel histogram merge collective (parallel/learners.py +
     # learner/grow.py): "scatter" (default) ReduceScatters the per-pass
@@ -392,11 +380,6 @@ class TreeConfig:
     # exceeds this tolerance the config is REFUSED with a named error
     # instead of silently training lossy
     tpu_hist_quantize_tol: float = 0.5
-    # RETIRED (accepted for compat, warns): the hand-written pallas
-    # histogram kernel measured slower than XLA's own fusion of the
-    # one-hot compare into the dot (14.4 vs 11.1 ms/pass at 2M x 28 x 64)
-    # and was removed; see profiles/README.md for the postmortem
-    tpu_hist_pallas: bool = False
 
 
 @dataclass
@@ -538,18 +521,13 @@ TPU_PARAM_SPEC = {
     "tpu_export_buckets": ("int", 1, None),
     # tree / histogram schedule
     "tpu_hist_chunk": ("int", 1, None),
-    "tpu_double_precision": "bool",
-    "tpu_batch_k": ("int", 1, None),
     "tpu_hist_bf16": "bool",
-    "tpu_hist_subtract": "bool",
-    "tpu_hist_compact": "bool",
     "tpu_compact_threshold": ("float", None, None),  # <= 0 disables
     "tpu_hist_reduce": ("choice", "scatter", "allreduce"),
     # must mirror ops/histogram.TRAIN_QUANTIZE_MODES (kept literal so the
     # table stays import-free and AST-readable)
     "tpu_hist_quantize": ("choice", "none", "int16", "int8"),
     "tpu_hist_quantize_tol": ("float>", 0.0),
-    "tpu_hist_pallas": "bool",                       # retired, warns
     # piecewise-linear leaves
     "tpu_linear_max_features": ("int", 1, None),
     # boosting

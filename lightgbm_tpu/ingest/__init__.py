@@ -16,8 +16,8 @@ The reproduction's analogue of the reference's `DatasetLoader` /
 - `cache`   — versioned, checksummed, memory-mapped binary dataset
   artifact: repeated runs skip parsing AND binning (pass 1+2 never run),
   mismatched fingerprints are refused;
-- `landing` — row-layout plan shared with the trainer + the landing
-  implementations.
+- `landing` — the landing implementations (the row-layout plan they
+  share with the trainer is `learner.schedule.plan_row_layout`).
 
 Everything is instrumented: `ingest/*` spans and rows/bytes/chunks
 counters flow into the telemetry registry and from there into the run
@@ -30,7 +30,7 @@ from .cache import (CacheCorrupt, CacheMismatch,
                     FORMAT_VERSION as CACHE_FORMAT_VERSION,
                     MAGIC as CACHE_MAGIC, binning_params_fingerprint_fields,
                     ingest_fingerprint, load_cache, save_cache)
-from .landing import HostLanding, RowLayout, ShardedLanding, plan_row_layout
+from .landing import HostLanding, ShardedLanding
 from .sketch import SketchResult, sketch_pass
 from .sources import (ArraySource, ChunkSource, ChunksSource,
                       DEFAULT_CHUNK_ROWS, FileSource)
@@ -39,8 +39,8 @@ __all__ = [
     "ArraySource", "CacheCorrupt", "CacheMismatch",
     "CACHE_FORMAT_VERSION", "CACHE_MAGIC",
     "ChunkSource", "ChunksSource", "DEFAULT_CHUNK_ROWS", "FileSource",
-    "HostLanding", "RowLayout", "ShardedLanding", "SketchResult",
+    "HostLanding", "ShardedLanding", "SketchResult",
     "binning_params_fingerprint_fields", "build_from_numpy", "build_inner",
-    "ingest_fingerprint", "load_cache", "plan_row_layout", "save_cache",
+    "ingest_fingerprint", "load_cache", "save_cache",
     "sketch_pass",
 ]

@@ -10,56 +10,18 @@
   the data/voting-parallel growers' shard_map expects (P(axis, None)),
   so training starts with zero resharding.
 
-`plan_row_layout` is the row-padding plan the trainer uses — extracted
-from GBDT.init so a landing padded here is byte-compatible with what the
-grower would have padded itself.
+A sharded landing is padded by the trainer's own row plan
+(`learner.schedule.plan_row_layout`), so it is byte-compatible with what
+the grower would have padded itself.
 """
 from __future__ import annotations
 
-from typing import List, NamedTuple, Optional
+from typing import List, Optional
 
 import numpy as np
 
 from .. import log, telemetry
-
-
-class RowLayout(NamedTuple):
-    chunk: int          # histogram row-chunk the grower will use
-    row_multiple: int   # rows per padding granule (chunk x device factor)
-    n_pad: int          # padded row count (this process)
-    ndev: int           # device count the plan assumed
-    local_dev: int      # local devices per process
-
-
-def plan_row_layout(n: int, num_groups: int, max_num_bin: int, *,
-                    tpu_hist_chunk: int = 65536,
-                    tree_learner: str = "serial",
-                    ndev: int = 1, nproc: int = 1) -> RowLayout:
-    """The padded-row plan of GBDT.init (boosting/gbdt.py): histogram
-    chunk capped by the group-block budget, rows padded to a chunk (x
-    shard) multiple, then bucketed into coarse power-of-two granules so
-    nearby row counts share one compiled signature. Multi-process
-    callers must still allgather-max the result across ranks."""
-    kind = tree_learner if tree_learner in ("data", "feature", "voting") \
-        else "serial"
-    if kind == "serial":
-        ndev = 1
-    local_dev = max(1, ndev // max(1, nproc))
-    chunk = min(int(tpu_hist_chunk), 1 << 20)
-    gb = max(1, int(num_groups) * int(max_num_bin))
-    target = max(1, (16 << 26) // gb)
-    chunk = min(chunk, max(8192, 1 << int(np.floor(np.log2(target)))))
-    chunk = int(min(chunk, max(256, 1 << int(np.ceil(np.log2(max(n, 1)))))))
-    row_multiple = chunk * (local_dev if nproc > 1 else ndev) \
-        if kind in ("data", "voting") else chunk
-    m_count = (n + row_multiple - 1) // row_multiple
-    if m_count > 1:
-        p2 = 1 << (m_count - 1).bit_length()
-        g = max(1, p2 // 8)
-        m_count = ((m_count + g - 1) // g) * g
-    return RowLayout(chunk=chunk, row_multiple=row_multiple,
-                     n_pad=m_count * row_multiple, ndev=ndev,
-                     local_dev=local_dev)
+from ..learner.schedule import RowLayout
 
 
 class HostLanding:

@@ -94,7 +94,6 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..binning import MISSING_NAN, MISSING_ZERO
 from ..ops import histogram as hist_ops
@@ -102,6 +101,7 @@ from ..ops import split as split_ops
 from ..ops.lookup import row_lookup
 from ..ops.split import leaf_output
 from ..telemetry.layers import scope
+from .schedule import compact_capacity
 
 
 class GrowerConfig(NamedTuple):
@@ -815,16 +815,9 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     # parent-minus-child identity) so it keeps the direct 2K-children path
     subtract = cfg.hist_subtract and not voting
 
-    # gather-compacted small-node contraction: static buffer capacity =
-    # compact_fraction of the (per-shard) row count, rounded UP to a
-    # chunk multiple and clamped to n, so every shape in the while_loop
-    # stays compile-stable. The capacity doubles as the switch
-    # threshold: a pass is compacted iff its selected nodes' in-bag
-    # member rows fit the buffer.
-    # single-chunk (per-shard) inputs have no chunks to skip: cap would
-    # round up to n and force EVERY pass through the slower gather —
-    # keep the contiguous full-pass kernel there. A non-positive
-    # fraction disables compaction (mirroring >= 1.0 forcing it on).
+    # gather-compacted small-node contraction: a static buffer capacity,
+    # 0 where every pass stays on the full kernel (schedule.py has the
+    # arithmetic and the guards)
     cap = compact_capacity(cfg, n)
     compact = cap > 0
     pass_cap = 4 * L + 64   # == the round_cond hard pass cap
@@ -1417,80 +1410,6 @@ def leaf_path_features(leaf_parent, node_feature, node_left, node_right,
     return jax.vmap(one_leaf)(leaf_parent.astype(jnp.int32))
 
 
-def compact_capacity(cfg: GrowerConfig, n: int) -> int:
-    """Rows the gather-compaction buffer holds for `n` (per-shard) rows
-    under `cfg`, 0 where grow_tree keeps every pass on the full kernel
-    (see the notes above its use). Also read on the host, to tell full
-    from compacted passes in `pass_rows` (telemetry.layers.split_passes)."""
-    if not (bool(cfg.hist_compact) and cfg.feature_axis is None
-            and float(cfg.compact_fraction) > 0.0
-            and n % cfg.chunk == 0 and n >= 2 * cfg.chunk):
-        return 0
-    cap = max(1, int(n * min(float(cfg.compact_fraction), 1.0)))
-    cap = min(n, ((cap + cfg.chunk - 1) // cfg.chunk) * cfg.chunk)
-    return cap if cap >= cfg.chunk else 0
-
-
-class CompactChoice(NamedTuple):
-    """`compact_threshold`'s answer and the three pass costs behind it,
-    each in ns a REAL row so they compare (the Schedule log prints them)."""
-    fraction: float   # of the padded rows, as `compact_capacity` takes it
-    full_ns: float    # a full pass, per row contracted
-    index_ns: float   # the index build, spread over the real rows
-    gather_ns: float  # a compacted pass's extra cost per gathered row
-
-
-# The model's constants: TPU v5 lite, one chip, `scripts/profile_train.py`
-# on `synth_higgs` at max_bin 63 / 255 leaves, compaction off against on
-# at 0.25, two traced trees a run, at 28, 137, 700 and 2000 features
-# (PERF.md section 6, PR 27, has the table). Stored groups x bins -> ns a
-# row of `lgbm/hist/contract` with every pass full. Stored groups -> ns a
-# gathered row: `lgbm/hist/gather` (bins, the three channels, leaf ids;
-# 43-47 ns at every width) plus what the contraction of gathered chunks
-# costs over a full pass's (0 to 22 ns). The index build reads leaf ids
-# and weights only, so one number a PADDED row at every width: the
-# reading at 28 features, whose builds are the longest (25.2M rows each:
-# `lgbm/grow/compact_index` 9.0 ns, less the relabel's select that XLA
-# fuses into it, plus the cumsum's unscoped reduce-window); at 2.6M, 0.5M
-# and 0.46M rows a build the others read 5.1, 6.6 and 7.8.
-_WIDTHS, _FULL_NS = (1764, 8631, 44100, 126000), (3.4, 8.5, 64.0, 181.4)
-_GROUPS, _GATHER_NS = (28, 137, 700, 2000), (46.9, 49.9, 54.6, 64.5)
-_INDEX_NS_PER_PADDED_ROW = 7.5
-COMPACT_FRACTION_MAX = 0.25
-# Under this the few passes that qualify cannot repay what the compacted
-# branch costs a program merely by being in it: at 137 features that
-# width's own three readings put the break-even at 0.05, and thresholds
-# 0.03-0.1 ran 8% to 14% slower than no compaction (XLA then copies the
-# whole binned matrix into the gather's layout once a tree, 0.058 s of
-# 0.75).
-COMPACT_FRACTION_MIN = 0.05
-
-
-def compact_threshold(num_groups: int, max_bins: int, rows: int,
-                      rows_padded: int) -> CompactChoice:
-    """The row fraction under which a gather-compacted pass is cheaper
-    than a full one, for a (per-shard) shape. A full pass costs
-    `rows * full`; a compacted one `rows_padded * index + cnt * (gather +
-    full)`: an index over every padded row whatever it finds, then the
-    gathers and the same contraction over the `cnt` member rows. The
-    break-even `cnt` is given as a fraction of the PADDED rows, which is
-    what `compact_capacity` multiplies, clipped to COMPACT_FRACTION_MAX so
-    the buffer never outgrows the one a fixed 0.25 gave, and 0 under
-    COMPACT_FRACTION_MIN; 0 means a full pass always wins and the grower
-    compiles without the compacted branch. Between the measured widths
-    the costs are piecewise-linear; outside them `np.interp` holds them
-    flat: no reading is trusted past the nearest measured width."""
-    rows = max(rows, 1)
-    full = float(np.interp(num_groups * max_bins, _WIDTHS, _FULL_NS))
-    gather = float(np.interp(num_groups, _GROUPS, _GATHER_NS))
-    index = _INDEX_NS_PER_PADDED_ROW * rows_padded / rows
-    cnt = rows * (full - index) / (gather + full)
-    fraction = min(cnt / rows_padded, COMPACT_FRACTION_MAX)
-    if fraction < COMPACT_FRACTION_MIN:
-        fraction = 0.0
-    return CompactChoice(fraction, full, index, gather)
-
-
 def shard_group_widths(group_widths, num_shards: int):
     """Per-position max of the per-shard feature-block widths: the one
     static block plan that is correct for every feature shard (see the
@@ -1503,36 +1422,6 @@ def shard_group_widths(group_widths, num_shards: int):
 
 FMETA_KEYS = ("num_bin", "missing_type", "default_bin", "is_categorical",
               "group", "offset", "is_bundled")
-
-
-def schedule_summary(cfg: GrowerConfig) -> dict:
-    """JSON-safe view of the static schedule baked into a compiled
-    grower — the telemetry run-log header's record of WHY this run's
-    pass economics look the way they do (telemetry/runlog.py). Group
-    widths are summarized, not dumped: wide shapes carry thousands."""
-    widths = cfg.group_widths or ()
-    return {
-        "num_leaves": int(cfg.num_leaves),
-        "max_bins": int(cfg.max_bins),
-        "feature_bins": int(cfg.feature_bins),
-        "chunk": int(cfg.chunk),
-        "batch_k": int(cfg.batch_k),
-        "table_mult": int(cfg.table_mult),
-        "hist_bf16": bool(cfg.hist_bf16),
-        "hist_subtract": bool(cfg.hist_subtract),
-        "hist_compact": bool(cfg.hist_compact),
-        "compact_fraction": float(cfg.compact_fraction),
-        "max_depth": int(cfg.max_depth),
-        "data_axis": cfg.data_axis, "feature_axis": cfg.feature_axis,
-        "voting": bool(cfg.voting),
-        "hist_scatter": bool(cfg.hist_scatter),
-        "num_data_shards": int(cfg.num_data_shards),
-        "num_groups": len(widths),
-        "group_width_max": int(max(widths)) if widths else int(cfg.max_bins),
-        "hist_quantize": cfg.hist_quantize,
-        "hist_qmax": int(cfg.hist_qmax),
-        "hist_hess_const": bool(cfg.hist_hess_const),
-    }
 
 
 def make_grower(cfg: GrowerConfig):

@@ -11,10 +11,6 @@ Layout:
   `jnp.linalg.solve` over every leaf's small normal-equation system,
   built by one-hot MXU contractions over the leaf's top-k path
   features; constant-leaf fallback on singular/under-populated leaves.
-- `stats.py`   — per-leaf marginal regression moments derived from the
-  histogram moment kernels (`ops/histogram.leaf_moments` family), the
-  diagnostics surface that cross-validates the solver's normal
-  equations bin-by-bin.
 
 The fit is a schedule-independent POST-GROWTH pass: tree structure and
 gains come from the unchanged constant-leaf grower (matching the
@@ -25,4 +21,3 @@ schedules — so linear coefficients inherit every bit-identity
 guarantee of the constant-leaf trees.
 """
 from .solver import fit_leaves  # noqa: F401
-from .stats import leaf_feature_moments  # noqa: F401

@@ -561,227 +561,6 @@ def gathered_leaves_histogram(binned: jnp.ndarray, weights: jnp.ndarray,
     return hist.reshape(f, num_bins, c_ids, 3).transpose(2, 0, 1, 3)
 
 
-# ---------------------------------------------------------------------------
-# per-bin raw-feature moments (linear_tree support, lightgbm_tpu/linear/)
-# ---------------------------------------------------------------------------
-def _contract_moment_block_parts(get_block, get_xblock, blocks, num_bins,
-                                 u3, u1):
-    """One row-chunk's moment contribution, group-block tiled.
-
-    Same tiling as _contract_block_parts but the one-hot is weighted by
-    the raw feature value (and its square) before the contraction:
-
-        part1[f,b,s] = sum_c 1[bin==b] * x[c,f]   * u3[c,s]
-        part2[f,b,s] = sum_c 1[bin==b] * x[c,f]^2 * u1[c,s]
-
-    x carries a full f32 mantissa, so there is no bf16 hi+lo variant —
-    moments always contract f32 at HIGHEST precision. Non-finite raw
-    values are zeroed before weighting (a NaN row would otherwise
-    poison its bin's sums; the grad/hess histogram's count channel
-    still counts such rows). Returns per-block [Gb, Bb, S3+S1] parts,
-    channel layout [u3-channels | u1-channels]."""
-    parts = []
-    for gs, gc, bw in blocks:
-        x = get_xblock(gs, gc)
-        x = jnp.where(jnp.isfinite(x), x, 0.0).astype(jnp.float32)
-        ohf = _onehot(get_block(gs, gc), min(bw, num_bins)) \
-            .astype(jnp.float32)
-        ohx = ohf * x[:, :, None]
-        p1 = jnp.einsum("cfb,cs->fbs", ohx, u3,
-                        preferred_element_type=jnp.float32,
-                        precision=jax.lax.Precision.HIGHEST)
-        p2 = jnp.einsum("cfb,cs->fbs", ohx * x[:, :, None], u1,
-                        preferred_element_type=jnp.float32,
-                        precision=jax.lax.Precision.HIGHEST)
-        parts.append(jnp.concatenate([p1, p2], axis=-1))
-    return tuple(parts)
-
-
-def _moment_blocks_zeros(blocks, num_bins, s):
-    return tuple(jnp.zeros((gc, min(bw, num_bins), s), jnp.float32)
-                 for _, gc, bw in blocks)
-
-
-def _moment_channels(u3_w, member=None):
-    """[chunk, 3] (g*m, h*m, m) weights -> (u3, u1) channel matrices in
-    the moment kernels' fixed order: u3 = (m, g*m, h*m), u1 = (m).
-    With a [chunk, C] membership, channels widen to 3C / C."""
-    m = u3_w[:, 2:3]
-    u3 = jnp.concatenate([m, u3_w[:, 0:1], u3_w[:, 1:2]], axis=1)
-    if member is None:
-        return u3, m
-    mb = member.astype(jnp.float32)
-    c_ids = member.shape[1]
-    u3w = (mb[:, :, None] * u3[:, None, :]).reshape(-1, c_ids * 3)
-    u1w = mb * m
-    return u3w, u1w
-
-
-def _split_moments(hist, f, num_bins, c_ids):
-    """[F, B, 4C] (channel layout [C*(m,gm,hm) | C*m]) -> [C, F, B, 4]
-    with the public moment order (sum_x, sum_x2, sum_xg, sum_xh); all
-    sums carry the mask/bag weight."""
-    p1 = hist[:, :, :c_ids * 3].reshape(f, num_bins, c_ids, 3)
-    p2 = hist[:, :, c_ids * 3:].reshape(f, num_bins, c_ids, 1)
-    out = jnp.concatenate([p1[..., 0:1], p2, p1[..., 1:3]], axis=-1)
-    return out.transpose(2, 0, 1, 3)
-
-
-@functools.partial(jax.jit, static_argnames=("num_bins", "chunk"))
-def leaf_moments(binned: jnp.ndarray, x: jnp.ndarray,
-                 weights: jnp.ndarray, num_bins: int,
-                 chunk: int = 16384, n_valid=None) -> jnp.ndarray:
-    """Per-bin raw-feature moments over rows where the mask is nonzero.
-
-    moments[f, b] = (sum x*m, sum x^2*m, sum x*g*m, sum x*h*m) over rows
-    with bin[r, f] == b — the per-bin regression statistics the
-    linear_tree subsystem batches as einsums (lightgbm_tpu/linear/).
-
-    Args:
-      binned:  [N, F] int bin indices (N a multiple of `chunk`).
-      x:       [N, F] raw feature values ALIGNED COLUMN-FOR-COLUMN with
-               `binned` (the caller resolves EFB bundling; non-finite
-               entries contribute zero to every moment).
-      weights: [N, 3] = (grad*mask, hess*mask, mask) — the same channel
-               tensor as leaf_histogram, so padding rows (all-zero
-               channels) contribute zero to every moment.
-      n_valid: optional traced row count; whole trailing padding chunks
-               are skipped exactly like leaf_histogram.
-
-    Always f32 at HIGHEST precision (x carries a full mantissa — there
-    is no bf16 hi+lo analogue), same chunk scaffolding as the grad/hess
-    histogram so compaction and psum_scatter schedules reduce the same
-    per-chunk partials. Returns [F, B, 4] float32.
-    """
-    n, f = binned.shape
-    if n % chunk != 0:
-        raise ValueError(
-            f"rows ({n}) must be padded to a multiple of chunk ({chunk})")
-    n_chunks = n // chunk
-    blocks = plan_group_blocks((num_bins,) * f, chunk)
-
-    def one(c):
-        w_chunk = jax.lax.dynamic_slice(weights, (c * chunk, 0), (chunk, 3))
-        u3, u1 = _moment_channels(w_chunk)
-        return _contract_moment_block_parts(
-            lambda gs, gc: jax.lax.dynamic_slice(binned, (c * chunk, gs),
-                                                 (chunk, gc)),
-            lambda gs, gc: jax.lax.dynamic_slice(x, (c * chunk, gs),
-                                                 (chunk, gc)),
-            blocks, num_bins, u3, u1)
-
-    if n_chunks == 1:
-        hist = _assemble_blocks(one(jnp.int32(0)), num_bins)
-    else:
-        def body(c, accs):
-            return tuple(a + p for a, p in zip(accs, one(c)))
-        trip = n_chunks if n_valid is None else \
-            jnp.minimum((n_valid + chunk - 1) // chunk, n_chunks)
-        hist = _assemble_blocks(
-            jax.lax.fori_loop(0, trip, body,
-                              _moment_blocks_zeros(blocks, num_bins, 4)),
-            num_bins)
-    return _split_moments(hist, f, num_bins, 1)[0]
-
-
-@functools.partial(jax.jit, static_argnames=("num_bins", "chunk"))
-def batched_leaves_moments(binned: jnp.ndarray, x: jnp.ndarray,
-                           weights: jnp.ndarray, leaf_id: jnp.ndarray,
-                           ids: jnp.ndarray, num_bins: int,
-                           chunk: int = 16384,
-                           n_valid=None) -> jnp.ndarray:
-    """leaf_moments for C leaf-label ids in one data pass.
-
-    Membership widens the channel matrices exactly like
-    batched_leaves_histogram (4 moment channels per id instead of 3
-    grad/hess channels). Returns [C, F, B, 4] float32.
-    """
-    n, f = binned.shape
-    if n % chunk != 0:
-        raise ValueError(
-            f"rows ({n}) must be padded to a multiple of chunk ({chunk})")
-    c_ids = ids.shape[0]
-    n_chunks = n // chunk
-    blocks = plan_group_blocks((num_bins,) * f, chunk)
-
-    def one(c):
-        w_chunk = jax.lax.dynamic_slice(weights, (c * chunk, 0), (chunk, 3))
-        lid = jax.lax.dynamic_slice(leaf_id, (c * chunk,), (chunk,))
-        member = lid[:, None] == ids[None, :]
-        u3, u1 = _moment_channels(w_chunk, member)
-        return _contract_moment_block_parts(
-            lambda gs, gc: jax.lax.dynamic_slice(binned, (c * chunk, gs),
-                                                 (chunk, gc)),
-            lambda gs, gc: jax.lax.dynamic_slice(x, (c * chunk, gs),
-                                                 (chunk, gc)),
-            blocks, num_bins, u3, u1)
-
-    if n_chunks == 1:
-        hist = _assemble_blocks(one(jnp.int32(0)), num_bins)
-    else:
-        def body(c, accs):
-            return tuple(a + p for a, p in zip(accs, one(c)))
-        trip = n_chunks if n_valid is None else \
-            jnp.minimum((n_valid + chunk - 1) // chunk, n_chunks)
-        hist = _assemble_blocks(
-            jax.lax.fori_loop(0, trip, body,
-                              _moment_blocks_zeros(blocks, num_bins,
-                                                   4 * c_ids)),
-            num_bins)
-    return _split_moments(hist, f, num_bins, c_ids)
-
-
-@functools.partial(jax.jit, static_argnames=("num_bins", "chunk"))
-def gathered_leaves_moments(binned: jnp.ndarray, x: jnp.ndarray,
-                            weights: jnp.ndarray, leaf_id: jnp.ndarray,
-                            rows: jnp.ndarray, ids: jnp.ndarray,
-                            num_bins: int, chunk: int = 16384,
-                            n_valid=None) -> jnp.ndarray:
-    """batched_leaves_moments over a COMPACTED row subset (same buffer
-    contract as gathered_leaves_histogram: slots past n_valid alias row
-    0 and are masked dead, whole all-padding chunks are skipped).
-    Returns [C, F, B, 4] float32.
-    """
-    cap = rows.shape[0]
-    f = binned.shape[1]
-    if cap % chunk != 0:
-        raise ValueError(
-            f"row buffer ({cap}) must be a multiple of chunk ({chunk})")
-    c_ids = ids.shape[0]
-    n_chunks = cap // chunk
-    blocks = plan_group_blocks((num_bins,) * f, chunk)
-    nv = jnp.int32(cap) if n_valid is None else \
-        jnp.minimum(jnp.asarray(n_valid, jnp.int32), cap)
-
-    def one(c):
-        r = jax.lax.dynamic_slice(rows, (c * chunk,), (chunk,))
-        live = (c * chunk + jnp.arange(chunk, dtype=jnp.int32)) < nv
-        w_chunk = jnp.where(live[:, None], weights[r], 0.0)
-        b_rows = binned[r]
-        x_rows = x[r]
-        member = (leaf_id[r][:, None] == ids[None, :]) & live[:, None]
-        u3, u1 = _moment_channels(w_chunk, member)
-        return _contract_moment_block_parts(
-            lambda gs, gc: jax.lax.slice_in_dim(b_rows, gs, gs + gc,
-                                                axis=1),
-            lambda gs, gc: jax.lax.slice_in_dim(x_rows, gs, gs + gc,
-                                                axis=1),
-            blocks, num_bins, u3, u1)
-
-    if n_chunks == 1:
-        hist = _assemble_blocks(one(jnp.int32(0)), num_bins)
-    else:
-        def body(c, accs):
-            return tuple(a + p for a, p in zip(accs, one(c)))
-        trip = jnp.minimum((nv + chunk - 1) // chunk, n_chunks)
-        hist = _assemble_blocks(
-            jax.lax.fori_loop(0, trip, body,
-                              _moment_blocks_zeros(blocks, num_bins,
-                                                   4 * c_ids)),
-            num_bins)
-    return _split_moments(hist, f, num_bins, c_ids)
-
-
 def leaf_weights(grad: jnp.ndarray, hess: jnp.ndarray, leaf_id: jnp.ndarray,
                  leaf: jnp.ndarray, bag_weight: jnp.ndarray) -> jnp.ndarray:
     """Build the [N, 3] channel tensor selecting rows of `leaf`."""
@@ -789,9 +568,3 @@ def leaf_weights(grad: jnp.ndarray, hess: jnp.ndarray, leaf_id: jnp.ndarray,
     w = jnp.where(mask, bag_weight, 0.0)
     cnt = jnp.where(mask & (bag_weight > 0), 1.0, 0.0)
     return jnp.stack([grad * w, hess * w, cnt], axis=-1)
-
-
-def subtract(parent: jnp.ndarray, child: jnp.ndarray) -> jnp.ndarray:
-    """larger-child histogram = parent - smaller-child
-    (reference: FeatureHistogram::Subtract, feature_histogram.hpp:64-70)."""
-    return parent - child

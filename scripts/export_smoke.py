@@ -1,7 +1,6 @@
 """Exported-forest artifact gates (ISSUE 16).
 
-Three phases, one committed artifact (EXPORT_r01.json via
-BENCH_SHAPE=export):
+Three phases, one machine-readable artifact (`--out`):
 
 1. **round_trip** — train, pack an artifact carrying the f32 + f16 +
    int8 layouts over the full bucket ladder, reload it in-process, and

@@ -267,18 +267,7 @@ DESCRIPTIONS = {
     "gpu_device_id": "kept for API compat",
     "gpu_use_dp": "kept for API compat",
     "tpu_hist_chunk": "rows per histogram contraction step",
-    "tpu_double_precision": "f64 accumulation paths where supported",
-    "tpu_batch_k": "nodes speculatively expanded per histogram pass "
-                   "(auto-selected by shape when unset)",
     "tpu_hist_bf16": "bf16 hi+lo MXU histogram contraction",
-    "tpu_hist_subtract": "sibling-subtraction histogram cache (build "
-                         "the smaller child, derive the larger); "
-                         "auto-disabled when the cache exceeds budget",
-    "tpu_hist_compact": "gather-compacted small-node histogram passes: "
-                        "when the nodes expanded in one pass jointly "
-                        "hold few rows, contract only their gathered "
-                        "rows instead of the full dataset (ignored by "
-                        "the feature-parallel learner)",
     "tpu_compact_threshold": "row fraction below which a pass takes the "
                              "compacted path (also sizes the gather "
                              "buffer; >= 1.0 forces compaction, <= 0 "
@@ -298,9 +287,6 @@ DESCRIPTIONS = {
                        "scores every feature). Trees are bit-identical "
                        "either way; voting keeps its elected-slice "
                        "exchange and ignores this",
-    "tpu_hist_pallas": "retired; accepted for compatibility, warns and "
-                       "uses the XLA path (see profiles/README.md "
-                       "postmortem)",
     "tpu_hist_quantize": "quantized-gradient training: none (default) "
                          "= bit-exact f32 histogram path; int16/int8 = "
                          "per-iteration gradients/hessians scaled and "

@@ -1,11 +1,11 @@
 """Lint gate artifact: run graftlint over lightgbm_tpu/ + scripts/ and
-commit the machine-readable result (LINT_r01.json via BENCH_SHAPE=lint,
-the elastic/overload smoke-gate discipline).
+write the machine-readable result (the elastic/overload smoke-gate
+discipline).
 
 The artifact records per-rule counts, every unsuppressed finding (zero
 for a green gate), every suppression WITH its written reason, and stale
 baseline entries (also zero for green — the baseline must shrink, not
-rot). CI and reviewers read the committed artifact; the tier-1 pytest
+rot). The tier-1 pytest
 (tests/test_static_analysis.py) enforces the same zero-findings
 contract on every run.
 

@@ -82,11 +82,11 @@ def _ref_train_predict(exe, build_dir, tag, tr, te, conf, iters,
 def _binary_task(rows, iters, exe, build_dir):
     import numpy as np
 
-    import bench
+    from scripts import synth_data
     import lightgbm_tpu as lgb
 
     n_test = rows // 5
-    X, y = bench.synth_higgs(rows + n_test, 28, seed=11)
+    X, y = synth_data.synth_higgs(rows + n_test, 28, seed=11)
     Xtr, ytr, Xte, yte = X[:rows], y[:rows], X[rows:], y[rows:]
 
     ds = lgb.Dataset(Xtr, ytr, params=dict(PARAMS))
@@ -114,11 +114,11 @@ def _binary_task(rows, iters, exe, build_dir):
 def _categorical_task(rows, iters, exe, build_dir):
     import numpy as np
 
-    import bench
+    from scripts import synth_data
     import lightgbm_tpu as lgb
 
     n_test = rows // 5
-    X, y, cat_idx = bench.synth_expo(rows + n_test, seed=13)
+    X, y, cat_idx = synth_data.synth_expo(rows + n_test, seed=13)
     Xtr, ytr, Xte, yte = X[:rows], y[:rows], X[rows:], y[rows:]
     params = dict(PARAMS, categorical_feature=cat_idx)
 

@@ -3,18 +3,17 @@
 Builds /root/reference out-of-tree (its CMakeLists drops binaries into the
 source dir via EXECUTABLE_OUTPUT_PATH; we redirect both output paths into
 the build dir so the read-only reference tree stays pristine), generates
-the exact synthetic datasets bench.py uses, trains with the same
+the synthetic datasets of scripts/synth_data.py, trains with the same
 hyperparameters through the reference CLI, and records the measured
 mrow_iters/s:
 
 - BENCH_BASELINE.json        — the HIGGS-like headline shape (legacy
                                layout, kept for round-over-round compat)
 - BENCH_BASELINE_SHAPES.json — {shape: {...}} for the wide/sparse/
-                               categorical shapes (bench.py reads these
-                               for per-shape vs_baseline)
+                               categorical shapes
 
 Usage: python scripts/measure_baseline.py [shape ...]
-       (default: higgs; "all" = every bench.py shape)
+       (default: higgs; "all" = every shape of synth_data.SHAPES)
 
 The recorded `mrows_per_sec` is max(measured-here, REFERENCE_8T_FLOOR)
 for the higgs shape: this box may expose fewer cores than the reference's
@@ -81,9 +80,9 @@ def _write_tsv(path: str, y, X) -> None:
 
 
 def measure_shape(exe: str, shape: str) -> dict:
-    import bench
+    from scripts import synth_data
 
-    n_rows, builder, max_bin = bench.SHAPES[shape]
+    n_rows, builder, max_bin = synth_data.SHAPES[shape]
     built = builder(n_rows)
     cat_idx = built[2] if len(built) == 3 else None
     X, y = built[0], built[1]
@@ -98,8 +97,8 @@ def measure_shape(exe: str, shape: str) -> dict:
 
     conf = {
         "task": "train", "objective": "binary", "metric": "auc",
-        "data": data_path, "num_trees": bench.N_ITERS,
-        "learning_rate": 0.1, "num_leaves": bench.NUM_LEAVES,
+        "data": data_path, "num_trees": synth_data.N_ITERS,
+        "learning_rate": 0.1, "num_leaves": synth_data.NUM_LEAVES,
         "max_bin": max_bin, "min_data_in_leaf": 1,
         "min_sum_hessian_in_leaf": 100.0, "verbosity": 1,
         "num_threads": os.cpu_count() or 1,
@@ -112,7 +111,7 @@ def measure_shape(exe: str, shape: str) -> dict:
                     metric="multi_logloss")
 
     # one untimed run loads/caches the binned dataset file; the timed run
-    # then measures training the way bench.py does (construct untimed).
+    # then measures training alone (construct untimed).
     # NOTE: the binary caches max_bin/categorical config, so the cache is
     # keyed per shape (epsilon vs epsilon15 differ only in max_bin).
     bin_path = data_path + f".{shape}.bin"
@@ -143,7 +142,7 @@ def measure_shape(exe: str, shape: str) -> dict:
             except (ValueError, IndexError):
                 pass
 
-    measured = n_rows * bench.N_ITERS / train_time / 1e6
+    measured = n_rows * synth_data.N_ITERS / train_time / 1e6
     rec = measured if shape != "higgs" else max(measured, REFERENCE_8T_FLOOR)
     return {
         "mrows_per_sec": round(rec, 4),
@@ -152,17 +151,17 @@ def measure_shape(exe: str, shape: str) -> dict:
         "wall_seconds": round(wall, 3),
         "threads": os.cpu_count() or 1,
         "rows": n_rows, "features": int(X.shape[1]),
-        "iters": bench.N_ITERS,
-        "num_leaves": bench.NUM_LEAVES, "max_bin": max_bin,
+        "iters": synth_data.N_ITERS,
+        "num_leaves": synth_data.NUM_LEAVES, "max_bin": max_bin,
     }
 
 
 def main():
-    import bench
+    from scripts import synth_data
 
     shapes = sys.argv[1:] or ["higgs"]
     if shapes == ["all"]:
-        shapes = list(bench.SHAPES)
+        shapes = list(synth_data.SHAPES)
     exe = build_reference()
 
     shapes_path = os.path.join(REPO, "BENCH_BASELINE_SHAPES.json")

@@ -59,12 +59,12 @@ def _rank_data(n, f=28, qlen=100, seed=0):
 def ours(rows_list, iters=15):
     import numpy as np
 
-    import bench
+    from scripts import synth_data
     import lightgbm_tpu as lgb
     data = _load()
     for rows in rows_list:
         rows = int(rows)
-        X, y = bench.synth_higgs(rows, 28)
+        X, y = synth_data.synth_higgs(rows, 28)
         ds = lgb.Dataset(X, y, params=dict(PARAMS))
         ds.construct()
         lgb.train(dict(PARAMS), ds, num_boost_round=1, verbose_eval=False)
@@ -90,14 +90,14 @@ def ref(rows_list, iters=15):
     from measure_baseline import BUILD_DIR, build_reference
     import numpy as np
 
-    import bench
+    from scripts import synth_data
     exe = build_reference()
     data = _load()
     for rows in rows_list:
         rows = int(rows)
         path = os.path.join(BUILD_DIR, f"bench_{rows}.train")
         if not os.path.exists(path):
-            X, y = bench.synth_higgs(rows, 28)
+            X, y = synth_data.synth_higgs(rows, 28)
             np.savetxt(path, np.column_stack([y, X]), fmt="%.6g",
                        delimiter="\t")
         binp = path + ".bin"
@@ -131,9 +131,9 @@ def ref(rows_list, iters=15):
 
 
 def ours_amortized(rows=2_000_000, iters=500):
-    import bench
+    from scripts import synth_data
     import lightgbm_tpu as lgb
-    X, y = bench.synth_higgs(int(rows), 28)
+    X, y = synth_data.synth_higgs(int(rows), 28)
     ds = lgb.Dataset(X, y, params=dict(PARAMS))
     t0 = time.time()
     ds.construct()
@@ -220,17 +220,17 @@ def _predict_fixture(rows=500_000, trees=100):
     from measure_baseline import BUILD_DIR
     import numpy as np
 
-    import bench
+    from scripts import synth_data
     os.makedirs(BUILD_DIR, exist_ok=True)
     model = os.path.join(BUILD_DIR, f"predict_model_{rows}_{trees}.txt")
     data = os.path.join(BUILD_DIR, f"predict_data_{rows}.tsv")
     if not os.path.exists(data):
-        X, y = bench.synth_higgs(rows, 28, seed=7)
+        X, y = synth_data.synth_higgs(rows, 28, seed=7)
         np.savetxt(data, np.column_stack([y, X]), fmt="%.6g",
                    delimiter="\t")
     if not os.path.exists(model):
         import lightgbm_tpu as lgb
-        X, y = bench.synth_higgs(rows, 28, seed=7)
+        X, y = synth_data.synth_higgs(rows, 28, seed=7)
         ds = lgb.Dataset(X, y, params=dict(PARAMS))
         booster = lgb.train(dict(PARAMS), ds, num_boost_round=trees,
                             verbose_eval=False)

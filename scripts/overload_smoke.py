@@ -1,7 +1,6 @@
 """Overload-resilience gates for the serving tier (ISSUE 12).
 
-Four phases, one committed artifact (OVERLOAD_r01.json via
-BENCH_SHAPE=overload):
+Four phases, one machine-readable artifact (`--out`):
 
 1. **overload** — open-loop bench at ~2x saturation. Capacity is made
    deterministic with `faults.slow_predict` (every coalesced dispatch

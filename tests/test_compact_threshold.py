@@ -1,4 +1,4 @@
-"""The auto-chosen gather-compaction threshold: `grow.compact_threshold`
+"""The auto-chosen gather-compaction threshold: `schedule.compact_threshold`
 (shape in, row fraction out) and how `GBDT.init` uses it. Unset,
 `tpu_compact_threshold` is the break-even of a full pass against an index
 build plus gathers; given, it is used as given."""
@@ -6,9 +6,9 @@ import numpy as np
 import pytest
 
 import lightgbm_tpu as lgb
-from lightgbm_tpu.learner.grow import (COMPACT_FRACTION_MAX,
-                                       COMPACT_FRACTION_MIN, CompactChoice,
-                                       compact_threshold)
+from lightgbm_tpu.learner.schedule import (COMPACT_FRACTION_MAX,
+                                           COMPACT_FRACTION_MIN,
+                                           CompactChoice, compact_threshold)
 
 # the four widths read on the chip (PERF.md section 6, PR 27): features,
 # rows, rows as GBDT.init pads them; max_bin 63 everywhere
@@ -116,8 +116,7 @@ def test_no_reading_is_trusted_past_the_measured_widths():
 
 
 # ---------------------------------------------------------------------------
-# GBDT.init: unset means "from the shape"; an explicit value wins; the
-# switch wins over both
+# GBDT.init: unset means "from the shape"; an explicit value wins
 # ---------------------------------------------------------------------------
 PARAMS = {"objective": "binary", "num_leaves": 15, "max_bin": 255,
           "min_data_in_leaf": 1, "verbose": -1, "tpu_hist_chunk": 2048}
@@ -141,8 +140,7 @@ WIDE = {"rows": 8192, "features": 200}
     ({"tpu_compact_threshold": 0.25}, True, 0.25),
     ({"tpu_compact_threshold": 1.0}, True, 1.0),
     ({"tpu_compact_threshold": 0}, False, 0.0),
-    ({"tpu_compact_threshold": 0.25, "tpu_hist_compact": False},
-     False, 0.25),
+    ({"tpu_compact_threshold": -1.0}, False, -1.0),       # <= 0 disables
 ])
 def test_explicit_threshold_wins_on_a_narrow_table(params, compact, fraction):
     inner = _inner(**params)
@@ -159,7 +157,7 @@ def test_explicit_threshold_wins_on_a_narrow_table(params, compact, fraction):
     ({}, True, None),                                    # wide, unset
     ({"tpu_compact_threshold": 0.1}, True, 0.1),
     ({"tpu_compact_threshold": 0}, False, 0.0),
-    ({"tpu_hist_compact": False}, False, None),
+    ({"tpu_compact_threshold": 1.0}, True, 1.0),
 ])
 def test_unset_threshold_compacts_a_wide_table(params, compact, fraction):
     inner = _inner(**WIDE, **params)
@@ -193,11 +191,11 @@ def test_per_shard_rows_are_what_the_model_and_the_gate_see():
     assert not one_chunk._grower_cfg.hist_compact
 
 
-def test_forced_compaction_past_the_model_gets_a_buffer(monkeypatch):
-    """LGBM_TPU_FORCE_COMPACT=1 (the debug override) on a shape whose
-    model says 0 still has to compact something: it takes the old 0.25."""
-    monkeypatch.setenv("LGBM_TPU_FORCE_COMPACT", "1")
-    inner = _inner()
+def test_compaction_past_the_model_gets_the_buffer_asked_for():
+    """An explicit threshold on a shape whose model says 0 compacts with
+    the buffer the user asked for (how `scripts/profile_train.py`
+    re-measures the model's constants)."""
+    inner = _inner(tpu_compact_threshold=COMPACT_FRACTION_MAX)
     assert inner._schedule_info["compact_model"]["fraction"] == 0.0
     assert inner._grower_cfg.hist_compact
     assert inner._grower_cfg.compact_fraction == COMPACT_FRACTION_MAX

@@ -4,8 +4,6 @@ Pins the subsystem's contracts end to end:
 
 - off-mode is byte-identical: `linear_tree=false` produces exactly the
   model text the default path produces, with no linear sections;
-- the fused histogram moment channels equal direct numpy marginals for
-  every (leaf, feature) — the seam tying ops/histogram to the solver;
 - the post-growth fit is schedule-invariant: the data-parallel scatter
   grower's state feeds the SAME fit program and yields bitwise-identical
   coefficients to the serial grower (child process, 2 forced host
@@ -131,41 +129,6 @@ def test_export_future_format_refused_by_name(trained, tmp_path):
         fh.write(patched)
     with pytest.raises(ArtifactError, match="format"):
         load_artifact(skew)
-
-
-# ---------------------------------------------------------------------------
-# histogram moment channels vs direct marginals
-# ---------------------------------------------------------------------------
-def test_moment_channels_match_direct_marginals():
-    """[C, F, 4] = (sum w x, sum w x^2, sum w g x, sum w h x) from the
-    fused per-bin kernel must equal numpy contractions exactly (f32
-    sums over a few hundred rows are exactly reproducible)."""
-    import jax.numpy as jnp
-    from lightgbm_tpu.linear.stats import leaf_feature_moments
-
-    rng = np.random.RandomState(7)
-    n, f, b, chunk = 256, 4, 16, 64
-    binned = rng.randint(0, b, (n, f)).astype(np.uint8)
-    x = rng.randn(n, f).astype(np.float32)
-    g = rng.randn(n).astype(np.float32)
-    h = np.abs(rng.randn(n)).astype(np.float32)
-    m = (rng.rand(n) < 0.8).astype(np.float32)
-    ids = np.array([0, 1, 2], np.int32)
-    leaf_id = rng.randint(0, 3, n).astype(np.int32)
-    weights = np.stack([g * m, h * m, m], axis=1)
-    got = np.asarray(leaf_feature_moments(
-        jnp.asarray(binned), jnp.asarray(x), jnp.asarray(weights),
-        jnp.asarray(leaf_id), ids, b, chunk=chunk))
-    assert got.shape == (3, f, 4)
-    for c, lid in enumerate(ids):
-        w = m * (leaf_id == lid)
-        for j in range(f):
-            want = np.array([(w * x[:, j]).sum(),
-                             (w * x[:, j] ** 2).sum(),
-                             (w * g * x[:, j]).sum(),
-                             (w * h * x[:, j]).sum()], np.float32)
-            np.testing.assert_allclose(got[c, j], want, rtol=1e-5,
-                                       atol=1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -8,7 +8,7 @@ retriable `ServingOverload`/`DeadlineExceeded` (never a silent drop or
 an unbounded queue wait), admitted requests stay bit-identical to an
 unloaded serve, and the defaults (every cap 0) reproduce the
 pre-admission behavior exactly. The full 2x-saturation storm runs in
-scripts/overload_smoke.py (BENCH_SHAPE=overload); the tier-1 tests
+scripts/overload_smoke.py; the tier-1 tests
 here exercise each mechanism in isolation at millisecond scale.
 """
 from __future__ import annotations

@@ -285,7 +285,7 @@ def test_split_passes(pass_rows, num_passes, cap, want):
     ({"tpu_hist_chunk": 2048, "tpu_compact_threshold": 0.25}, True),
     ({"tpu_hist_chunk": 65536, "tpu_compact_threshold": 0.25}, False),
     # unset: 8 groups x 63 bins is narrower than any table whose full
-    # pass costs more than the index build (grow.compact_threshold)
+    # pass costs more than the index build (schedule.compact_threshold)
     ({"tpu_hist_chunk": 2048}, False),
 ])
 def test_tree_record_identities(params, compacts):

@@ -15,9 +15,13 @@ import pytest
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu import telemetry
-from lightgbm_tpu.boosting.gbdt import pick_schedule
-from lightgbm_tpu.ingest.landing import plan_row_layout
-from lightgbm_tpu.learner.grow import COMPACT_FRACTION_MAX
+from lightgbm_tpu.config import Config
+from lightgbm_tpu.learner import schedule
+from lightgbm_tpu.learner.grow import GrowerConfig
+from lightgbm_tpu.learner.schedule import (COMPACT_FRACTION_MAX,
+                                           compact_capacity, pick_schedule,
+                                           plan_row_layout,
+                                           subtract_cache_fits)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmarks")
@@ -84,14 +88,157 @@ def test_where_the_two_switches_turn(features, subtract, compact):
 def test_what_the_user_set_wins_over_the_shape():
     shape = (2000, 63, 1 << 20, 1 << 20, 8192)
     assert not pick_schedule(*shape, num_leaves=255,
-                             hist_compact=False).compact
-    given = pick_schedule(*shape, num_leaves=255, compact_fraction=0.1,
-                          batch_k=5)
+                             compact_fraction=0.0).compact
+    given = pick_schedule(*shape, num_leaves=255, compact_fraction=0.1)
     assert given.compact and given.compact_fraction == 0.1
-    assert given.batch_k == 5 and given.compact_model.fraction == 0.25
+    assert given.batch_k == 12 and given.compact_model.fraction == 0.25
     # one chunk of rows has nothing to skip
     assert not pick_schedule(2000, 63, 8192, 8192, 8192,
                              num_leaves=255).compact
+
+
+# what the parent commit of PR 30 (9f626d5) handed the grower for the two
+# cells' shapes, written down from it: RowLayout, Schedule, the schedule
+# fields of GrowerConfig, the compaction buffer's rows
+GOLDEN = {
+    "higgs-train-1chip": (
+        (21_000_000, 28),
+        (65536, 65536, 25_165_824, 1, 1),
+        (False, True, 12, False, 0.0,
+         (0.0, 3.4, 8.987794285714285, 46.9), 24),
+        {"chunk": 65536, "batch_k": 24, "hist_subtract": True,
+         "hist_compact": False, "compact_fraction": 0.0, "table_mult": 12},
+        0),
+    "epsilon-train-1chip": (
+        (1_048_576, 2000),
+        (8192, 8192, 1_048_576, 1, 1),
+        (True, False, 12, True, 0.25, (0.25, 181.4, 7.5, 64.5), 12),
+        {"chunk": 8192, "batch_k": 12, "hist_subtract": False,
+         "hist_compact": True, "compact_fraction": 0.25, "table_mult": 12},
+        262_144),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(GOLDEN))
+def test_the_cells_schedules_are_the_parents_to_the_field(cell):
+    """No dataset is built: layout, schedule and the grower's static
+    schedule fields for `max_bin` 63, 255 leaves, serial, one class."""
+    (rows, features), layout_want, picked_want, fields_want, cap_want = \
+        GOLDEN[cell]
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        declared = json.load(fh)
+    config_name, = [w["config"] for w in declared["workloads"]
+                    if w["name"] == cell]
+    config_file, = [c["file"] for c in declared["configs"]
+                    if c["name"] == config_name]
+    with open(os.path.join(ROOT, config_file)) as fh:
+        config = json.load(fh)
+    assert (int(config["rows"]), int(config["features"])) == (rows, features)
+    assert (config["params"]["max_bin"], config["params"]["num_leaves"]) \
+        == (63, 255)
+    layout = plan_row_layout(rows, features, 63)
+    assert tuple(layout) == layout_want
+    picked = pick_schedule(features, 63, rows, layout.n_pad, layout.chunk,
+                           num_leaves=255)
+    assert tuple(picked._replace(
+        compact_model=tuple(picked.compact_model))) == picked_want
+    cfg = GrowerConfig(
+        num_leaves=255, max_bins=63, lambda_l1=0.0, lambda_l2=0.0,
+        min_gain_to_split=0.0, min_data_in_leaf=1,
+        min_sum_hessian_in_leaf=100.0, max_depth=-1,
+        **picked.grower_fields(layout.chunk))
+    assert {key: getattr(cfg, key) for key in fields_want} == fields_want
+    assert [type(getattr(cfg, key)) for key in fields_want] \
+        == [type(value) for value in fields_want.values()]
+    assert compact_capacity(cfg, layout.n_pad) == cap_want
+
+
+def test_the_schedule_module_loads_without_jax():
+    """The ingest side plans a landing's rows without the grower."""
+    import subprocess
+    code = (
+        "import sys\n"
+        "sys.modules['jax'] = sys.modules['jaxlib'] = None\n"
+        "from lightgbm_tpu.learner.schedule import plan_row_layout\n"
+        "print(plan_row_layout(21_000_000, 28, 63).n_pad)\n"
+        "assert not [m for m in sys.modules if m.split('.')[0] == 'jax'"
+        " and sys.modules[m] is not None]\n")
+    out = subprocess.run(
+        [sys.executable, "-c", code], cwd=ROOT, capture_output=True,
+        text=True, timeout=120,
+        env=dict(os.environ, LIGHTGBM_TPU_COMPILE_CACHE="0"))
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == ["25165824"]
+
+
+SMALL = {"objective": "binary", "num_leaves": 4, "max_bin": 15,
+         "min_data_in_leaf": 1, "verbose": -1, "tpu_hist_chunk": 256}
+
+
+def _small_booster(**params):
+    rng = np.random.RandomState(3)
+    X = rng.randn(1024, 4).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.float32)
+    params = dict(SMALL, **params)
+    return lgb.Booster(params, lgb.Dataset(X, y, params=params))
+
+
+@pytest.mark.parametrize("kind,name,value", [
+    ("option", "tpu_hist_pallas", "true"),
+    ("option", "tpu_double_precision", "true"),
+    ("option", "tpu_hist_subtract", "false"),
+    ("option", "tpu_hist_compact", "false"),
+    ("option", "tpu_batch_k", "4"),
+    # each value changed the schedule at the parent of PR 30
+    ("env", "LGBM_TPU_TABLE_MULT", "4"),
+    ("env", "LGBM_TPU_FORCE_SUBTRACT", "0"),
+    ("env", "LGBM_TPU_FORCE_COMPACT", "1"),
+    ("env", "LGBM_TPU_NO_PIPELINE", "1"),
+])
+def test_a_removed_switch_switches_nothing(kind, name, value, monkeypatch):
+    if kind == "option":
+        # refused as any key the program never heard of is
+        for key in (name, "tpu_no_such_option"):
+            with pytest.raises(lgb.log.LightGBMError,
+                               match="Unknown parameter: " + key):
+                Config.from_params({key: value})
+        return
+    want = _small_booster()._inner
+    monkeypatch.setenv(name, value)
+    booster = _small_booster()
+    assert booster._inner._schedule_info == want._schedule_info
+    assert booster._inner._grower_cfg == want._grower_cfg
+    if name == "LGBM_TPU_NO_PIPELINE":
+        booster.update()
+        assert booster._inner._pending_small is not None, \
+            "the tree is still fetched one iteration late"
+
+
+@pytest.mark.parametrize("models,fits", [(6, True), (7, False)])
+def test_the_sweep_and_the_schedule_ask_one_predicate(models, fits,
+                                                      monkeypatch):
+    """120 groups x 63 bins x 31 leaves: one cache is 38.5 MB at
+    `table_mult` 12, so six copies fit the 256 MiB budget and seven do
+    not; a single job subtracts either way."""
+    from lightgbm_tpu.boosting import sweep as sweep_mod
+    assert sweep_mod.subtract_cache_fits is subtract_cache_fits
+    assert subtract_cache_fits(120, 63, 31, 12, copies=models) == fits
+    rng = np.random.RandomState(3)
+    X = rng.randn(512, 120).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 31, "max_bin": 63,
+              "min_data_in_leaf": 1, "verbose": -1}
+    trainer = sweep_mod.SweepTrainer(
+        [dict(params, lambda_l2=float(k)) for k in range(models)],
+        lgb.Dataset(X, y, params=params), 1)
+    info = trainer.lead._schedule_info
+    assert (info["groups"], info["max_bin"]) == (120, 63)
+    assert info["subtract"] and info["table_mult"] == 12
+    assert trainer.cfg.hist_subtract == fits
+    # and `pick_schedule` has no arithmetic of its own beside it
+    monkeypatch.setattr(schedule, "subtract_cache_fits",
+                        lambda *a, **k: False)
+    assert not pick_schedule(120, 63, 512, 512, 512, num_leaves=31).subtract
 
 
 # ---------------------------------------------------------------------------
@@ -358,7 +505,7 @@ def test_wide_trees_do_not_depend_on_compaction(wide):
     """Compaction is a pure scheduling choice: switched off, the same
     splits, and leaf values up to float32 summation order."""
     with_compaction = wide.sound()["trees_window"]
-    params = dict(wide.base["config"]["params"], tpu_hist_compact=False)
+    params = dict(wide.base["config"]["params"], tpu_compact_threshold=0)
     booster = lgb.Booster(params, wide.prepared["ds"])
     for _ in range(1 + len(with_compaction)):
         booster.update()
