@@ -184,6 +184,12 @@ def feature_fraction_mask(rng, frac: float, num_features: int,
     return mask
 
 
+def _device_memory_bytes(device) -> int:
+    """The device's memory as its backend reports it (`bytes_limit`), 0
+    where it reports none (the CPU): `schedule.subtract_cache_budget`."""
+    return int((device.memory_stats() or {}).get("bytes_limit", 0))
+
+
 def _pad_to(arr: np.ndarray, n: int, value=0):
     pad = n - arr.shape[0]
     if pad <= 0:
@@ -790,6 +796,9 @@ class GBDT:
         # grower's own guard sees
         g_cnt = max(1, int(train_data.num_groups))
         shards = layout.row_multiple // layout.chunk  # this process's
+        # the memory the subtraction cache is judged against (a device
+        # of THIS process: `devs` is global under multi-host)
+        self._device_bytes = _device_memory_bytes(jax.local_devices()[0])
         picked = pick_schedule(
             g_cnt, self._max_bins, n // shards, n_pad // shards,
             layout.chunk, num_leaves=self.config.tree.num_leaves,
@@ -800,7 +809,8 @@ class GBDT:
             compact_fraction=(
                 float(self.config.tree.tpu_compact_threshold)
                 if "tpu_compact_threshold" in self.config.raw_params
-                else None))
+                else None),
+            device_bytes=self._device_bytes)
         costs = picked.compact_model
         log.info("Schedule: groups=%d max_bin=%d wide=%s subtract=%s "
                  "compact=%s@%.3f (ns a row: full=%.1f index=%.1f "
@@ -915,7 +925,8 @@ class GBDT:
             tree_learner=self._tree_learner_kind, num_processes=nproc,
             hist_reduce=((hist_reduce if use_scatter else "allreduce")
                          if self._tree_learner_kind == "data" else None),
-            owned_groups=g_pad // ndev if use_scatter else g_cnt)
+            owned_groups=g_pad // ndev if use_scatter else g_cnt,
+            device_bytes=self._device_bytes)
 
         # boost from average (gbdt.cpp:358-378): the score bump happens at
         # init; the bias itself is folded into the first trained tree via

@@ -193,7 +193,8 @@ class SweepTrainer:
         if self.cfg.hist_subtract and not subtract_cache_fits(
                 max(1, int(gb.train_data.num_groups)), gb._max_bins,
                 lead_cfg.tree.num_leaves, self.cfg.table_mult,
-                classes=self.kc, copies=K):
+                classes=self.kc, copies=K, rows_padded=self.n_pad,
+                device_bytes=gb._device_bytes):
             log.warning(
                 "Sweep: %d sibling-subtraction caches exceed the "
                 "device budget; disabling subtraction for the sweep. "

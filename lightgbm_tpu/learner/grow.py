@@ -73,12 +73,13 @@ histogram cost tracks the leaf (serial_tree_learner.cpp:349-363,
 data_partition.hpp:94-170). Here, when a pass's selected nodes jointly
 hold at most compact_fraction*N in-bag rows (they are exactly the rows
 relabeled this pass, so membership is ONE compare against the
-allocation pointer), their indices are compacted by a stable cumsum
-scatter into a fixed-capacity chunk-multiple buffer and the SAME
-contraction runs over the gathered subset with a dynamic trip count
-(ops/histogram.gathered_leaves_histogram) — shapes stay compile-stable,
-and per-pass cost drops to O(rows-in-selected-nodes). Selection,
-routing, and split scans are unchanged, so trees keep the
+allocation pointer; under subtraction only the smaller children's rows
+count and are gathered, `rows_of_nodes`), their indices are compacted
+by a stable cumsum scatter into a fixed-capacity chunk-multiple buffer
+and the SAME contraction runs over the gathered subset with a dynamic
+trip count (ops/histogram.gathered_leaves_histogram) — shapes stay
+compile-stable, and per-pass cost drops to O(rows-in-selected-nodes).
+Selection, routing, and split scans are unchanged, so trees keep the
 bit-identical-to-sequential guarantee on order-invariant sums (the
 gather only reorders f32 partial sums, like subtraction). The
 `rows_contracted` / `pass_rows` counters record the realized economics
@@ -1078,11 +1079,18 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             # rows just relabeled to fresh child ids — every id >=
             # next_free is new this pass (the allocation pointer is
             # monotone), so membership is one compare, no K-loop.
+            # Under subtraction only the SMALLER child of a node lands
+            # in a channel (the larger is parent - smaller), so only
+            # its rows are members: the K ids of `hist_ids`.
             # Zero-weight (out-of-bag / padding) rows contribute zero to
             # every channel either way; excluding them keeps small
             # bagged nodes inside the buffer.
             with scope("lgbm/grow/compact_index"):
-                member = (leaf_id >= carry.next_free) & (w3[:, 2] > 0.0)
+                if subtract:
+                    in_pass = rows_of_nodes(leaf_id, hist_ids)
+                else:
+                    in_pass = leaf_id >= carry.next_free
+                member = in_pass & (w3[:, 2] > 0.0)
                 cnt = jnp.sum(member.astype(jnp.int32))
                 use_compact = cnt <= cap
 
@@ -1128,7 +1136,15 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             # larger child = parent - smaller (the cache holds every
             # created node's histogram; parents are always present)
             with scope("lgbm/grow/subtract"):
-                parent_h = carry.hist_cache[sel_c]           # [K, fl, B, 3]
+                # K reads of one slot each, not `hist_cache[sel_c]`: that
+                # gather XLA:TPU serves by slicing the WHOLE cache in two
+                # (`mini-gather-slice`), a second buffer of the cache's
+                # size copied every pass (7.4 ms a pass at 2.39 GB, 14.2
+                # at 4.7: TPU v5e, PR 31, PERF.md section 6)
+                parent_h = jnp.stack([
+                    jax.lax.dynamic_index_in_dim(
+                        carry.hist_cache, sel_c[k], axis=0, keepdims=False)
+                    for k in range(K)])                      # [K, fl, B, 3]
                 other = parent_h - hists
                 sl4 = small_left[:, None, None, None]
                 hists = jnp.concatenate([jnp.where(sl4, hists, other),
@@ -1408,6 +1424,13 @@ def leaf_path_features(leaf_parent, node_feature, node_left, node_right,
         return feats
 
     return jax.vmap(one_leaf)(leaf_parent.astype(jnp.int32))
+
+
+def rows_of_nodes(leaf_id, node_ids):
+    """[N] bool: the rows whose label is one of the K `node_ids` (-1 for
+    an empty slot; labels are never negative). K compares a row with the
+    rows on the minor axis, no gather: K is a pass's batch."""
+    return jnp.any(leaf_id[None, :] == node_ids[:, None], axis=0)
 
 
 def shard_group_widths(group_widths, num_shards: int):

@@ -58,24 +58,57 @@ def plan_row_layout(n: int, num_groups: int, max_num_bin: int, *,
                      local_dev=local_dev)
 
 
-# ceiling for the sibling-subtraction histogram cache ([M, G, B, 3] f32
-# per class tree); beyond it the grower builds both children directly.
-# Deliberately modest: a near-HBM-sized cache (Epsilon-shape at 2 GiB
-# measured) thrashes the while-loop carry and stalls training outright
+# The sibling-subtraction histogram cache ([M, G, B, 3] float32 per class
+# tree) is kept where it fits the device, judged from what the code can
+# observe: the device's memory (`bytes_limit` of its `memory_stats()`,
+# handed in by the caller: this module imports no jax) less the binned
+# matrix the shape already holds there. The cache may take a third of
+# what is left; past that the grower builds both children directly.
+# Read on a TPU v5e (PR 31, `scripts/profile_train.py` on
+# `epsilon-400kx2000`, 1,048,576 x 2000 x 63 bins, PERF.md section 6): a
+# cache of 2.39 GB costs the program one buffer of its size
+# (`peak_bytes_reserved` 4.28 -> 6.72 GB; 9.55 GB with one of 4.7 GB) and,
+# read and written a slot at a time, 0.005 s of a 2.49 s pair of trees;
+# no stall. A third leaves room for a second buffer of it, which XLA held
+# while `grow.py` read the parents by a gather.
+SUBTRACT_CACHE_SHARE = 1.0 / 3.0
+# A backend that reports no memory (the CPU) keeps a fixed budget
 _SUBTRACT_CACHE_BUDGET = 256 << 20
+
+
+def subtract_cache_bytes(groups: int, max_bins: int, num_leaves: int,
+                         table_mult: int, *, classes: int = 1,
+                         copies: int = 1) -> int:
+    """Bytes of `copies` subtraction caches of this shape. A cache holds
+    one [G, B, 3] float32 histogram per class tree for every node-table
+    slot: `table_mult` per configured leaf plus the widest batch's
+    speculative children (grow.py: M = table_mult * L + 2K + 2, K <= 25)."""
+    slots = table_mult * num_leaves + 52
+    return slots * classes * groups * max_bins * 3 * 4 * copies
+
+
+def subtract_cache_budget(groups: int, max_bins: int, rows_padded: int,
+                          device_bytes: int) -> int:
+    """Bytes the subtraction caches of a shape may take on a device of
+    `device_bytes` (0: the backend reports none) that already holds the
+    shape's binned matrix, `rows_padded` x `groups` bin indices."""
+    if device_bytes <= 0:
+        return _SUBTRACT_CACHE_BUDGET
+    held = rows_padded * groups * (1 if max_bins <= 256 else 2)
+    return max(0, int((device_bytes - held) * SUBTRACT_CACHE_SHARE))
 
 
 def subtract_cache_fits(groups: int, max_bins: int, num_leaves: int,
                         table_mult: int, *, classes: int = 1,
-                        copies: int = 1) -> bool:
+                        copies: int = 1, rows_padded: int = 0,
+                        device_bytes: int = 0) -> bool:
     """Whether `copies` subtraction caches of this shape stay inside the
-    budget. A cache holds one [G, B, 3] float32 histogram per class tree
-    for every node-table slot: `table_mult` per configured leaf plus the
-    widest batch's speculative children (grow.py: M = table_mult * L +
-    2K + 2, K <= 25)."""
-    slots = table_mult * num_leaves + 52
-    slot_bytes = classes * groups * max_bins * 3 * 4
-    return slots * slot_bytes * copies <= _SUBTRACT_CACHE_BUDGET
+    budget of a device of `device_bytes` holding `rows_padded` rows of it
+    (`subtract_cache_budget`)."""
+    return subtract_cache_bytes(
+        groups, max_bins, num_leaves, table_mult, classes=classes,
+        copies=copies) <= subtract_cache_budget(
+            groups, max_bins, rows_padded, device_bytes)
 
 
 def _compact_rows(n: int, chunk: int, fraction: float,
@@ -120,7 +153,7 @@ class CompactChoice(NamedTuple):
 # The model's constants: TPU v5 lite, one chip, `scripts/profile_train.py`
 # on `synth_higgs` at max_bin 63 / 255 leaves, compaction off against on
 # at 0.25, two traced trees a run, at 28, 137, 700 and 2000 features
-# (PERF.md section 6, PR 27, has the table). Stored groups x bins -> ns a
+# (PERF.md section 6, PR 27, has the readings). Stored groups x bins -> ns a
 # row of `lgbm/hist/contract` with every pass full. Stored groups -> ns a
 # gathered row: `lgbm/hist/gather` (bins, the three channels, leaf ids;
 # 43-47 ns at every width) plus what the contraction of gathered chunks
@@ -191,12 +224,14 @@ def pick_schedule(groups: int, max_bins: int, rows: int, rows_padded: int,
                   chunk: int, *, num_leaves: int, classes: int = 1,
                   learner: str = "serial", bundled: bool = False,
                   quantize: str = "none",
-                  compact_fraction: Optional[float] = None) -> Schedule:
+                  compact_fraction: Optional[float] = None,
+                  device_bytes: int = 0) -> Schedule:
     """The execution schedule as a function of the shape: stored groups,
     bins of the widest group, rows and padded rows of ONE shard, and the
-    histogram chunk (`plan_row_layout` gives the last two).
-    `compact_fraction` is the one thing a user can set
-    (`tpu_compact_threshold`); None leaves it to the shape.
+    histogram chunk (`plan_row_layout` gives the last two); and of the
+    device: `device_bytes` is its memory, 0 where the backend reports
+    none (`subtract_cache_budget`). `compact_fraction` is the one thing
+    a user can set (`tpu_compact_threshold`); None leaves it to the shape.
 
     "Wide" shapes (large groups x bins) are channel-cost-bound in the
     histogram contraction (the [G*B, chunk] x [chunk, S] matmul's FLOPs
@@ -206,13 +241,21 @@ def pick_schedule(groups: int, max_bins: int, rows: int, rows_padded: int,
     wide = groups * max_bins > 8192
     # sibling subtraction: the per-node [M, G, B, 3] histogram cache must
     # fit the budget (vmap'd class trees each carry their own cache).
-    # Node-table size rides the same budget: generous tables keep
-    # late-boosting speculation wide (grow.py table notes) — use the
-    # largest table_mult in [6, 12] whose cache still fits; without the
-    # cache the table is [M]-scalar cheap, so take the max.
+    # Node-table size rides on it: generous tables keep late-boosting
+    # speculation wide (grow.py table notes), so where slots are cheap
+    # take the largest table_mult in [6, 12] whose cache stays inside the
+    # fixed 256 MB; a cache past that, which only a device that reports
+    # room allows, takes 6: on the run above trees 3-4 filled 509-527 of
+    # its 1,548 slots, and 12 read the same passes for 2.8 GB more.
+    # Without the cache the table is [M]-scalar cheap: take the max.
+    def cache_fits(mult: int, device: int) -> bool:
+        return subtract_cache_fits(groups, max_bins, num_leaves, mult,
+                                   classes=classes, rows_padded=rows_padded,
+                                   device_bytes=device)
+
     mult_fit = next((m for m in range(12, 5, -1)
-                     if subtract_cache_fits(groups, max_bins, num_leaves, m,
-                                            classes=classes)), 0)
+                     if cache_fits(m, 0) and cache_fits(m, device_bytes)),
+                    6 if cache_fits(6, device_bytes) else 0)
     subtract = (learner == "serial"
                 # vmap'd class trees each carry a cache: the x classes
                 # scatter/memory traffic measured a net LOSS on the
@@ -242,16 +285,21 @@ def pick_schedule(groups: int, max_bins: int, rows: int, rows_padded: int,
                and _compact_rows(rows_padded, chunk, compact_fraction,
                                  learner == "feature") > 0)
     if subtract:
-        # one smaller-child channel set per node: 25*(3+2) fills the
-        # 128-lane tile; wide shapes stay narrow (channel-cost-bound
-        # passes + depth-bound trees — K=8 matches the channel cost
-        # of the round-4 K=4 direct path while expanding 2x nodes)
+        # one smaller-child channel set per node: 24 x (3 hi + 2 lo)
+        # fills the 128-lane tile. A wide shape's pass is not tile-bound:
+        # on the run above a contracted row costs 121-122 ns at 30-40
+        # matmul columns and 166-176 at 50-120, and fewer nodes a pass
+        # leave more passes under the compaction threshold; two trees at
+        # batch_k 4 / 6 / 8 / 10 / 16 took 2.88 / 3.09 / 2.49 / 3.39 /
+        # 3.56 s of device time (12 and 24, before the parents' gather
+        # was cured: 3.88 and 3.58, of which 0.40 and 0.25 the gather).
+        # 137-224 groups keep 8 unread (no cell; ROADMAP Queue 3 item 11)
         batch_k = 8 if wide else 24
     else:
         # Bosch-class data (wide AND heavily EFB-bundled — sparse
         # one-hot blocks) measured fastest at K=4: deep depth-bound
         # trees, channel-cost-bound passes. Unbundled wide shapes
-        # (Epsilon) keep the full-tile default.
+        # keep the full-tile default.
         batch_k = 4 if (wide and bundled) else 12
     if quantize == "int8":
         # int8 contracts 3 channels per node id instead of the bf16
@@ -267,7 +315,8 @@ def pick_schedule(groups: int, max_bins: int, rows: int, rows_padded: int,
 
 def schedule_info(picked: Schedule, layout: RowLayout, cfg, *, rows: int,
                   groups: int, tree_learner: str, num_processes: int,
-                  hist_reduce: Optional[str], owned_groups: int) -> dict:
+                  hist_reduce: Optional[str], owned_groups: int,
+                  device_bytes: int = 0) -> dict:
     """JSON-safe record of the schedule a run took (`GBDT._schedule_info`,
     the telemetry run-log header, the benchmark's `schedule`): the knobs
     that explain its pass economics, host-readable without re-deriving
@@ -284,6 +333,13 @@ def schedule_info(picked: Schedule, layout: RowLayout, cfg, *, rows: int,
         "hist_reduce": hist_reduce, "owned_groups": int(owned_groups),
         "groups": int(groups), "max_bin": int(cfg.max_bins),
         "wide": bool(picked.wide), "subtract": bool(picked.subtract),
+        # the cache's bytes (one class tree: `pick_schedule` subtracts
+        # for no more) and the device memory it was judged against (0:
+        # the backend reports none, `subtract_cache_budget`)
+        "subtract_cache_bytes": subtract_cache_bytes(
+            groups, cfg.max_bins, cfg.num_leaves, picked.table_mult)
+        if picked.subtract else 0,
+        "device_bytes": int(device_bytes),
         "compact": bool(picked.compact),
         "compact_fraction": picked.compact_fraction,
         # the pass-cost model's answer for this (per-shard) shape,

@@ -3,8 +3,9 @@
 program picks for such a shape, the benchmark's generator, the dataset
 layer's `ConstructRecord`, the readers the cell brought, and the program
 against the benchmark's plain reference on a table wide enough to take
-the cell's KIND of schedule (no subtraction cache, gather-compaction on)
-with no `tpu_*` option. Nothing here is a device measurement."""
+a wide schedule (gather-compaction on; the subtraction cache off at 255
+leaves inside the CPU's fixed budget, on at 31) with no `tpu_*` option.
+Nothing here is a device measurement."""
 import json
 import os
 import sys
@@ -33,6 +34,9 @@ import datagen  # noqa: E402
 import run as harness  # noqa: E402
 
 CELL = "epsilon-train-1chip"
+# `bytes_limit` of the TPU v5e's `memory_stats()` (PERF.md's head), which
+# `GBDT.init` hands `pick_schedule` there; the CPU backend reports none
+V5E_BYTES = 16_909_336_064
 
 
 def _config():
@@ -53,36 +57,59 @@ def test_the_cells_shape_takes_the_wide_schedule(rows):
     assert layout.chunk == 8192
     assert layout.n_pad == rows, "the rung leaves no padded rows"
     picked = pick_schedule(groups, bins, rows, layout.n_pad, layout.chunk,
-                           num_leaves=int(config["params"]["num_leaves"]))
-    assert picked.wide and not picked.subtract
+                           num_leaves=int(config["params"]["num_leaves"]),
+                           device_bytes=V5E_BYTES)
+    assert picked.wide and picked.subtract
     assert picked.compact
     assert picked.compact_fraction == COMPACT_FRACTION_MAX == 0.25
-    assert picked.batch_k == 12 and picked.table_mult == 12
+    assert picked.batch_k == 8 and picked.table_mult == 6
     assert not any(key.startswith("tpu_") for key in config["params"])
+    # where the backend reports no memory the cache of 2.39 GB and more
+    # stays out, and the direct path takes both children of 12 nodes
+    unseen = pick_schedule(groups, bins, rows, layout.n_pad, layout.chunk,
+                           num_leaves=int(config["params"]["num_leaves"]))
+    assert unseen == picked._replace(subtract=False, batch_k=12,
+                                     table_mult=12)
 
 
-def test_the_other_cells_shape_sits_on_the_other_side():
-    """`higgs-train-1chip`: the subtraction cache fits and the index build
-    never pays, so the two cells guard the two sides of both switches."""
+@pytest.mark.parametrize("device_bytes", [0, V5E_BYTES])
+def test_the_other_cells_shape_sits_on_the_other_side(device_bytes):
+    """`higgs-train-1chip`: the index build never pays, so the two cells
+    guard the two sides of the compaction switch; its cache fits any
+    budget."""
     layout = plan_row_layout(21_000_000, 28, 63)
     picked = pick_schedule(28, 63, 21_000_000, layout.n_pad, layout.chunk,
-                           num_leaves=255)
+                           num_leaves=255, device_bytes=device_bytes)
     assert (layout.chunk, layout.n_pad) == (65536, 25_165_824)
     assert picked.subtract and not picked.compact and not picked.wide
     assert picked.batch_k == 24 and picked.compact_fraction == 0.0
+    assert picked.table_mult == 12
 
 
-@pytest.mark.parametrize("features,subtract,compact", [
-    (137, True, False), (200, True, True), (224, True, True),
-    (225, False, True), (700, False, True)])
-def test_where_the_two_switches_turn(features, subtract, compact):
-    """At max_bin 63 and 255 leaves the 256 MB cache budget is missed
-    from 225 stored groups, and the pass-cost model compacts from 158."""
+@pytest.mark.parametrize("device_bytes,features,max_bin,subtract,compact", [
+    # no memory reported: the fixed 256 MB is missed from 225 stored groups
+    (0, 137, 63, True, False), (0, 200, 63, True, True),
+    (0, 224, 63, True, True), (0, 225, 63, False, True),
+    (0, 700, 63, False, True),
+    # the v5e: a third of what the binned matrix leaves. At `max_bin` 63
+    # every width of the cell's rows keeps the cache; at 255 it is missed
+    # from 1,086 stored groups, so 2000 x 255 is on the direct path
+    (V5E_BYTES, 137, 63, True, False), (V5E_BYTES, 225, 63, True, True),
+    (V5E_BYTES, 700, 63, True, True), (V5E_BYTES, 2000, 63, True, True),
+    (V5E_BYTES, 1085, 255, True, True), (V5E_BYTES, 1086, 255, False, True),
+    (V5E_BYTES, 2000, 255, False, True)])
+def test_where_the_two_switches_turn(device_bytes, features, max_bin,
+                                     subtract, compact):
+    """At 255 leaves and 2^20 rows: where the cache's budget is missed,
+    on a backend that reports no memory and on the v5e, and that the
+    pass-cost model compacts from 158 groups at `max_bin` 63."""
     rows = 1 << 20
-    layout = plan_row_layout(rows, features, 63)
-    picked = pick_schedule(features, 63, rows, layout.n_pad, layout.chunk,
-                           num_leaves=255)
+    layout = plan_row_layout(rows, features, max_bin)
+    picked = pick_schedule(features, max_bin, rows, layout.n_pad,
+                           layout.chunk, num_leaves=255,
+                           device_bytes=device_bytes)
     assert (picked.subtract, picked.compact) == (subtract, compact)
+    assert picked.batch_k == (8 if subtract else 12), "all of them wide"
 
 
 def test_what_the_user_set_wins_over_the_shape():
@@ -97,9 +124,10 @@ def test_what_the_user_set_wins_over_the_shape():
                              num_leaves=255).compact
 
 
-# what the parent commit of PR 30 (9f626d5) handed the grower for the two
-# cells' shapes, written down from it: RowLayout, Schedule, the schedule
-# fields of GrowerConfig, the compaction buffer's rows
+# what the grower is handed for the two cells' shapes on the v5e:
+# RowLayout, Schedule, the schedule fields of GrowerConfig, the compaction
+# buffer's rows. HIGGS as the parent commit of PR 30 (9f626d5) had it;
+# Epsilon as PR 31 left it (the cache, and 8 smaller children a pass)
 GOLDEN = {
     "higgs-train-1chip": (
         (21_000_000, 28),
@@ -112,17 +140,18 @@ GOLDEN = {
     "epsilon-train-1chip": (
         (1_048_576, 2000),
         (8192, 8192, 1_048_576, 1, 1),
-        (True, False, 12, True, 0.25, (0.25, 181.4, 7.5, 64.5), 12),
-        {"chunk": 8192, "batch_k": 12, "hist_subtract": False,
-         "hist_compact": True, "compact_fraction": 0.25, "table_mult": 12},
+        (True, True, 6, True, 0.25, (0.25, 181.4, 7.5, 64.5), 8),
+        {"chunk": 8192, "batch_k": 8, "hist_subtract": True,
+         "hist_compact": True, "compact_fraction": 0.25, "table_mult": 6},
         262_144),
 }
 
 
 @pytest.mark.parametrize("cell", sorted(GOLDEN))
-def test_the_cells_schedules_are_the_parents_to_the_field(cell):
+def test_the_cells_schedules_are_pinned_to_the_field(cell):
     """No dataset is built: layout, schedule and the grower's static
-    schedule fields for `max_bin` 63, 255 leaves, serial, one class."""
+    schedule fields for `max_bin` 63, 255 leaves, serial, one class, on
+    the v5e's memory."""
     (rows, features), layout_want, picked_want, fields_want, cap_want = \
         GOLDEN[cell]
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
@@ -139,7 +168,7 @@ def test_the_cells_schedules_are_the_parents_to_the_field(cell):
     layout = plan_row_layout(rows, features, 63)
     assert tuple(layout) == layout_want
     picked = pick_schedule(features, 63, rows, layout.n_pad, layout.chunk,
-                           num_leaves=255)
+                           num_leaves=255, device_bytes=V5E_BYTES)
     assert tuple(picked._replace(
         compact_model=tuple(picked.compact_model))) == picked_want
     cfg = GrowerConfig(
@@ -214,15 +243,24 @@ def test_a_removed_switch_switches_nothing(kind, name, value, monkeypatch):
             "the tree is still fetched one iteration late"
 
 
-@pytest.mark.parametrize("models,fits", [(6, True), (7, False)])
-def test_the_sweep_and_the_schedule_ask_one_predicate(models, fits,
-                                                      monkeypatch):
+@pytest.mark.parametrize("models,device_bytes,fits", [
+    (6, 0, True), (7, 0, False), (7, V5E_BYTES, True)])
+def test_the_sweep_and_the_schedule_ask_one_predicate(models, device_bytes,
+                                                      fits, monkeypatch):
     """120 groups x 63 bins x 31 leaves: one cache is 38.5 MB at
-    `table_mult` 12, so six copies fit the 256 MiB budget and seven do
-    not; a single job subtracts either way."""
+    `table_mult` 12, so six copies fit the 256 MiB of a backend that
+    reports no memory and seven do not; a third of the v5e holds 146. A
+    single job subtracts either way."""
+    from lightgbm_tpu.boosting import gbdt as gbdt_mod
     from lightgbm_tpu.boosting import sweep as sweep_mod
     assert sweep_mod.subtract_cache_fits is subtract_cache_fits
-    assert subtract_cache_fits(120, 63, 31, 12, copies=models) == fits
+    seen = {"rows_padded": 512, "device_bytes": device_bytes}
+    assert subtract_cache_fits(120, 63, 31, 12, copies=models, **seen) == fits
+    if device_bytes:
+        assert subtract_cache_fits(120, 63, 31, 12, copies=146, **seen)
+        assert not subtract_cache_fits(120, 63, 31, 12, copies=147, **seen)
+    monkeypatch.setattr(gbdt_mod, "_device_memory_bytes",
+                        lambda device: device_bytes)
     rng = np.random.RandomState(3)
     X = rng.randn(512, 120).astype(np.float32)
     y = (X[:, 0] > 0).astype(np.float32)
@@ -234,6 +272,9 @@ def test_the_sweep_and_the_schedule_ask_one_predicate(models, fits,
     info = trainer.lead._schedule_info
     assert (info["groups"], info["max_bin"]) == (120, 63)
     assert info["subtract"] and info["table_mult"] == 12
+    # the cache's bytes and the memory it was judged against are on record
+    assert info["subtract_cache_bytes"] == 120 * 63 * 12 * (12 * 31 + 52)
+    assert info["device_bytes"] == device_bytes
     assert trainer.cfg.hist_subtract == fits
     # and `pick_schedule` has no arithmetic of its own beside it
     monkeypatch.setattr(schedule, "subtract_cache_fits",
@@ -522,3 +563,102 @@ def test_wide_trees_do_not_depend_on_compaction(wide):
             np.testing.assert_array_equal(a[key], b[key])
         np.testing.assert_allclose(a["leaf_value"], b["leaf_value"],
                                    rtol=1e-4, atol=1e-7)
+
+
+# ---------------------------------------------------------------------------
+# (f) the cell's schedule since PR 31, at a size the CPU's 256 MB holds: at
+# 31 leaves the same table keeps the subtraction cache (238 slots of 0.8
+# MB), so cache, compaction and `wide` are all on, as on the chip at 255
+# ---------------------------------------------------------------------------
+CACHED_LEAVES = 31
+
+
+def _cached_booster(wide, **extra):
+    params = dict(wide.base["config"]["params"], num_leaves=CACHED_LEAVES,
+                  **extra)
+    return lgb.Booster(params, wide.prepared["ds"])
+
+
+def test_wide_cached_trees_do_not_depend_on_compaction(wide):
+    """With the cache a compacted pass gathers the smaller children alone;
+    switched off, the same splits, and leaf values up to float32
+    summation order."""
+    trees = {}
+    for name, extra in (("on", {}), ("off", {"tpu_compact_threshold": 0})):
+        booster = _cached_booster(wide, **extra)
+        for _ in range(3):
+            booster.update()
+        booster.current_iteration()
+        inner = booster._inner
+        info = inner._schedule_info
+        assert info["wide"] and info["subtract"]
+        assert (info["batch_k"], info["chunk"]) == (8, 8192)
+        assert info["subtract_cache_bytes"] <= 256 << 20
+        assert info["device_bytes"] == 0, "the CPU reports no memory"
+        assert info["compact"] == (name == "on")
+        compacted = [rec.compact_passes for rec in inner.pass_log]
+        assert (min(compacted) > 0) if name == "on" else not any(compacted)
+        if name == "on":
+            # a smaller child holds at most half its parent's rows, and
+            # the nodes of a pass share no row
+            assert all(rec.rows_gathered <= rec.compact_passes * WIDE_ROWS / 2
+                       for rec in inner.pass_log)
+        trees[name] = [wide.mode.tree_arrays(t) for t in inner.models]
+    assert len(trees["on"]) == len(trees["off"]) == 3
+    for a, b in zip(trees["on"], trees["off"]):
+        for key in ("split_feature", "threshold", "left_child",
+                    "right_child", "internal_count", "leaf_count"):
+            np.testing.assert_array_equal(a[key], b[key])
+        np.testing.assert_allclose(a["leaf_value"], b["leaf_value"],
+                                   rtol=1e-4, atol=1e-7)
+
+
+def test_a_cached_compacted_pass_contracts_the_smaller_children(wide):
+    """Pass by pass, on the grower itself with every pass forced through
+    the gather (`compact_fraction` 1): with the cache a pass contracts
+    half the rows it relabelled or fewer; without it, all of them."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.learner.grow import FMETA_KEYS, grow_tree
+    inner = _cached_booster(wide)._inner
+    y = jnp.asarray(wide.prepared["y"][:WIDE_ROWS])
+    grad, hess = 0.5 - y, jnp.full(WIDE_ROWS, 0.25, jnp.float32)
+    weight = jnp.ones(WIDE_ROWS, jnp.float32)
+    mask = jnp.ones(inner._num_features_padded, bool)
+    grown = {}
+    for subtract in (True, False):
+        cfg = inner._grower_cfg._replace(hist_subtract=subtract,
+                                         compact_fraction=1.0)
+        grown[subtract] = grow_tree(
+            inner._binned, grad, hess, weight, mask,
+            *[inner._fmeta[k] for k in FMETA_KEYS], cfg)
+    cached, direct = (
+        np.asarray(out.pass_rows)[:int(out.num_passes)]
+        for out in (grown[True], grown[False]))
+    assert int(grown[True].num_leaves_used) > CACHED_LEAVES // 2
+    assert len(cached) == len(direct) > 2
+    assert cached[0] == direct[0] == WIDE_ROWS          # the root's
+    assert (2 * cached[1:] <= direct[1:]).all(), (cached, direct)
+    # the first pass splits the root alone: all its rows relabelled, its
+    # smaller child contracted, to the row
+    out = grown[True]
+    node_count, leaf_count = np.asarray(out.node_count), np.asarray(out.count)
+    root_children = [
+        int(leaf_count[~kid] if kid < 0 else node_count[kid])
+        for kid in (int(out.node_left[0]), int(out.node_right[0]))]
+    assert direct[1] == sum(root_children) == WIDE_ROWS
+    assert cached[1] == min(root_children)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_rows_of_nodes_is_isin(seed):
+    import jax.numpy as jnp
+    from lightgbm_tpu.learner.grow import rows_of_nodes
+    rng = np.random.default_rng(seed)
+    leaf_id = rng.integers(0, 400, size=5000).astype(np.int32)
+    # a pass's ids: some of the fresh children, -1 in the empty slots
+    nodes = rng.choice(np.arange(300, 400), size=24, replace=False)
+    nodes[rng.random(24) < 0.3] = -1
+    got = np.asarray(rows_of_nodes(jnp.asarray(leaf_id),
+                                   jnp.asarray(nodes.astype(np.int32))))
+    np.testing.assert_array_equal(got, np.isin(leaf_id, nodes[nodes >= 0]))
+    assert got.dtype == bool and 0 < got.sum() < got.size
