@@ -338,6 +338,19 @@ class Smoke:
               f"data-parallel trees did not all split: {leaves}")
         serial = self.booster._inner.models[:PARALLEL_ITERATIONS]
         structure_equal = same_structure(inner.models, serial)
+        # node row counts are int32 sums, exact at any size: every tree's
+        # leaves hold all the rows, and a tree that took the serial run's
+        # splits holds the serial run's rows leaf by leaf
+        held = [int(t.leaf_count[:t.num_leaves].sum()) for t in inner.models]
+        check(held == [info["rows"]] * len(held),
+              f"leaf_count does not sum to the {info['rows']} rows: {held}")
+        for i, (a, b) in enumerate(zip(inner.models, serial)):
+            check(not same_structure([a], [b])
+                  or (np.array_equal(a.leaf_count, b.leaf_count)
+                      and np.array_equal(a.internal_count,
+                                         b.internal_count)),
+                  f"tree {i} takes the serial run's splits with other row "
+                  f"counts")
         text_equal = trees_text(booster) == trees_text(
             self.booster, PARALLEL_ITERATIONS)
 
@@ -369,6 +382,7 @@ class Smoke:
             "devices": ndev, "hist_reduce": "scatter",
             "rows_per_device": per_dev[0][1],
             "compiles_per_iteration": per_iter,
+            "leaf_count_sums_to_rows": True,
             "model_text_equal_to_serial": text_equal,
             "tree_structure_equal_to_serial": structure_equal,
             "splits_shared_with_serial_per_tree": shared,

@@ -205,7 +205,8 @@ _SMALL_STATE_KEYS = (
     "num_leaves_used", "leaf_value", "count", "node_feature",
     "node_threshold", "node_default_left", "node_is_cat", "node_left",
     "node_right", "node_gain", "node_value", "node_count", "num_passes",
-    "next_free", "comm_elems", "rows_contracted", "pass_rows")
+    "next_free", "comm_elems", "rows_contracted", "pass_rows",
+    "root_cell_max", "root_cell_at")
 
 
 class _HostState:
@@ -218,16 +219,19 @@ class _HostState:
 
 def _grow_and_update_impl(score, binned, grad, hess, row_weight, fmask,
                           shrinkage, n_valid, fmeta_args, cls, cfg,
-                          qscale=None):
+                          qscale=None, owned_feats=None):
     """grow one tree + train-score update, fused into ONE device program.
 
     The per-tree path (grow -> leaf lookup -> score add) is one dispatch
     that returns only the small tree arrays, so the host pays one
-    dispatch + one device_get per tree instead of an eager op chain."""
+    dispatch + one device_get per tree instead of an eager op chain.
+    Also the body of the data-parallel learners' sharded program
+    (parallel/learners.py): there the rows are one shard's."""
     import jax.numpy as jnp
 
     state = grow_tree(binned, grad, hess, row_weight, fmask, *fmeta_args,
-                      cfg, n_valid=n_valid, qscale=qscale)
+                      cfg, n_valid=n_valid, qscale=qscale,
+                      owned_feats=owned_feats)
     with telemetry.scope("lgbm/score/update"):
         grew = state.num_leaves_used > 1
         leaf_vals = state.leaf_value * shrinkage
@@ -241,9 +245,19 @@ def _grow_and_update_impl(score, binned, grad, hess, row_weight, fmask,
 
 
 def _grow_and_update(score, binned, grad, hess, row_weight, fmask,
-                     shrinkage, n_valid, fmeta_args, cls, cfg, qscale=None):
+                     shrinkage, n_valid, fmeta_args, cls, cfg, qscale=None,
+                     grower=None):
+    """The one device program of a training iteration. `grower` is the
+    data-parallel learner's, which runs the same body sharded over its
+    mesh under its own config (`cfg` is then not read)."""
     import jax
     import jax.numpy as jnp
+    if grower is not None:
+        from ..learner.grow import FMETA_KEYS
+        return grower.grow_and_update(
+            score, shrinkage, cls, binned, grad, hess, row_weight, fmask,
+            dict(zip(FMETA_KEYS, fmeta_args)), n_valid=n_valid,
+            qscale=qscale)
     global _grow_and_update_jit
     if _grow_and_update_jit is None:
         _grow_and_update_jit = jax.jit(
@@ -898,17 +912,37 @@ class GBDT:
                 and train_data.num_groups != train_data.num_features):
             log.fatal("feature-parallel requires unbundled features; "
                       "construct the Dataset with enable_bundle=false")
+        # one process, rows over its devices: the learner's fused grow +
+        # score-update program serves (train_one_iter), and every per-row
+        # array lands ONCE in the sharding that program declares; left
+        # unplaced it sits whole on the first device and is re-split at
+        # every tree
+        self._row_sharded = (self._tree_learner_kind in ("data", "voting")
+                             and ndev > 1 and nproc == 1)
+        self.land_s = 0.0
+        if self._row_sharded:
+            from jax.sharding import NamedSharding, PartitionSpec
+            mesh = self._dist_grower.mesh
+            rows_1d = NamedSharding(mesh, PartitionSpec("data"))
+            self._score = jax.device_put(self._score, NamedSharding(
+                mesh, PartitionSpec(None, "data")))
+            self._base_weight = jax.device_put(self._base_weight, rows_1d)
+            for key in objective_array_keys(objective) if objective else ():
+                arr = getattr(objective, key)
+                if arr.ndim == 1 and arr.shape[0] == n_pad:
+                    setattr(objective, key, jax.device_put(arr, rows_1d))
         if device_binned is not None:
             # already sharded the way the data/voting shard_map wants
             self._binned = device_binned
-        elif (self._tree_learner_kind in ("data", "voting")
-                and ndev > 1 and nproc == 1):
-            # land the rows ONCE in the sharding the grow program
-            # declares (P(data, None)); left unplaced the whole matrix
-            # sits on the first device and is re-split at every tree
-            from jax.sharding import NamedSharding, PartitionSpec
-            self._binned = jax.device_put(binned_host, NamedSharding(
-                self._dist_grower.mesh, PartitionSpec("data", None)))
+        elif self._row_sharded:
+            # the upload itself, waited for: four shards from one process
+            # are this deployment's own share of the set-up
+            t_land = time.perf_counter()
+            with telemetry.span("lgbm/init/land"):
+                self._binned = jax.block_until_ready(jax.device_put(
+                    binned_host, NamedSharding(
+                        mesh, PartitionSpec("data", None))))
+            self.land_s = time.perf_counter() - t_land
         else:
             self._binned = jnp.asarray(binned_host)
         # logical (possibly shard-padded) feature count for feature_fraction
@@ -1203,8 +1237,8 @@ class GBDT:
             return self._train_one_iter_multi(grad, hess, row_weight,
                                               qscales)
 
-        if (self._dist_grower is None and k == 1 and not self.valid_sets
-                and gradients is None
+        if ((self._dist_grower is None or self._row_sharded) and k == 1
+                and not self.valid_sets and gradients is None
                 and getattr(self, "_supports_pipeline", True)):
             return self._train_one_iter_pipelined(grad, hess, row_weight,
                                                   probe, qscales, t_enter)
@@ -1303,8 +1337,9 @@ class GBDT:
 
     def _train_one_iter_pipelined(self, grad, hess, row_weight,
                                   probe, qscales, t_enter) -> bool:
-        """Serial-learner iteration with the tree fetch pipelined one
-        iteration behind the device dispatch (see __init__ note). The
+        """One-class iteration of the serial learner, or of a data-parallel
+        one whose rows live on this process's devices, with the tree fetch
+        pipelined one iteration behind the device dispatch (see __init__). The
         stop/rollback decision therefore lags one iteration: a
         non-splitting tree is detected when it is materialized, its
         iteration is rolled back (its score delta was already zero on
@@ -1329,7 +1364,8 @@ class GBDT:
                 row_weight, jnp.asarray(mask), self.shrinkage_rate,
                 self._n, [self._fmeta[key] for key in FMETA_KEYS], 0,
                 self._grower_cfg,
-                qscale=None if qscales is None else qscales[0])
+                qscale=None if qscales is None else qscales[0],
+                grower=self._dist_grower)
         dispatch_s = time.perf_counter() - t_enter
         # fetch + build the PREVIOUS tree while this one runs on device
         ok_prev = self._flush_pending()
@@ -1414,13 +1450,18 @@ class GBDT:
             else self._dist_grower.cfg
         shards = max(1, cfg.num_data_shards)
         cap = shards * compact_capacity(cfg, self._n_pad // shards)
+        pass_rows = getattr(host_state, "pass_rows", ())
         full, compacted, gathered = telemetry.layers.split_passes(
-            getattr(host_state, "pass_rows", ()), num_passes, cap)
+            pass_rows, num_passes, cap)
+        self._warn_count_cell(host_state)
         rec = telemetry.TreeRecord(
             num_passes=num_passes,
             table_high_water=int(host_state.next_free),
-            rows_contracted=float(getattr(host_state, "rows_contracted",
-                                          0.0)),
+            # the per-pass int32 counts summed here: the device's own
+            # float32 total rounds past 2^24 and an int32 one would pass
+            # 2^31 (84M rows x 28 passes)
+            rows_contracted=float(np.sum(pass_rows[:num_passes],
+                                         dtype=np.int64)),
             comm_elems=comm_elems,
             # element count -> wire bytes: every exchanged histogram
             # element is 4 bytes (f32, or the exact int32 domain under
@@ -1439,6 +1480,28 @@ class GBDT:
         telemetry.counter_add("tree/comm_bytes", rec.comm_bytes)
         telemetry.counter_add("tree/compact_passes", rec.compact_passes)
         telemetry.counter_add("tree/rows_gathered", rec.rows_gathered)
+
+    def _warn_count_cell(self, host_state) -> None:
+        """Say once, naming the feature, that a (group, bin) cell of the
+        root histogram holds 2^24 rows or more: the float32 count channel
+        is exact below that, and the int32 node counts are sums of its
+        cells (ops/split.exact_count)."""
+        top = float(getattr(host_state, "root_cell_max", 0.0))
+        if top < 2.0 ** 24 or getattr(self, "_count_cell_warned", False):
+            return
+        self._count_cell_warned = True
+        group, bin_ = divmod(int(host_state.root_cell_at), self._max_bins)
+        fm = self.train_data.feature_meta_arrays()
+        inside = ((fm["group"] == group) & (fm["offset"] <= bin_)
+                  & (bin_ < fm["offset"] + fm["num_bin"]))
+        names = [self.feature_names[self.train_data.real_feature_index(int(j))]
+                 for j in np.nonzero(inside)[0]] or ["group %d" % group]
+        log.warning(
+            "Feature %s: bin %d holds %.0f rows, 2^24 or more, which the "
+            "float32 count channel of a histogram cannot hold exactly; "
+            "node row counts (min_data_in_leaf, leaf_count, "
+            "internal_count) may be off by a few rows"
+            % (", ".join(names), bin_, top))
 
     def _flush_pending(self) -> bool:
         """Materialize the pipelined tree, if any. Returns False when the

@@ -262,7 +262,8 @@ class GrowParams(NamedTuple):
 class TreeGrowerState(NamedTuple):
     """Public result of one tree growth (what GBDT / Tree export read)."""
     leaf_id: jnp.ndarray          # [N] i32 committed LEAF SLOT per row
-    # per-leaf-slot aggregates [L]
+    # per-leaf-slot aggregates [L]; every row count the grower stores or
+    # hands on (count, node_count, the table's) is int32 and exact
     sum_g: jnp.ndarray
     sum_h: jnp.ndarray
     count: jnp.ndarray
@@ -276,9 +277,8 @@ class TreeGrowerState(NamedTuple):
                                   # cross-shard collectives this tree
     rows_contracted: jnp.ndarray  # scalar f32: rows fed to histogram
                                   # contractions this tree (global under
-                                  # data_axis); the old full-pass
-                                  # economics report ~num_passes * N,
-                                  # the compacted path far less
+                                  # data_axis), rounded past 2^24; the
+                                  # exact figure is pass_rows' sum
     pass_rows: jnp.ndarray        # [4L+64] i32 rows contracted per pass
                                   # (index = pass number; compaction
                                   # observability)
@@ -293,6 +293,11 @@ class TreeGrowerState(NamedTuple):
     node_value: jnp.ndarray
     node_count: jnp.ndarray
     num_leaves_used: jnp.ndarray  # scalar i32
+    # the root histogram's fullest (group, bin) count cell and its flat
+    # index group * B + bin: a cell of 2^24 rows or more is past what the
+    # float32 count channel holds exactly (GBDT warns, naming the feature)
+    root_cell_max: jnp.ndarray    # scalar f32
+    root_cell_at: jnp.ndarray     # scalar i32
 
 
 class _NodeTable(NamedTuple):
@@ -302,7 +307,7 @@ class _NodeTable(NamedTuple):
     depth: jnp.ndarray            # i32
     sum_g: jnp.ndarray            # f32 node aggregates
     sum_h: jnp.ndarray
-    count: jnp.ndarray
+    count: jnp.ndarray            # i32, exact (as left_c)
     gain: jnp.ndarray             # cached best split of the node
     feature: jnp.ndarray
     threshold: jnp.ndarray
@@ -326,7 +331,7 @@ class _NodeTable(NamedTuple):
             depth=jnp.zeros(m, jnp.int32),
             sum_g=jnp.zeros(m, jnp.float32),
             sum_h=jnp.zeros(m, jnp.float32),
-            count=jnp.zeros(m, jnp.float32),
+            count=jnp.zeros(m, jnp.int32),
             gain=jnp.full(m, neg_inf),
             feature=jnp.zeros(m, jnp.int32),
             threshold=jnp.zeros(m, jnp.int32),
@@ -334,7 +339,7 @@ class _NodeTable(NamedTuple):
             is_cat=jnp.zeros(m, bool),
             left_g=jnp.zeros(m, jnp.float32),
             left_h=jnp.zeros(m, jnp.float32),
-            left_c=jnp.zeros(m, jnp.float32),
+            left_c=jnp.zeros(m, jnp.int32),
             created=jnp.zeros(m, bool),
             expanded=jnp.zeros(m, bool),
             frontier=jnp.zeros(m, bool),
@@ -363,9 +368,44 @@ def _extract_feature_hist(group_hist, sum_g, sum_h, count, fmeta, cfg):
         fmeta["is_bundled"][:, None]
     totals = jnp.stack([jnp.broadcast_to(sum_g, at_default.shape[:1]),
                         jnp.broadcast_to(sum_h, at_default.shape[:1]),
-                        jnp.broadcast_to(count, at_default.shape[:1])], -1)
+                        jnp.broadcast_to(count.astype(jnp.float32),
+                                         at_default.shape[:1])], -1)
     rest = totals[:, None, :] - fh.sum(axis=1, keepdims=True)
     return jnp.where(at_default[:, :, None], rest, fh)
+
+
+def _fullest_count_cell(hist, meta, first_group, num_groups):
+    """(rows, flat index group * B + bin) of the fullest count cell of a
+    root histogram [Gl, B, 3] that a stored count can be summed from;
+    `hist` holds groups [first_group, first_group + Gl) of `num_groups`.
+    Left out: groups no splittable feature maps to (the scatter
+    schedule's padding columns, one-bin features: every row sits in their
+    bin 0) and bin 0 of a bundle, which holds the rows at every bundled
+    feature's default and is never read (a bundled feature's default bin
+    is the node's count less the rest)."""
+    gl, b, _ = hist.shape
+    at = lambda flags: jnp.zeros(num_groups, bool).at[meta["group"]].max(
+        flags)
+    own = lambda mask: jax.lax.dynamic_slice_in_dim(mask, first_group, gl)
+    read = own(at(meta["num_bin"] > 1))[:, None] & ~(
+        own(at(meta["is_bundled"]))[:, None]
+        & (jnp.arange(b, dtype=jnp.int32) == 0)[None, :])
+    cells = jnp.where(read, hist[..., 2].astype(jnp.float32), 0.0)
+    flat = jnp.argmax(cells.reshape(-1)).astype(jnp.int32)
+    return cells.reshape(-1)[flat], flat + first_group * b
+
+
+def _chosen_left_count(fh, best, count, res, meta):
+    """The exact int32 left count of the split chosen at position `best`
+    of the per-feature histograms `fh` [F, Bf, 3] (ops/split.py
+    exact_left_count: the float32 running count of the scan stays the
+    min_data_in_leaf gate and is not stored)."""
+    pick = lambda arr: arr[best]
+    return split_ops.exact_left_count(
+        fh[best, :, 2], count, pick(res.threshold), pick(res.default_left),
+        pick(res.is_categorical), pick(meta["num_bin"]),
+        pick(meta["missing_type"]), pick(meta["default_bin"]),
+        pick(meta["is_bundled"]))
 
 
 @scope("lgbm/split/scan")
@@ -399,7 +439,7 @@ def _leaf_best_split(hist, sum_g, sum_h, count, depth, feature_mask, fmeta,
     pick = lambda arr: arr[best_f]
     vals = (pick(gains), best_f, pick(res.threshold), pick(res.default_left),
             pick(res.is_categorical), pick(res.left_sum_g), pick(res.left_sum_h),
-            pick(res.left_count))
+            _chosen_left_count(hist, best_f, count, res, fmeta))
     if cfg.feature_axis is None:
         return vals
     # allreduce-argmax across feature shards: winner shard's payload wins,
@@ -482,10 +522,12 @@ def _scattered_best_split(hist, sum_g, sum_h, count, depth, feature_mask,
         out = jax.lax.psum(z, ax)
         return out > 0 if x.dtype == jnp.bool_ else out
 
+    # the left count travels as int32: an int32 psum of one non-zero term
     return (gmax, bcast(jnp.maximum(feat_global, 0)),
             bcast(pick(res.threshold)), bcast(pick(res.default_left)),
             bcast(pick(res.is_categorical)), bcast(pick(res.left_sum_g)),
-            bcast(pick(res.left_sum_h)), bcast(pick(res.left_count)))
+            bcast(pick(res.left_sum_h)),
+            bcast(_chosen_left_count(fh, best, count, res, sub)))
 
 
 @scope("lgbm/split/scan")
@@ -532,7 +574,7 @@ def _voting_children_best(hists_local, sum_g, sum_h, count, depth,
     # gains weighted by the local/mean data share (GlobalVoting weighting,
     # cpp:171-180)
     kth = jax.lax.top_k(gains_local, min(cfg.top_k, gains_local.shape[1]))[0][:, -1]
-    mean_cnt = jnp.maximum(count / m, 1.0)                   # [C] global/m
+    mean_cnt = jnp.maximum(count.astype(jnp.float32) / m, 1.0)  # [C] global/m
     weight = ltot[:, 2] / mean_cnt
     submitted = jnp.where(gains_local >= kth[:, None],
                           gains_local * weight[:, None], -jnp.inf)
@@ -560,7 +602,7 @@ def _voting_children_best(hists_local, sum_g, sum_h, count, depth,
     efh = jnp.where(valid[:, :, :, None], efh, 0.0)
     at_default = (bins == fmeta["default_bin"][elected][:, :, None]) & \
         fmeta["is_bundled"][elected][:, :, None]
-    totals = jnp.stack([sum_g, sum_h, count], -1)             # [C, 3]
+    totals = jnp.stack([sum_g, sum_h, count.astype(jnp.float32)], -1)  # [C, 3]
     rest = totals[:, None, None, :] - efh.sum(axis=2, keepdims=True)
     efh = jnp.where(at_default[:, :, :, None], rest, efh)
 
@@ -579,10 +621,12 @@ def _voting_children_best(hists_local, sum_g, sum_h, count, depth,
         gains = jnp.minimum(gains, _GAIN_CLAMP)
         best = jnp.argmax(gains).astype(jnp.int32)
         pick = lambda a: a[best]
+        emeta = {k: fmeta[k][eidx] for k in
+                 ("num_bin", "missing_type", "default_bin", "is_bundled")}
         return (pick(gains), eidx[best], pick(res.threshold),
                 pick(res.default_left), pick(res.is_categorical),
                 pick(res.left_sum_g), pick(res.left_sum_h),
-                pick(res.left_count))
+                _chosen_left_count(fh_c, best, cnt, res, emeta))
 
     vals = jax.vmap(global_scan)(efh, elected, sum_g, sum_h, count, depth)
     return vals, comm
@@ -837,19 +881,39 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         # slices are bitwise equal to the full psum) and broadcasts, so the
         # bin-sum ORDER matches the allreduce path exactly and totals stay
         # bit-identical between the two schedules.
+        # The root's COUNT is the int32 sum of those count cells: a
+        # float32 sum over bins goes wrong past 2^24 rows.
         if voting:
             root_tot = jax.lax.psum(local_root[0].sum(axis=0), cfg.data_axis)
+            root_c = jax.lax.psum(
+                split_ops.exact_count(local_root[0, :, 2]), cfg.data_axis)
         elif scatter:
             owner0 = jax.lax.axis_index(cfg.data_axis) == 0
             rt = root_hist[0].sum(axis=0)
             root_tot = jax.lax.psum(
                 jnp.where(owner0, rt, jnp.zeros_like(rt)), cfg.data_axis)
+            root_c = jax.lax.psum(
+                jnp.where(owner0, split_ops.exact_count(root_hist[0, :, 2]),
+                          0), cfg.data_axis)
         else:
             root_tot = root_hist[0].sum(axis=0)
+            root_c = split_ops.exact_count(root_hist[0, :, 2])
         # quantized modes: totals leave the exact integer domain HERE; every
         # table aggregate / gain / leaf value downstream is real-unit f32
         root_tot = dequant(root_tot)
-        root_g, root_h, root_c = root_tot[0], root_tot[1], root_tot[2]
+        root_g, root_h = root_tot[0], root_tot[1]
+        # the fullest count cell of the (merged) root histogram, for the
+        # host's warning; a scatter shard sees its owned groups and voting
+        # its local rows, so those take the largest over shards
+        root_cell_max, root_cell_at = _fullest_count_cell(
+            root_hist, fmeta if scatter else local_fmeta,
+            gs if scatter else 0, g_cols if scatter else fl)
+        if scatter or voting:
+            top = jax.lax.pmax(root_cell_max, cfg.data_axis)
+            root_cell_at = jax.lax.pmin(
+                jnp.where(root_cell_max == top, root_cell_at,
+                          jnp.int32(1 << 30)), cfg.data_axis)
+            root_cell_max = top
     root_comm = jnp.float32(0.0)
     if cfg.data_axis is not None:
         # per-device elements moved: voting ships 3 totals, scatter keeps
@@ -924,7 +988,7 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             hist_cache=hist_cache,
             sum_g=jnp.zeros(L, jnp.float32).at[0].set(root_g),
             sum_h=jnp.zeros(L, jnp.float32).at[0].set(root_h),
-            count=jnp.zeros(L, jnp.float32).at[0].set(root_c),
+            count=jnp.zeros(L, jnp.int32).at[0].set(root_c),
             leaf_value=jnp.zeros(L, jnp.float32).at[0].set(
                 leaf_output(root_g, root_h, gp.lambda_l1, gp.lambda_l2)),
             leaf_depth=jnp.zeros(L, jnp.int32),
@@ -937,7 +1001,7 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             node_right=jnp.zeros(L - 1, jnp.int32),
             node_gain=jnp.zeros(L - 1, jnp.float32),
             node_value=jnp.zeros(L - 1, jnp.float32),
-            node_count=jnp.zeros(L - 1, jnp.float32),
+            node_count=jnp.zeros(L - 1, jnp.int32),
             num_leaves_used=jnp.int32(1),
         )
 
@@ -1029,7 +1093,7 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             # from parent - smaller below, feature_histogram.hpp:64-70)
             sel_c = jnp.clip(sel, 0, M - 1)
             if subtract:
-                small_left = t.left_c[sel_c] * 2.0 <= t.count[sel_c]  # [K]
+                small_left = t.left_c[sel_c] * 2 <= t.count[sel_c]    # [K]
                 hist_ids = jnp.where(valid,
                                      jnp.where(small_left, cl, cr), -1)
             else:
@@ -1380,6 +1444,7 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         node_gain=carry.node_gain, node_value=carry.node_value,
         node_count=carry.node_count,
         num_leaves_used=carry.num_leaves_used,
+        root_cell_max=root_cell_max, root_cell_at=root_cell_at,
     )
 
 

@@ -42,9 +42,9 @@ class SplitResult(NamedTuple):
     is_categorical: jnp.ndarray  # bool (threshold is the left-alone bin)
     left_sum_g: jnp.ndarray
     left_sum_h: jnp.ndarray
-    left_count: jnp.ndarray
-    right_sum_g: jnp.ndarray
-    right_sum_h: jnp.ndarray
+    left_count: jnp.ndarray    # f32 running count: the min_data_in_leaf
+    right_sum_g: jnp.ndarray   # gate's view, exact under 2^24 rows only;
+    right_sum_h: jnp.ndarray   # what is STORED is exact_left_count's
     right_count: jnp.ndarray
 
 
@@ -97,7 +97,8 @@ def find_best_splits(hist: jnp.ndarray,
 
     Args:
       hist: [F, B, 3] (sum_grad, sum_hess, count) per (feature, bin).
-      parent_sum_g/h/count: scalars for the leaf being split.
+      parent_sum_g/h/count: scalars for the leaf being split (the count
+        may be the grower's exact int32; the scan gates in float32).
       num_bin / missing_type / default_bin / is_categorical: [F] static
         per-feature metadata (Dataset.feature_meta_arrays).
     """
@@ -108,6 +109,7 @@ def find_best_splits(hist: jnp.ndarray,
     bins = jnp.arange(b, dtype=jnp.int32)[None, :]          # [1,B]
     nb = num_bin[:, None]                                    # [F,1]
     parent_sum_h = parent_sum_h + 2 * K_EPSILON
+    parent_count = jnp.asarray(parent_count).astype(jnp.float32)
 
     parent_gain = leaf_split_gain(parent_sum_g, parent_sum_h, lambda_l1, lambda_l2)
     min_gain_shift = parent_gain + min_gain_to_split
@@ -233,3 +235,40 @@ def find_best_splits(hist: jnp.ndarray,
         right_sum_h=parent_sum_h - lh_best - K_EPSILON,
         right_count=parent_count - lc_best,
     )
+
+
+def exact_count(cells) -> jnp.ndarray:
+    """int32 sum of float32 count cells. A (group, bin) cell is an exact
+    integer while it stays under 2^24; what float32 loses past 2^24 rows
+    under one node is the SUM over cells, so every stored row count is
+    summed here, in int32."""
+    return jnp.round(cells).astype(jnp.int32).sum(axis=-1)
+
+
+def exact_left_count(count_bins, parent_count, threshold, default_left,
+                     is_categorical, num_bin, missing_type, default_bin,
+                     is_bundled=False) -> jnp.ndarray:
+    """Rows left of ONE chosen split, exact in int32: the int32 sum of
+    that feature's own count bins [B] on the left of `threshold`, by
+    find_best_splits' rules (a dual scan's default or NaN bin follows
+    `default_left`; a categorical split sends one bin left). At most B
+    terms a selected node and nothing per feature, where a second
+    cumulative sum beside the scan's float32 one would cost the whole
+    [F, B] pass again. `parent_count` is the node's int32 count: a
+    bundled feature's default bin holds no rows of its own and is the
+    parent less the rest (`_extract_feature_hist`)."""
+    b = count_bins.shape[-1]
+    bins = jnp.arange(b, dtype=jnp.int32)
+    c = jnp.round(count_bins).astype(jnp.int32)
+    at_default = bins == default_bin
+    c = jnp.where(is_bundled & at_default,
+                  parent_count - jnp.where(at_default, 0, c).sum(), c)
+    dual = (num_bin > 2) & (missing_type != MISSING_NONE)
+    at_moving = jnp.where(
+        dual & (missing_type == MISSING_NAN), bins == num_bin - 1,
+        dual & (missing_type == MISSING_ZERO) & at_default)
+    moving = jnp.where(at_moving, c, 0).sum()
+    scanned = jnp.where((bins <= threshold) & ~at_moving, c, 0).sum()
+    numerical = scanned + jnp.where(default_left, moving, 0)
+    return jnp.where(is_categorical,
+                     jnp.where(bins == threshold, c, 0).sum(), numerical)

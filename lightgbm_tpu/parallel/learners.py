@@ -46,7 +46,8 @@ from ..testing import faults
 from . import watchdog
 
 
-def _sharded_grower(mesh, cfg, row_axis, state_spec, scatter, quantized):
+def _sharded_grower(mesh, cfg, row_axis, state_spec, scatter, quantized,
+                    update_cls=None):
     """`jax.jit(jax.shard_map(grow_tree))` for one operand layout. After
     the six leading operands come, in order: the scatter schedule's
     replicated owned-feature table (each shard dynamic-indexes its own
@@ -54,21 +55,44 @@ def _sharded_grower(mesh, cfg, row_axis, state_spec, scatter, quantized):
     fmeta arrays; the f32 dispatch thus keeps its own signature and
     program. Callers build this ONCE per (cfg, layout) and keep it: an
     eager shard_map rebuilt from a fresh closure re-traces and
-    re-compiles the whole grow program on every dispatch."""
-    def body(b, g, h, w, fm, nv, *rest):
+    re-compiles the whole grow program on every dispatch.
+
+    With `update_cls` the program is the serial learner's fused one
+    (`gbdt._grow_and_update_impl`: grow, then class `update_cls` of the
+    training score updated from each shard's own leaf ids): two more
+    leading operands, the [k, N] score with its rows sharded and the
+    shrinkage; it returns the score and the small tree state, and
+    `state_spec` is not read."""
+    def split(rest):
         rest = list(rest)
         of = rest.pop(0) if scatter else None
         qs = rest.pop(0) if quantized else None
+        return rest, of, qs
+
+    def body(b, g, h, w, fm, nv, *rest):
+        rest, of, qs = split(rest)
         return grow_tree(b, g, h, w, fm, *rest, cfg, n_valid=nv,
                          owned_feats=of, qscale=qs)
 
+    def fused(score, shrink, b, g, h, w, fm, nv, *rest):
+        from ..boosting.gbdt import _grow_and_update_impl
+        rest, of, qs = split(rest)
+        return _grow_and_update_impl(score, b, g, h, w, fm, shrink, nv,
+                                     rest, update_cls, cfg, qscale=qs,
+                                     owned_feats=of)
+
     extra = ((P(None, None),) if scatter else ()) \
         + ((P(None),) if quantized else ())
+    in_specs = (P(row_axis, None), P(row_axis), P(row_axis), P(row_axis),
+                P(None), P()) + extra + (P(None),) * 7
+    if update_cls is None:
+        return jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=in_specs, out_specs=state_spec,
+            check_vma=False))
+    # P() as the small state's spec is a prefix of its dict: replicated
     return jax.jit(jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(row_axis, None), P(row_axis), P(row_axis), P(row_axis),
-                  P(None), P()) + extra + (P(None),) * 7,
-        out_specs=state_spec, check_vma=False))
+        fused, mesh=mesh, in_specs=(P(None, row_axis), P()) + in_specs,
+        out_specs=(P(None, row_axis), P()), check_vma=False))
 
 
 def make_mesh(num_devices: Optional[int] = None, axis_name: str = "data",
@@ -110,7 +134,8 @@ class DataParallelGrower:
         self._global_binned = None
         self._global_binned_id = None
         self._calls = 0
-        # (cfg, scatter, quantized) -> jitted shard_map (_sharded_grower)
+        # (cfg, scatter, quantized, score class of the fused program or
+        # None) -> jitted shard_map (_sharded_grower)
         self._programs: Dict = {}
         # scatter prep cache: (id(binned) -> padded binned), owned table
         self._scatter_binned = None
@@ -175,6 +200,20 @@ class DataParallelGrower:
             self._scatter_binned_id = id(binned)
         return self._scatter_binned, self._owned_feats
 
+    def grow_and_update(self, score, shrinkage, cls, binned, grad, hess,
+                        row_weight, feature_mask, fmeta: Dict, n_valid=None,
+                        qscale=None):
+        """One tree and the training score's update as ONE sharded
+        program (single-process runs: the score is one array whose rows
+        live where their leaf ids do). Returns what the serial
+        `gbdt._grow_and_update` returns, the score and the small state."""
+        self._calls += 1
+        with watchdog.deadline("collective.dispatch",
+                               iteration=self._calls):
+            return self._dispatch(binned, grad, hess, row_weight,
+                                  feature_mask, fmeta, n_valid, qscale,
+                                  update=(score, shrinkage, cls))
+
     def __call__(self, binned, grad, hess, row_weight, feature_mask,
                  fmeta: Dict, n_valid=None, qscale=None):
         # the per-pass dispatch is a host-level collective seam: under
@@ -191,7 +230,7 @@ class DataParallelGrower:
                                   feature_mask, fmeta, n_valid, qscale)
 
     def _dispatch(self, binned, grad, hess, row_weight, feature_mask,
-                  fmeta: Dict, n_valid=None, qscale=None):
+                  fmeta: Dict, n_valid=None, qscale=None, update=None):
         # injection point: a severed/restarting worker surfaces here as
         # a failed collective dispatch; a WEDGED worker surfaces as an
         # injected sleep the deadline guard above must catch
@@ -243,16 +282,20 @@ class DataParallelGrower:
         if n_valid is None:
             n_valid = binned.shape[0]
         scatter, quantized = owned_feats is not None, qscale is not None
-        key = (self.cfg, scatter, quantized)
+        cls = None if update is None else update[2]
+        key = (self.cfg, scatter, quantized, cls)
         run = self._programs.get(key)
         if run is None:
             # out_specs: leaf_id stays sharded by rows; everything else
             # is replicated (identical on all shards by construction)
             run = self._programs[key] = _sharded_grower(
                 self.mesh, self.cfg, self.axis, self._state_specs(),
-                scatter, quantized)
+                scatter, quantized, update_cls=cls)
         extra = [a for a in (owned_feats, qscale) if a is not None]
-        return run(binned, grad, hess, row_weight, feature_mask,
+        lead = () if update is None else (
+            jax.device_put(update[0], NamedSharding(self.mesh, P(None, ax))),
+            jnp.float32(update[1]))
+        return run(*lead, binned, grad, hess, row_weight, feature_mask,
                    jnp.int32(n_valid), *extra,
                    *[fmeta[k] for k in FMETA_KEYS])
 

@@ -32,8 +32,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-from .layers import (DATASET_SPANS, ITER_SPANS, SCOPES, ConstructRecord,
-                     TreeRecord, scope)
+from .layers import (DATASET_SPANS, INIT_SPANS, ITER_SPANS, SCOPES,
+                     ConstructRecord, TreeRecord, scope)
 from .metrics import (DEFAULT_TIME_BUCKETS, Counter, Gauge, Histogram,
                       Registry, block, counter_add, current_site, enable,
                       enabled, gauge_set, heartbeat, observe, registry,
@@ -45,7 +45,7 @@ from .runlog import (SCHEMA_VERSION, RunLog, TrainRecorder, read_records,
 __all__ = [
     "DEFAULT_TIME_BUCKETS", "Counter", "Gauge", "Histogram", "Registry",
     "RunLog", "TrainRecorder", "CompileObserver", "SCHEMA_VERSION",
-    "DATASET_SPANS", "ITER_SPANS", "SCOPES", "ConstructRecord",
+    "DATASET_SPANS", "INIT_SPANS", "ITER_SPANS", "SCOPES", "ConstructRecord",
     "TreeRecord", "scope", "last_construct", "record_construct",
     "active_recorder", "block", "counter_add", "current_site", "enable",
     "enabled", "gauge_set", "heartbeat", "observe", "observer",

@@ -42,15 +42,29 @@ from .layers import PREFIX, SCOPES, UNSCOPED
 OP_LINE = "xla ops"
 SCOPE_STAT = "tf_op"
 NO_SPAN = "(no lgbm span)"
+# XLA:TPU emits the histogram merge as a fusion of its own making
+# (`calls=%all-reduce-scatter...`, emitter SingleInputAllReduceScatter
+# Fusion) and gives it no `op_name`: the one collective of a grow program
+# that large, every other (the best split's pmax and psums) keeps its
+# path. An operation with no scope whose text names one of these is the
+# merge's (compiled for a described v5e:2x2 and seen `unscoped` in the
+# four-chip trace of PR 32, PERF.md section 5)
+MERGE_SCOPE = "lgbm/hist/merge"
+COLLECTIVES = ("all-reduce-scatter", "reduce-scatter", "all-reduce",
+               "all-gather", "all-to-all")
 
 
-def scope_of(path: str) -> str:
-    """The innermost `SCOPES` name in an `op_name` path, else `unscoped`."""
+def scope_of(path: str, op: str = "") -> str:
+    """The innermost `SCOPES` name in an `op_name` path; without one, the
+    merge's scope for an operation `op` whose text names a collective
+    (`COLLECTIVES`), else `unscoped`."""
     best, at = UNSCOPED, -1
     for name in SCOPES:
         i = path.rfind(name)
         if i > at:
             best, at = name, i
+    if at < 0 and any(word in op for word in COLLECTIVES):
+        return MERGE_SCOPE
     return best
 
 
@@ -105,20 +119,27 @@ def reduce_events(device, host, top=10):
     """device: {plane: [(name, start, end, op_name path), ...]} from the
     operation line of each device plane; host: {thread: [(span name,
     start, end), ...]}, the program's own spans. Times in ns; seconds
-    out, device numbers the mean over planes."""
+    out, device numbers the mean over planes; `planes` keeps each
+    plane's own busy seconds and scopes (the shards of a data-parallel
+    job do unequal work)."""
     planes = max(len(device), 1)
     scopes, ops, op_scope = (collections.Counter(), collections.Counter(), {})
-    every, busy_ns = [], 0
-    for events in device.values():
-        keyed = [((name, scope_of(path)), s, e)
+    every, busy_ns, by_plane = [], 0, {}
+    for plane, events in device.items():
+        keyed = [((name, scope_of(path, name)), s, e)
                  for name, s, e, path in events]
+        own = collections.Counter()
         for (name, scope), ns in self_times(keyed).items():
-            scopes[scope] += ns
+            own[scope] += ns
             ops[name] += ns
             op_scope[name] = scope
+        scopes.update(own)
         merged = union_intervals((s, e) for _, s, e, _ in events)
         busy_ns += sum(e - s for s, e in merged)
         every.append(merged)
+        by_plane[plane] = {
+            "busy_s": sum(e - s for s, e in merged) / 1e9,
+            "scopes": {k: ns / 1e9 for k, ns in own.most_common()}}
     if not any(every):
         raise ValueError("the device planes hold no operation")
     lo = min(iv[0][0] for iv in every if iv)
@@ -131,6 +152,7 @@ def reduce_events(device, host, top=10):
     return {
         "busy_s": busy_ns / 1e9 / planes,
         "window_s": (hi - lo) / 1e9,
+        "planes": by_plane,
         "scopes": {k: ns / 1e9 / planes for k, ns in scopes.most_common()},
         "ops": [[name[:120], ns / 1e9 / planes, op_scope[name]]
                 for name, ns in ops.most_common(top)],
@@ -278,6 +300,20 @@ def reduce_xplane(path: str, top: int = 10):
     return out
 
 
+def host_spans(path: str) -> dict:
+    """Seconds by name of the program's own host spans in one xplane file
+    (summed over threads; a trace of set-up holds no device plane worth
+    reducing, and `reduce_xplane` would refuse it)."""
+    out = collections.Counter()
+    for plane in read_xspace(path):
+        if plane["name"].startswith("/host:"):
+            for line in plane["lines"]:
+                for name, s, e, _ in line["events"]:
+                    if name.startswith(PREFIX):
+                        out[name] += (e - s) / 1e9
+    return dict(out)
+
+
 def layer_table(reduced) -> str:
     """The reduction as the markdown table PERF.md section 5 holds."""
     busy = reduced["busy_s"] or 1.0
@@ -285,6 +321,20 @@ def layer_table(reduced) -> str:
             "| --- | --- | --- |"]
     rows += [f"| `{k}` | {v:.4f} | {100 * v / busy:.1f}% |"
              for k, v in reduced["scopes"].items()]
+    planes = reduced.get("planes", {})
+    if len(planes) > 1:
+        names = sorted(planes)
+        window = reduced["window_s"] or 1.0
+        rows += ["", "| Scope, self s by device plane | "
+                 + " | ".join(n.rsplit(":", 1)[-1] for n in names) + " |",
+                 "| --- |" + " --- |" * len(names)]
+        rows += [f"| `{k}` | " + " | ".join(
+            f"{planes[n]['scopes'].get(k, 0.0):.4f}" for n in names) + " |"
+            for k in reduced["scopes"]]
+        rows += ["| busy s (idle share of the window) | " + " | ".join(
+            f"{planes[n]['busy_s']:.4f} "
+            f"({100 * (1 - planes[n]['busy_s'] / window):.2f}%)"
+            for n in names) + " |"]
     rows += ["", "| Device operation | self s | scope |", "| --- | --- | --- |"]
     rows += [f"| `{n[:60]}` | {v:.4f} | `{sc}` |"
              for n, v, sc in reduced["ops"]]

@@ -1,6 +1,6 @@
 """The names this program gives its layers, in one place.
 
-Three vocabularies, each read by `telemetry/devtrace.py` and by the
+Four vocabularies, each read by `telemetry/devtrace.py` and by the
 benchmark's per-layer readers:
 
 - `SCOPES` — `jax.named_scope` names inside the jitted programs
@@ -19,6 +19,7 @@ benchmark's per-layer readers:
 - `DATASET_SPANS` — host spans of one `Dataset` construction
   (`ingest/build.build_inner`), by phase. Their seconds are also kept,
   always, as the `ConstructRecord` on the dataset they built.
+- `INIT_SPANS` — host spans of `GBDT.init`.
 - `TreeRecord` — the per-tree entry of `GBDT.pass_log`.
 """
 from __future__ import annotations
@@ -51,6 +52,12 @@ ITER_SPANS = (
     "lgbm/iter/dispatch",     # the enqueue of the grow(+update) program
     "lgbm/iter/fetch",        # device_get of a tree's small state: the wait
     "lgbm/iter/build_tree",   # Tree.from_grower_state, shrinkage, bookkeeping
+)
+
+INIT_SPANS = (
+    "lgbm/init/land",         # the binned matrix uploaded as one row shard
+                              # a device (tree_learner=data/voting, one
+                              # process), waited for; GBDT.land_s
 )
 
 DATASET_SPANS = (

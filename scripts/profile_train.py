@@ -5,6 +5,10 @@ reduction; this file only drives the run).
 
     python scripts/profile_train.py --config benchmarks/configs/higgs-10m5x28.json
 
+A configuration with `tree_learner=data` (`higgs-10m5x28-dp4.json`) runs
+over every attached chip; the table then also gives each scope's self
+seconds and the busy seconds by device plane.
+
 `--config` is a benchmark configuration file (`rows`, `features`,
 `params`, `generator`); `--rows` overrides its row count, for a
 rehearsal. The layer table goes to standard output and the whole
@@ -18,6 +22,7 @@ the scope on a new libtpu (`devtrace.SCOPE_STATS`).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import shutil
@@ -60,6 +65,8 @@ def main(argv=None) -> int:
                     metavar="KEY=VALUE", help="override one parameter of "
                     "the configuration (an experiment, not the cell)")
     ap.add_argument("--name", default="profile_train")
+    ap.add_argument("--trace-init", action="store_true",
+                    help="also trace Booster init, for its host spans")
     ap.add_argument("--keep-trace", action="store_true",
                     help="copy the .xplane.pb beside the JSON")
     args = ap.parse_args(argv)
@@ -90,13 +97,31 @@ def main(argv=None) -> int:
     t = time.perf_counter()
     ds = lgb.Dataset(X, y, params=dict(config["params"])).construct()
     construct_host_s = time.perf_counter() - t
+    # with `--trace-init` traced too, in a trace of its own: `GBDT.init`'s
+    # host spans (`lgbm/init/land`) are set-up and not in the iterations'
+    # trace. Off by default: the profiler's Python tracer stretched this
+    # step from ~10 s to 187 s at 84M rows (TPU v5e host, PR 32)
+    init_dir = tempfile.mkdtemp(prefix="profile_init_") \
+        if args.trace_init else None
+    init_spans = {}
     t = time.perf_counter()
-    booster = lgb.Booster(dict(config["params"]), ds)
-    inner = booster._inner
-    jax.block_until_ready(inner._binned)
+    try:
+        with (jax.profiler.trace(init_dir) if init_dir
+              else contextlib.nullcontext()):
+            booster = lgb.Booster(dict(config["params"]), ds)
+            inner = booster._inner
+            jax.block_until_ready(inner._binned)
+        if init_dir:
+            init_spans = devtrace.host_spans(devtrace.newest_xplane(init_dir))
+    finally:
+        if init_dir:
+            shutil.rmtree(init_dir, ignore_errors=True)
+    # `land_s`: of those seconds, the upload of the binned matrix as one
+    # row shard a device (0 where the rows are on one device)
     setup = dict(ds._lazy_init().construct_record._asdict(),
                  construct_host_s=construct_host_s,
-                 booster_to_device_s=time.perf_counter() - t)
+                 booster_to_device_s=time.perf_counter() - t,
+                 land_s=getattr(inner, "land_s", 0.0), **init_spans)
     print("set-up, host seconds: " + ", ".join(
         f"{k} {v:.2f}" if k != "values" else f"{v} values"
         for k, v in setup.items()), flush=True)

@@ -210,9 +210,12 @@ def test_predictor_warmup_then_no_restack_or_retrace(base):
     assert warm["buckets"] == [16, 32, 64]
     pred.predict_one(X[0])             # settle
     compiles = []
-    jax.monitoring.register_event_listener(
-        lambda name, **kw: compiles.append(name) if "compil" in name
-        else None)
+
+    def listener(name, **kw):
+        if "compil" in name:
+            compiles.append(name)
+
+    jax.monitoring.register_event_listener(listener)
     try:
         restacks0 = pred.stats()["stack_restacks"]
         for i in range(10):
@@ -224,7 +227,10 @@ def test_predictor_warmup_then_no_restack_or_retrace(base):
         assert stats["requests"] >= 20
         assert stats["p50_latency_ms"] is not None
     finally:
-        jax.monitoring.clear_event_listeners()
+        # this listener alone: clearing them all would also silence the
+        # compile observer (telemetry/observer.py) for every later test
+        # that shares this worker
+        jax.monitoring.unregister_event_listener(listener)
 
 
 def test_predictor_values_match_booster(base):

@@ -163,6 +163,49 @@ def test_scope_of_takes_the_innermost_scope():
     assert devtrace.scope_of("") == layers.UNSCOPED
 
 
+MERGE_OP = ("%fusion.395 = f32[64,8,63]{2,1,0:T(8,128)S(1)} fusion(f32[24,28"
+            ",63,3]{2,0,3,1:T(8,128)S(1)} %copy.222), kind=kCustom, "
+            "calls=%all-reduce-scatter.clone.clone")
+
+
+@pytest.mark.parametrize("path,op,want", [
+    # the merge as XLA:TPU emits it on four chips: no op_name at all
+    ("", MERGE_OP, "lgbm/hist/merge"),
+    ("", "%all-reduce.28 = f32[256,8,63] all-reduce(%pad.341)",
+     "lgbm/hist/merge"),
+    # a collective that kept its path stays where the path puts it
+    ("jit(f)/while/body/vmap(lgbm/split/scan)/pmax", "%pmax.36 = f32[24] "
+     "all-reduce(%fusion.414)", "lgbm/split/scan"),
+    ("", "%copy.3 = f32[8] copy(%p)", layers.UNSCOPED),
+    ("jit(f)/while", MERGE_OP, "lgbm/hist/merge"),
+])
+def test_an_unscoped_collective_is_the_merge(path, op, want):
+    assert devtrace.scope_of(path, op) == want
+
+
+def test_devtrace_keeps_each_planes_own_seconds():
+    """The shards of a data-parallel job do unequal work: the mean over
+    planes beside each plane's busy seconds and scopes, and the table
+    `scripts/profile_train.py` prints."""
+    slow = [("fusion.8", 0, 1000, "jit(f)/lgbm/hist/contract/dot"),
+            (MERGE_OP, 1000, 1100, "")]
+    fast = [("fusion.8", 0, 300, "jit(f)/lgbm/hist/contract/dot"),
+            (MERGE_OP, 300, 1100, "")]
+    out = devtrace.reduce_events(
+        {"/device:TPU:0": slow, "/device:TPU:3": fast}, HOST)
+    assert out["scopes"]["lgbm/hist/merge"] == pytest.approx(450e-9)
+    planes = out["planes"]
+    assert planes["/device:TPU:3"]["scopes"]["lgbm/hist/merge"] \
+        == pytest.approx(800e-9)
+    assert planes["/device:TPU:0"]["scopes"]["lgbm/hist/contract"] \
+        == pytest.approx(1000e-9)
+    assert planes["/device:TPU:0"]["busy_s"] == pytest.approx(1100e-9)
+    table = devtrace.layer_table(out)
+    assert "| Scope, self s by device plane | 0 | 3 |" in table
+    one = devtrace.layer_table(devtrace.reduce_events(DEVICE, HOST))
+    assert "by device plane" not in one
+
+
 def test_devtrace_while_encloses_its_body():
     out = devtrace.reduce_events(DEVICE, HOST)
     ops = {name: (s, scope) for name, s, scope in out["ops"]}
