@@ -327,6 +327,11 @@ class Smoke:
         info = inner._schedule_info
         check(info["hist_reduce"] == "scatter" and info["num_shards"] == ndev,
               f"not the default scatter merge over {ndev} devices: {info}")
+        # the serial run's schedule for one shard's shape: the cache, at
+        # the owned slice's width on more than one device
+        check(info["subtract"],
+              f"sibling subtraction not selected under the data axis: "
+              f"{info}")
         shards = inner._binned.addressable_shards
         per_dev = sorted((str(s.device), int(s.data.shape[0]))
                          for s in shards)
@@ -380,6 +385,7 @@ class Smoke:
               f"{PARALLEL_ITERATIONS} trees")
         self.summary["data_parallel"] = {
             "devices": ndev, "hist_reduce": "scatter",
+            "subtract": info["subtract"], "batch_k": info["batch_k"],
             "rows_per_device": per_dev[0][1],
             "compiles_per_iteration": per_iter,
             "leaf_count_sums_to_rows": True,
@@ -391,6 +397,7 @@ class Smoke:
             "serial_holdout_auc": round(auc_serial, 6),
             "logloss": got, "serial_logloss": want}
         return (f"devices={ndev} rows_per_device={per_dev[0][1]} "
+                f"subtract={info['subtract']} batch_k={info['batch_k']} "
                 f"compiles_per_iteration={per_iter} "
                 f"text_equal={text_equal} structure_equal={structure_equal} "
                 f"splits_shared={shared} of {[n - 1 for n in leaves]} "

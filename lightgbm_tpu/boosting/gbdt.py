@@ -813,6 +813,10 @@ class GBDT:
         # the memory the subtraction cache is judged against (a device
         # of THIS process: `devs` is global under multi-host)
         self._device_bytes = _device_memory_bytes(jax.local_devices()[0])
+        # the groups one device keeps of a merged histogram, which is the
+        # width of its subtraction cache: its owned slice under the
+        # scatter merge (grow.py), every group otherwise
+        owned_groups = g_pad // ndev if use_scatter else g_cnt
         picked = pick_schedule(
             g_cnt, self._max_bins, n // shards, n_pad // shards,
             layout.chunk, num_leaves=self.config.tree.num_leaves,
@@ -824,7 +828,7 @@ class GBDT:
                 float(self.config.tree.tpu_compact_threshold)
                 if "tpu_compact_threshold" in self.config.raw_params
                 else None),
-            device_bytes=self._device_bytes)
+            device_bytes=self._device_bytes, cache_groups=owned_groups)
         costs = picked.compact_model
         log.info("Schedule: groups=%d max_bin=%d wide=%s subtract=%s "
                  "compact=%s@%.3f (ns a row: full=%.1f index=%.1f "
@@ -959,7 +963,7 @@ class GBDT:
             tree_learner=self._tree_learner_kind, num_processes=nproc,
             hist_reduce=((hist_reduce if use_scatter else "allreduce")
                          if self._tree_learner_kind == "data" else None),
-            owned_groups=g_pad // ndev if use_scatter else g_cnt,
+            owned_groups=owned_groups,
             device_bytes=self._device_bytes)
 
         # boost from average (gbdt.cpp:358-378): the score bump happens at

@@ -57,7 +57,14 @@ feature_histogram.hpp:380-548); each expansion contracts only the
 SMALLER child per node and derives the larger as parent - smaller
 (FeatureHistogram::Subtract, feature_histogram.hpp:64-70). Channels per
 node halve, so batch_k doubles inside the same 128-lane MXU output tile
-(K*(3+2) <= 128 -> K <= 25).
+(K*(3+2) <= 128 -> K <= 25). Under a data axis the cache is kept too
+(`schedule.pick_schedule` consents for the serial and the data-parallel
+learner): each shard contracts the smaller child's rows it holds, only
+those K histograms travel through the merge, and a shard subtracts in
+what it keeps of the merged tensor: its owned slice of the stored groups
+under hist_scatter, every group under the full psum. Which child is the
+smaller is read from the merged counts, so all shards agree. Voting
+keeps local histograms and drops the cache.
 
 Pass count drops from ~(commits / 2.8) to ~max(tree depth, commits / K):
 measured 91 -> ~30 per 255-leaf tree (batch_k=12, round 3), ~20 with
@@ -185,7 +192,8 @@ class GrowerConfig(NamedTuple):
     # histogram per expanded node and derive the larger as
     # parent - smaller. Halves the contraction channels per node, so
     # batch_k can double inside the same 128-lane MXU output tile.
-    # The GBDT layer gates this on the cache fitting a memory budget.
+    # `schedule.pick_schedule` gates this on the learner kind and on the
+    # cache, at the width one device keeps of it, fitting its budget.
     hist_subtract: bool = False
     # node-table slots per num_leaves (M = table_mult*L + 2K + 2). The
     # GBDT layer raises this as far as the subtraction cache's memory

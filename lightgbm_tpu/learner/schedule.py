@@ -225,13 +225,20 @@ def pick_schedule(groups: int, max_bins: int, rows: int, rows_padded: int,
                   learner: str = "serial", bundled: bool = False,
                   quantize: str = "none",
                   compact_fraction: Optional[float] = None,
-                  device_bytes: int = 0) -> Schedule:
+                  device_bytes: int = 0,
+                  cache_groups: Optional[int] = None) -> Schedule:
     """The execution schedule as a function of the shape: stored groups,
     bins of the widest group, rows and padded rows of ONE shard, and the
-    histogram chunk (`plan_row_layout` gives the last two); and of the
-    device: `device_bytes` is its memory, 0 where the backend reports
-    none (`subtract_cache_budget`). `compact_fraction` is the one thing
-    a user can set (`tpu_compact_threshold`); None leaves it to the shape.
+    histogram chunk (`plan_row_layout` gives the last two); of the
+    learner kind; and of the device: `device_bytes` is its memory, 0
+    where the backend reports none (`subtract_cache_budget`).
+    `cache_groups` is the width the subtraction cache has on one device
+    where that is not the shape's: the data-parallel learner under the
+    scatter merge keeps its owned slice, ceil(groups / shards) of the
+    stored groups (grow.py); None means all of them. The contraction's
+    cost model (`wide`, `compact_threshold`) keeps `groups`, which is
+    what a pass contracts. `compact_fraction` is the one thing a user
+    can set (`tpu_compact_threshold`); None leaves it to the shape.
 
     "Wide" shapes (large groups x bins) are channel-cost-bound in the
     histogram contraction (the [G*B, chunk] x [chunk, S] matmul's FLOPs
@@ -248,15 +255,27 @@ def pick_schedule(groups: int, max_bins: int, rows: int, rows_padded: int,
     # room allows, takes 6: on the run above trees 3-4 filled 509-527 of
     # its 1,548 slots, and 12 read the same passes for 2.8 GB more.
     # Without the cache the table is [M]-scalar cheap: take the max.
+    if cache_groups is None:
+        cache_groups = groups
+
     def cache_fits(mult: int, device: int) -> bool:
-        return subtract_cache_fits(groups, max_bins, num_leaves, mult,
+        return subtract_cache_fits(cache_groups, max_bins, num_leaves, mult,
                                    classes=classes, rows_padded=rows_padded,
                                    device_bytes=device)
 
     mult_fit = next((m for m in range(12, 5, -1)
                      if cache_fits(m, 0) and cache_fits(m, device_bytes)),
                     6 if cache_fits(6, device_bytes) else 0)
-    subtract = (learner == "serial"
+    # The serial and the data-parallel learner keep the cache: under the
+    # data axis only the K smaller children travel through the merge and
+    # each shard subtracts in what it keeps of it (grow.py). On four v5e
+    # chips: 30 -> 18 passes a tree, 29.86 -> 41.04 Mrow-iters/s, subtract
+    # and table 0.9 ms a tree (PR 33, `higgs-train-dp4`, PERF.md section
+    # 6). Voting keeps local histograms and drops the cache itself
+    # (grow.py), and the feature-parallel learner has never run with it:
+    # both keep the direct path, and consent here would only widen
+    # `batch_k` for a cache that is not built.
+    subtract = (learner in ("serial", "data")
                 # vmap'd class trees each carry a cache: the x classes
                 # scatter/memory traffic measured a net LOSS on the
                 # multiclass shape (0.62 vs 0.89 Mrow-iters/s)
@@ -286,7 +305,11 @@ def pick_schedule(groups: int, max_bins: int, rows: int, rows_padded: int,
                                  learner == "feature") > 0)
     if subtract:
         # one smaller-child channel set per node: 24 x (3 hi + 2 lo)
-        # fills the 128-lane tile. A wide shape's pass is not tile-bound:
+        # fills the 128-lane tile. Under the data axis as on one chip:
+        # `higgs-train-dp4` (28 groups, 25.2M rows a shard, four v5e
+        # chips, PR 33, PERF.md section 6) read 41.05 Mrow-iters/s at 24
+        # (18 passes a tree) and 32.27 at 12 (30 passes; 29.88 with no
+        # cache). A wide shape's pass is not tile-bound:
         # on the run above a contracted row costs 121-122 ns at 30-40
         # matmul columns and 166-176 at 50-120, and fewer nodes a pass
         # leave more passes under the compaction threshold; two trees at
@@ -333,11 +356,13 @@ def schedule_info(picked: Schedule, layout: RowLayout, cfg, *, rows: int,
         "hist_reduce": hist_reduce, "owned_groups": int(owned_groups),
         "groups": int(groups), "max_bin": int(cfg.max_bins),
         "wide": bool(picked.wide), "subtract": bool(picked.subtract),
-        # the cache's bytes (one class tree: `pick_schedule` subtracts
-        # for no more) and the device memory it was judged against (0:
-        # the backend reports none, `subtract_cache_budget`)
+        # the cache's bytes on one device (one class tree: `pick_schedule`
+        # subtracts for no more; at the owned slice's width, which is
+        # every group except under the scatter merge) and the device
+        # memory it was judged against (0: the backend reports none,
+        # `subtract_cache_budget`)
         "subtract_cache_bytes": subtract_cache_bytes(
-            groups, cfg.max_bins, cfg.num_leaves, picked.table_mult)
+            owned_groups, cfg.max_bins, cfg.num_leaves, picked.table_mult)
         if picked.subtract else 0,
         "device_bytes": int(device_bytes),
         "compact": bool(picked.compact),

@@ -52,6 +52,9 @@ def test_phases_pass_in_process_on_cpu(capsys):
     # 8 host devices >= 4: the data-parallel phase ran and spread the rows
     dp = summary["data_parallel"]
     assert dp["devices"] == 8 and dp["tree_structure_equal_to_serial"]
+    # under the data axis too: the cache, 24 smaller children a pass
+    assert dp["subtract"] is True and dp["batch_k"] == 24
+    assert "subtract=True batch_k=24" in out
     for phase in ("train", "predict", "serve", "repeat_train",
                   "data_parallel"):
         assert f"[chip_smoke] {phase}: ok" in out

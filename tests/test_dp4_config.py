@@ -19,7 +19,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 from lightgbm_tpu.binning import MISSING_NAN, MISSING_NONE, MISSING_ZERO
 from lightgbm_tpu.learner import grow
 from lightgbm_tpu.learner.grow import GrowerConfig, GrowParams
-from lightgbm_tpu.learner.schedule import pick_schedule, plan_row_layout
+from lightgbm_tpu.learner.schedule import (pick_schedule, plan_row_layout,
+                                           subtract_cache_bytes)
 from lightgbm_tpu.ops import split as split_ops
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -273,24 +274,40 @@ def test_the_configuration_is_the_one_chip_configuration_sharded():
         harness.load_cell("higgs-train-1chip")["cell"]["limits"])
 
 
-def test_one_shards_shape_takes_the_direct_path():
+def test_one_shards_shape_takes_the_cache():
     """What `GBDT.init` asks `pick_schedule` for this cell: one shard's
-    rows, the data learner, the v5e's memory."""
+    rows, the data learner, the v5e's memory, and the cache at the width
+    one device keeps of it (7 owned groups under the scatter merge, all
+    28 under allreduce)."""
     config = _loaded()["config"]
     rows, groups = int(config["rows"]), int(config["features"])
     bins = int(config["params"]["max_bin"])
+    leaves = int(config["params"]["num_leaves"])
     layout = plan_row_layout(rows, groups, bins, tree_learner="data", ndev=4)
     assert (layout.chunk, layout.n_pad) == (65536, 100_663_296)
     # each device holds the padded shard higgs-train-1chip holds
     assert layout.n_pad // 4 == plan_row_layout(21_000_000, 28, 63).n_pad
     shards = layout.row_multiple // layout.chunk
     assert shards == 4
-    picked = pick_schedule(groups, bins, rows // shards,
-                           layout.n_pad // shards, layout.chunk,
-                           num_leaves=int(config["params"]["num_leaves"]),
-                           learner="data", device_bytes=V5E_BYTES)
-    assert not picked.subtract and not picked.compact and not picked.wide
-    assert (picked.batch_k, picked.table_mult) == (12, 12)
+    shape = (groups, bins, rows // shards, layout.n_pad // shards,
+             layout.chunk)
+    owned_groups = -(-groups // shards)
+    picked = pick_schedule(*shape, num_leaves=leaves, learner="data",
+                           device_bytes=V5E_BYTES, cache_groups=owned_groups)
+    assert picked.subtract and not picked.compact and not picked.wide
+    assert (picked.batch_k, picked.table_mult) == (24, 12)
+    # under allreduce a device keeps every group: the same schedule, and
+    # it is the one-chip cell's for the same padded shard
+    whole = pick_schedule(*shape, num_leaves=leaves, learner="data",
+                          device_bytes=V5E_BYTES)
+    assert whole == picked
+    assert whole == pick_schedule(28, 63, 21_000_000, layout.n_pad // 4,
+                                  65536, num_leaves=leaves,
+                                  device_bytes=V5E_BYTES)
+    at_owned_slice = subtract_cache_bytes(owned_groups, bins, leaves, 12)
+    at_every_group = subtract_cache_bytes(groups, bins, leaves, 12)
+    assert (owned_groups, at_owned_slice) == (7, 16_468_704)
+    assert at_every_group == 65_874_816 == 4 * at_owned_slice
     # padding is a global suffix: the real rows each device holds
     per = layout.n_pad // 4
     real = [min(per, max(0, rows - d * per)) for d in range(4)]
@@ -392,15 +409,23 @@ def test_sharded_program_is_correct_and_control_is_not(sharded):
     assert (info["tree_learner"], info["num_shards"], info["hist_reduce"]) \
         == ("data", 4, "scatter")
     assert info["owned_groups"] == 7 and info["rows_padded"] == 4 * 4096
-    assert not info["subtract"] and not info["compact"]
-    assert info["batch_k"] == 12
+    assert info["subtract"] and not info["compact"]
+    assert (info["batch_k"], info["table_mult"]) == (24, 12)
+    assert info["subtract_cache_bytes"] == 16_468_704, "7 owned groups"
     assert out["correct"], out["compared"]
     assert out["compared"]["count_mismatch"]["value"] == 0
     assert not out["control_correct"], out["control_compared"]
     from lightgbm_tpu import telemetry
-    comm = [e[telemetry.TreeRecord._fields.index("comm_bytes")]
-            for e in out["pass_log_window"]]
-    assert min(comm) > 0, "the merge ran"
+    records = [telemetry.TreeRecord(*e) for e in out["pass_log_window"]]
+    assert min(r.comm_bytes for r in records) > 0, "the merge ran"
+    # what one device keeps of a pass's merge is the K smaller children
+    # at its 7 owned groups, not both children of every node; the root's
+    # pass merges one histogram
+    # (with the root's three totals)
+    kept = 7 * 63 * 3 * 4
+    for r in records:
+        assert r.comm_bytes == ((r.num_passes - 1) * 24 + 1) * kept + 12
+        assert r.rows_contracted == r.num_passes * DP_ROWS
     wait = [e[telemetry.TreeRecord._fields.index("fetch_wait_s")]
             for e in out["pass_log_window"]]
     assert min(wait) > 0, "the tree is fetched one iteration late"
@@ -446,6 +471,78 @@ def test_sharded_trees_are_the_serial_trees(sharded):
     assert inner.land_s > 0.0 and inner._row_sharded
     assert len(inner._binned.addressable_shards) == 4
     assert inner._score.sharding.spec == P(None, "data")
+
+
+# 6,000 x 12 at 31 leaves through `lgb.train`; `tpu_hist_chunk` 512 steers
+# the row plan to four shards of 1,536 rows, the last holding 1,392 real
+# ones (see DP_CHUNK above)
+TRAIN_ROWS, TRAIN_ROUNDS = 6000, 4
+
+
+_TRAINED = {}
+
+
+def _trained(sharded, **extra):
+    """(trees as arrays, pass records, schedule) of one `lgb.train` run,
+    kept: the serial side is the same for both merges."""
+    key = tuple(sorted(extra.items()))
+    if key not in _TRAINED:
+        _TRAINED[key] = _train(sharded, extra)
+    return _TRAINED[key]
+
+
+def _train(sharded, extra):
+    import lightgbm_tpu as lgb
+    rng = np.random.RandomState(5)
+    X = rng.randn(TRAIN_ROWS, 12).astype(np.float32)
+    margin = X[:, 0] + 0.6 * X[:, 1] * X[:, 2] - 0.4 * np.abs(X[:, 3])
+    y = (margin + 0.5 * rng.randn(TRAIN_ROWS) > 0).astype(np.float32)
+    params = dict({"objective": "binary", "num_leaves": 31, "max_bin": 63,
+                   "min_data_in_leaf": 5, "learning_rate": 0.1,
+                   "tpu_hist_chunk": 512, "verbose": -1}, **extra)
+    booster = lgb.train(params, lgb.Dataset(X, y, params=params),
+                        num_boost_round=TRAIN_ROUNDS, verbose_eval=False)
+    inner = booster._inner
+    return ([sharded.mode.tree_arrays(t) for t in inner.models],
+            list(inner.pass_log), inner._schedule_info)
+
+
+@pytest.mark.parametrize("bagging", [
+    {}, {"bagging_fraction": 0.5, "bagging_freq": 1, "bagging_seed": 3}],
+    ids=["all_rows", "bagging"])
+@pytest.mark.parametrize("reduce", ["scatter", "allreduce"])
+def test_the_trained_data_learner_keeps_the_cache_and_the_serial_trees(
+        sharded, reduce, bagging):
+    """The program, not `grow_tree` alone: `lgb.train` with the fused
+    sharded program on the cache's path against the serial learner, which
+    takes the same schedule for the same shape."""
+    dp, dp_log, info = _trained(sharded, tree_learner="data",
+                                tpu_hist_reduce=reduce, **bagging)
+    serial, serial_log, serial_info = _trained(sharded, **bagging)
+    assert (info["tree_learner"], info["num_shards"], info["hist_reduce"]) \
+        == ("data", 4, reduce)
+    assert info["rows_padded"] == 4 * 1536
+    assert info["owned_groups"] == (3 if reduce == "scatter" else 12)
+    assert info["subtract"] and serial_info["subtract"]
+    assert (info["batch_k"], info["table_mult"]) \
+        == (serial_info["batch_k"], serial_info["table_mult"]) == (24, 12)
+    assert info["subtract_cache_bytes"] * 12 \
+        == serial_info["subtract_cache_bytes"] * info["owned_groups"]
+    assert len(dp) == len(serial) == TRAIN_ROUNDS
+    for a, b in zip(dp, serial):
+        for key in ("split_feature", "threshold", "left_child",
+                    "right_child", "internal_count", "leaf_count"):
+            np.testing.assert_array_equal(a[key], b[key])
+        np.testing.assert_allclose(a["leaf_value"], b["leaf_value"],
+                                   rtol=1e-4, atol=1e-7)
+    assert len(dp_log) == len(serial_log) == TRAIN_ROUNDS
+    for rec, want in zip(dp_log, serial_log):
+        # the smaller child of each of K nodes a pass, every pass full
+        assert rec.num_passes == want.num_passes
+        assert rec.rows_contracted == rec.num_passes * TRAIN_ROWS \
+            == want.rows_contracted
+        assert (rec.full_passes, rec.compact_passes) == (rec.num_passes, 0)
+        assert rec.comm_bytes > 0 == want.comm_bytes
 
 
 def test_the_landing_span_is_in_the_profilers_trace(sharded, tmp_path):

@@ -112,6 +112,123 @@ def test_where_the_two_switches_turn(device_bytes, features, max_bin,
     assert picked.batch_k == (8 if subtract else 12), "all of them wide"
 
 
+NARROW, WIDE = (28, 63, 25_165_824), (2000, 63, 1 << 20)
+
+
+@pytest.mark.parametrize(
+    "shape,learner,classes,subtract,batch_k,table_mult,compact", [
+        # the serial and the data-parallel learner keep the cache (one
+        # class tree, and it fits the v5e at both widths)
+        (NARROW, "serial", 1, True, 24, 12, False),
+        (NARROW, "data", 1, True, 24, 12, False),
+        (WIDE, "serial", 1, True, 8, 6, True),
+        (WIDE, "data", 1, True, 8, 6, True),
+        # voting drops the cache itself (grow.py), the feature-parallel
+        # learner never ran with it, class trees lose by it: the direct
+        # path, as the parent of PR 33 gave every one of these
+        (NARROW, "voting", 1, False, 12, 12, False),
+        (NARROW, "feature", 1, False, 12, 12, False),
+        (WIDE, "voting", 1, False, 12, 12, True),
+        (WIDE, "feature", 1, False, 12, 12, False),
+        (NARROW, "serial", 3, False, 12, 6, False),
+        (NARROW, "data", 3, False, 12, 6, False),
+        (NARROW, "voting", 3, False, 12, 6, False),
+        (NARROW, "feature", 3, False, 12, 6, False),
+        (WIDE, "serial", 3, False, 12, 6, False),
+        (WIDE, "data", 3, False, 12, 6, False),
+        (WIDE, "voting", 3, False, 12, 6, False),
+        (WIDE, "feature", 3, False, 12, 6, False)])
+def test_which_learner_takes_the_cache(shape, learner, classes, subtract,
+                                       batch_k, table_mult, compact):
+    """Learner kind x class trees x shape on the v5e's memory, at 255
+    leaves: one shard's shape as `GBDT.init` hands it over."""
+    groups, bins, rows = shape
+    layout = plan_row_layout(rows, groups, bins)
+    picked = pick_schedule(groups, bins, rows, layout.n_pad, layout.chunk,
+                           num_leaves=255, classes=classes, learner=learner,
+                           device_bytes=V5E_BYTES)
+    assert (picked.subtract, picked.batch_k, picked.table_mult,
+            picked.compact) == (subtract, batch_k, table_mult, compact)
+    assert picked.wide == (shape is WIDE)
+    assert picked.compact_fraction == (0.25 if shape is WIDE else 0.0)
+    if learner == "data" and classes == 1:
+        # the data learner's answer is the serial learner's for the shape
+        assert picked == pick_schedule(
+            groups, bins, rows, layout.n_pad, layout.chunk, num_leaves=255,
+            device_bytes=V5E_BYTES)
+
+
+@pytest.mark.parametrize("bins,device_bytes,whole_fits", [
+    # 2000 groups over 4 shards. At `max_bin` 255 on the v5e the whole
+    # cache (9.68 GB at `table_mult` 6) misses a third of what the binned
+    # shard leaves and the owned slice of 500 groups (2.42 GB) fits; at 63
+    # the same on a device of 4 GiB (2.39 GB against 0.60); at 63 on the
+    # v5e both fit
+    (255, V5E_BYTES, False), (63, 4 << 30, False), (63, V5E_BYTES, True)])
+def test_the_budget_is_judged_at_the_width_the_cache_has(bins, device_bytes,
+                                                         whole_fits):
+    groups, rows, shards = 2000, 1 << 20, 4
+    layout = plan_row_layout(rows, groups, bins, tree_learner="data",
+                             ndev=shards)
+    shape = (groups, bins, rows // shards, layout.n_pad // shards,
+             layout.chunk)
+    asked = dict(num_leaves=255, learner="data", device_bytes=device_bytes)
+    # the scatter merge: a device keeps ceil(groups / shards) of them
+    owned = pick_schedule(*shape, cache_groups=500, **asked)
+    assert owned.subtract and (owned.batch_k, owned.table_mult) == (8, 6)
+    assert subtract_cache_fits(500, bins, 255, 6, rows_padded=shape[3],
+                               device_bytes=device_bytes)
+    # allreduce: every group on every device
+    whole = pick_schedule(*shape, **asked)
+    assert whole == pick_schedule(*shape, cache_groups=groups, **asked)
+    assert whole.subtract == whole_fits == subtract_cache_fits(
+        groups, bins, 255, 6, rows_padded=shape[3],
+        device_bytes=device_bytes)
+    assert whole.batch_k == (8 if whole_fits else 12)
+    assert whole.table_mult == (6 if whole_fits else 12)
+    # the contraction's cost model keeps the shard's full width
+    assert owned.wide and whole.wide
+    assert owned.compact_model == whole.compact_model
+    assert (owned.compact, owned.compact_fraction) \
+        == (whole.compact, whole.compact_fraction)
+
+
+@pytest.mark.parametrize("reduce,owned_groups,subtract", [
+    ("scatter", 100, True), ("allreduce", 400, False)])
+def test_the_trainer_hands_over_the_width_its_cache_has(reduce, owned_groups,
+                                                        subtract):
+    """`GBDT.init` on 4 of conftest's CPU devices (256 MB, no memory
+    reported): 400 groups x 255 bins at 31 leaves keep a cache of 291 MB
+    whole at `table_mult` 6, and of 130 MB at the owned slice at 12."""
+    import jax
+    rng = np.random.RandomState(11)
+    X = rng.randn(4096, 400).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 31, "max_bin": 255,
+              "min_data_in_leaf": 1, "tree_learner": "data",
+              "tpu_hist_reduce": reduce, "tpu_hist_chunk": 512,
+              "verbose": -1}
+    patch = pytest.MonkeyPatch()
+    four = jax.devices()[:4]
+    patch.setattr(jax, "devices", lambda *a, **k: four)
+    try:
+        info = lgb.Booster(params, lgb.Dataset(
+            X, (X[:, 0] > 0).astype(np.float32),
+            params=params))._inner._schedule_info
+    finally:
+        patch.undo()
+    assert (info["groups"], info["max_bin"]) == (400, 255)
+    assert (info["num_shards"], info["hist_reduce"]) == (4, reduce)
+    assert info["owned_groups"] == owned_groups
+    assert info["subtract"] == subtract
+    table_mult = info["table_mult"]
+    assert (info["batch_k"], table_mult) == (8 if subtract else 12, 12)
+    assert info["subtract_cache_bytes"] == (
+        schedule.subtract_cache_bytes(100, 255, 31, table_mult)
+        if subtract else 0)
+    assert not subtract_cache_fits(400, 255, 31, 6)
+    assert subtract_cache_fits(100, 255, 31, 12)
+
+
 def test_what_the_user_set_wins_over_the_shape():
     shape = (2000, 63, 1 << 20, 1 << 20, 8192)
     assert not pick_schedule(*shape, num_leaves=255,
