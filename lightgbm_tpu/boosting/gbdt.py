@@ -152,9 +152,10 @@ def _gradient_jit(obj):
     The row arrays are arguments, so objectives that agree on class and
     scalar state SHARE one program: a second booster on the same data (a
     warm-up train, then the timed one) reuses the compiled gradients
-    instead of re-tracing a fresh closure. An objective holding anything
-    besides arrays and plain scalars (lambdarank's per-dataset bucket
-    lists) bakes that into its trace and gets a private jit."""
+    instead of re-tracing a fresh closure. Lambdarank's pair layout is
+    held that way too (flat arrays beside a tuple of bucket shapes). An
+    objective holding anything besides arrays and plain scalars bakes
+    that into its trace and gets a private jit."""
     arr_keys = objective_array_keys(obj)
     rest = {k: v for k, v in vars(obj).items() if k not in arr_keys}
     if all(_is_plain(v) for v in rest.values()):
@@ -965,6 +966,15 @@ class GBDT:
                          if self._tree_learner_kind == "data" else None),
             owned_groups=owned_groups,
             device_bytes=self._device_bytes)
+        rank = getattr(objective, "rank_counters", None)
+        if rank is not None:
+            # lambdarank's pair layout, counted once in its init()
+            self._schedule_info["rank"] = rank.as_dict()
+            log.info("Schedule: rank queries=%d docs=%d max_docs=%d "
+                     "buckets=%s slots=%d pair_slots=%d valid_pairs=%d",
+                     rank.queries, rank.docs, rank.max_docs,
+                     ",".join("%d:%dx%d" % b for b in rank.buckets),
+                     rank.slots, rank.pair_slots, rank.valid_pairs)
 
         # boost from average (gbdt.cpp:358-378): the score bump happens at
         # init; the bias itself is folded into the first trained tree via

@@ -316,7 +316,10 @@ def pick_schedule(groups: int, max_bins: int, rows: int, rows_padded: int,
         # batch_k 4 / 6 / 8 / 10 / 16 took 2.88 / 3.09 / 2.49 / 3.39 /
         # 3.56 s of device time (12 and 24, before the parents' gather
         # was cured: 3.88 and 3.58, of which 0.40 and 0.25 the gather).
-        # 137-224 groups keep 8 unread (no cell; ROADMAP Queue 3 item 11)
+        # 137-224 groups keep 8 on ONE reading: `msltr-rank-1chip` (137
+        # groups, 12.58M rows, v5e, PR 34, PERF.md section 5) took 35 full
+        # passes a tree at 105.6 ms, 8.39 ns a row a pass, at 8; 4, 12 and
+        # 24 were not run there (ROADMAP Queue 3 item 11)
         batch_k = 8 if wide else 24
     else:
         # Bosch-class data (wide AND heavily EFB-bundled — sparse

@@ -55,13 +55,14 @@ COLLECTIVES = ("all-reduce-scatter", "reduce-scatter", "all-reduce",
 
 
 def scope_of(path: str, op: str = "") -> str:
-    """The innermost `SCOPES` name in an `op_name` path; without one, the
-    merge's scope for an operation `op` whose text names a collective
-    (`COLLECTIVES`), else `unscoped`."""
+    """The innermost `SCOPES` name in an `op_name` path, the longest where
+    two start at one place (`lgbm/gradients/rank_sort` over
+    `lgbm/gradients`); without one, the merge's scope for an operation
+    `op` whose text names a collective (`COLLECTIVES`), else `unscoped`."""
     best, at = UNSCOPED, -1
     for name in SCOPES:
         i = path.rfind(name)
-        if i > at:
+        if i > at or (i == at >= 0 and len(name) > len(best)):
             best, at = name, i
     if at < 0 and any(word in op for word in COLLECTIVES):
         return MERGE_SCOPE

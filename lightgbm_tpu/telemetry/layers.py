@@ -29,6 +29,14 @@ from typing import NamedTuple
 
 SCOPES = (
     "lgbm/gradients",         # objective gradients (boosting/gbdt.py)
+    # lambdarank's share of it, by stage (objectives.py); each is opened
+    # INSIDE lgbm/gradients, and devtrace charges an event to the longest
+    # name at the innermost place
+    "lgbm/gradients/rank_sort",     # scores gathered into the padded query
+                                    # batches, the two argsorts
+    "lgbm/gradients/rank_pairs",    # the [Qb, D, D] pair arithmetic and
+                                    # its two reductions
+    "lgbm/gradients/rank_scatter",  # lambdas and hessians back to rows
     "lgbm/grow/root_hist",    # the root's full pass and its totals
     "lgbm/grow/select",       # gain ranking, top_k, child slot allocation
     "lgbm/grow/relabel",      # route(): rows of the selected nodes -> children

@@ -14,7 +14,8 @@ seconds and the busy seconds by device plane.
 rehearsal. The layer table goes to standard output and the whole
 reduction, with the per-tree records of the traced trees, to
 `<out>/<name>.json` (`--out`, default `chiprun_out/`, the directory the
-chip tool brings back; `--name`, default `profile_train`). `--stats N`
+chip tool brings back; `--name`, default `profile_train`). `--ops N`
+keeps the N longest device operations (default 10); `--stats N`
 also prints every stat of the first N device events, and `--keep-trace`
 keeps the `.xplane.pb`: the by-hand look that tells which stat carries
 the scope on a new libtpu (`devtrace.SCOPE_STATS`).
@@ -61,6 +62,8 @@ def main(argv=None) -> int:
     ap.add_argument("--iterations", type=int, default=2)
     ap.add_argument("--out", default=os.path.join(REPO, "chiprun_out"))
     ap.add_argument("--stats", type=int, default=0)
+    ap.add_argument("--ops", type=int, default=10,
+                    help="how many device operations the table keeps")
     ap.add_argument("--param", action="append", default=[],
                     metavar="KEY=VALUE", help="override one parameter of "
                     "the configuration (an experiment, not the cell)")
@@ -87,7 +90,8 @@ def main(argv=None) -> int:
         config = json.load(fh)
     rows = args.rows or int(config["rows"])
     config["params"].update(kv.split("=", 1) for kv in args.param)
-    X, y = datagen.generator(config["generator"])(
+    # a ranking configuration's generator hands query sizes over too
+    X, y, *group = datagen.generator(config["generator"])(
         rows, int(config["features"]), args.seed)
     # set-up, as the benchmark's `dataset.construct_s` splits it: the
     # host's seconds in `construct()` (its phases are the ConstructRecord)
@@ -95,7 +99,8 @@ def main(argv=None) -> int:
     # row padding, upload, GBDT.init)
     jax.devices()   # the client's start-up (~8 s on the chip) is not set-up
     t = time.perf_counter()
-    ds = lgb.Dataset(X, y, params=dict(config["params"])).construct()
+    ds = lgb.Dataset(X, y, group=group[0] if group else None,
+                     params=dict(config["params"])).construct()
     construct_host_s = time.perf_counter() - t
     # with `--trace-init` traced too, in a trace of its own: `GBDT.init`'s
     # host spans (`lgbm/init/land`) are set-up and not in the iterations'
@@ -146,7 +151,7 @@ def main(argv=None) -> int:
         if args.keep_trace:
             shutil.copy(xplane, os.path.join(args.out,
                                              args.name + ".xplane.pb"))
-        reduced = devtrace.reduce_xplane(xplane)
+        reduced = devtrace.reduce_xplane(xplane, top=args.ops)
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
 

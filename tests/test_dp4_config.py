@@ -329,7 +329,8 @@ def test_the_merge_reader_reads_the_record_and_nothing_on_one_device():
     assert read({"schedule": {"num_shards": 4}}) is None
     assert read({}) is None
     bench = harness.load_json(ROOT, "BENCHMARK.json")
-    entry, = [m for m in bench["per_layer"] if "workloads" in m]
+    entry, = [m for m in bench["per_layer"]
+              if "workloads" in m and m["layer"] == "data-parallel"]
     assert entry["name"] == "merge.comm_mb_per_tree"
     assert entry["workloads"] == [CELL]
     assert (entry["layer"], entry["moves"], entry["source"]) == (

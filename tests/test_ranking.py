@@ -79,19 +79,10 @@ def test_map_matches_bruteforce(ranked_data):
         assert abs(np.mean(vals) - res[f"map@{k}"]) < 1e-9
 
 
-def test_lambdarank_gradients_match_bruteforce(ranked_data):
-    """Bucketed [Qb, D, D] pair gradients == reference's per-query O(cnt^2)
-    doc-pair loop (rank_objective.hpp:83-160)."""
-    import jax.numpy as jnp
-    md, qb, lab, score, n = ranked_data
-    cfg = Config.from_params({"objective": "lambdarank"})
-    obj = LambdarankNDCG(cfg)
-    obj.init(md, n)
-    g, h = obj.get_gradients(jnp.asarray(score, jnp.float32))
-    g, h = np.asarray(g), np.asarray(h)
-
-    sig = cfg.objective_config.sigmoid
-    inv = obj._inv_max_dcg_np
+def bruteforce_lambdas(qb, lab, score, inv, sig=1.0):
+    """The reference's per-query O(cnt^2) doc-pair loop
+    (rank_objective.hpp:83-160), positions by a stable descending sort."""
+    n = int(qb[-1])
     bg, bh = np.zeros(n), np.zeros(n)
     for q in range(len(qb) - 1):
         s_, e_ = qb[q], qb[q + 1]
@@ -117,6 +108,21 @@ def test_lambdarank_gradients_match_bruteforce(ranked_data):
                 bg[s_ + j] -= -dn * pl
                 bh[s_ + i] += 2.0 * dn * ph
                 bh[s_ + j] += 2.0 * dn * ph
+    return bg, bh
+
+
+def test_lambdarank_gradients_match_bruteforce(ranked_data):
+    """Bucketed [Qb, D, D] pair gradients == reference's per-query O(cnt^2)
+    doc-pair loop (rank_objective.hpp:83-160)."""
+    import jax.numpy as jnp
+    md, qb, lab, score, n = ranked_data
+    cfg = Config.from_params({"objective": "lambdarank"})
+    obj = LambdarankNDCG(cfg)
+    obj.init(md, n)
+    g, h = obj.get_gradients(jnp.asarray(score, jnp.float32))
+    g, h = np.asarray(g), np.asarray(h)
+    bg, bh = bruteforce_lambdas(qb, lab, score, obj._inv_max_dcg_np,
+                                cfg.objective_config.sigmoid)
     assert np.abs(g - bg).max() < 1e-3
     assert np.abs(h - bh).max() < 1e-3
 
@@ -135,12 +141,12 @@ def test_lambdarank_bucket_shapes():
     obj = LambdarankNDCG(cfg)
     obj.init(md, n)
     budget = LambdarankNDCG._PAIR_BUDGET
-    for gather, lab, mask, inv in obj._buckets:
+    for gather, lab, mask, inv in obj.bucket_layout():
         nb, Qb, D = gather.shape
         assert Qb * D * D <= max(budget, D * D), (Qb, D)
     # every real doc appears exactly once across buckets
     import jax.numpy as jnp
-    total_docs = sum(int(m.sum()) for _, _, m, _ in obj._buckets)
+    total_docs = sum(int(m.sum()) for _, _, m, _ in obj.bucket_layout())
     assert total_docs == n
 
 

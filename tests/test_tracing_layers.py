@@ -112,8 +112,27 @@ def lowered():
     return out
 
 
+def _lower_rank_gradients():
+    """The gradient program of a small lambdarank booster, lowered."""
+    rng = np.random.RandomState(5)
+    sizes = rng.randint(1, 40, size=30)
+    rows = int(sizes.sum())
+    p = dict(PARAMS, objective="lambdarank")
+    inner = lgb.Booster(p, lgb.Dataset(
+        rng.randn(rows, 4).astype(np.float32),
+        rng.randint(0, 5, size=rows).astype(np.float32), group=sizes,
+        params=p))._inner
+    inner._compute_gradients(inner._score)
+    arrs = {k: getattr(inner.objective, k) for k in inner._jit_grads_keys}
+    return inner._jit_grads.lower(inner._score, arrs)
+
+
 @pytest.mark.parametrize("name", layers.SCOPES)
 def test_scope_is_in_the_lowered_program(lowered, name):
+    if name.startswith("lgbm/gradients/rank_"):     # lambdarank's alone
+        assert name not in lowered["serial"]
+        assert name in _lower_rank_gradients().as_text(debug_info=True)
+        return
     where = "parallel" if name == "lgbm/hist/merge" else "serial"
     assert name in lowered[where]
 

@@ -261,6 +261,16 @@ GOLDEN = {
         {"chunk": 8192, "batch_k": 8, "hist_subtract": True,
          "hist_compact": True, "compact_fraction": 0.25, "table_mult": 6},
         262_144),
+    # PR 34: 137 groups x 63 bins is just past the wide line (8,631 of
+    # 8,192 columns): the cache at 8 smaller children a pass, no
+    # compaction, no padded row
+    "msltr-rank-1chip": (
+        (12_582_912, 137),
+        (65536, 65536, 12_582_912, 1, 1),
+        (True, True, 9, False, 0.0, (0.0, 8.5, 7.5, 49.9), 8),
+        {"chunk": 65536, "batch_k": 8, "hist_subtract": True,
+         "hist_compact": False, "compact_fraction": 0.0, "table_mult": 9},
+        0),
 }
 
 
@@ -559,9 +569,12 @@ def test_benchmark_json_names_the_cell_and_its_readers():
     assert names[10:13] == ["grower.gathered_per_row",
                             "dataset.sketch_s", "dataset.bin_s"]
     for m in bench["per_layer"]:
-        # every cell produces what this cell's readers read; the one
-        # metric with a list of cells came with higgs-train-dp4
-        assert ("workloads" in m) == (m["name"] == "merge.comm_mb_per_tree")
+        # every cell produces what this cell's readers read; the metrics
+        # with a list of cells came with higgs-train-dp4 (the merge) and
+        # msltr-rank-1chip (the ranking gradients)
+        assert ("workloads" in m) == (
+            m["name"] == "merge.comm_mb_per_tree"
+            or m["name"].startswith("gradients."))
         assert os.path.isfile(os.path.join(BENCH, "layer_metrics",
                                            m["name"] + ".py"))
     assert set(loaded["cell"]["limits"]) == set(
