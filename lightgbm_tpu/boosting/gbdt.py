@@ -26,7 +26,8 @@ from ..config import Config
 from ..dataset import Dataset, Metadata
 from ..learner.grow import GrowerConfig, grow_tree
 from ..learner.schedule import (compact_capacity, pick_schedule,
-                                plan_row_layout, schedule_info)
+                                plan_row_layout, relabel_rows,
+                                schedule_info)
 from ..metrics import Metric, create_metric, default_metric_for_objective
 from ..objectives import ObjectiveFunction
 from ..ops.lookup import row_lookup
@@ -831,19 +832,25 @@ class GBDT:
                 else None),
             device_bytes=self._device_bytes, cache_groups=owned_groups)
         costs = picked.compact_model
+        relabel = relabel_rows(g_cnt, self._max_bins, picked.batch_k,
+                               n_pad // shards,
+                               classes=self.num_tree_per_iteration)
         log.info("Schedule: groups=%d max_bin=%d wide=%s subtract=%s "
                  "compact=%s@%.3f (ns a row: full=%.1f index=%.1f "
                  "gather=%.1f) batch_k=%d table_mult=%d chunk=%d "
-                 "quantize=%s qmax=%d",
+                 "relabel=%s@%d quantize=%s qmax=%d",
                  g_cnt, self._max_bins, picked.wide, picked.subtract,
                  picked.compact, picked.compact_fraction, costs.full_ns,
                  costs.index_ns, costs.gather_ns, picked.batch_k,
-                 picked.table_mult, layout.chunk, quant_mode, quant_qmax)
+                 picked.table_mult, layout.chunk,
+                 "blocked" if relabel else "columns", relabel, quant_mode,
+                 quant_qmax)
         self._grower_cfg = GrowerConfig(
             num_leaves=self.config.tree.num_leaves,
             max_bins=self._max_bins,
             feature_bins=int(train_data.num_bins_per_feature().max(initial=1)),
             **picked.grower_fields(layout.chunk),
+            relabel_rows=relabel,
             hist_bf16=self.config.tree.tpu_hist_bf16,
             lambda_l1=self.config.tree.lambda_l1,
             lambda_l2=self.config.tree.lambda_l2,

@@ -221,6 +221,10 @@ class GrowerConfig(NamedTuple):
     # up to a chunk multiple; >= 1.0 forces every pass through the
     # compacted path — useful for tests; <= 0 disables compaction)
     compact_fraction: float = 0.25
+    # rows a step of the blocked relabel routes; 0 keeps the column form
+    # (`route`; `schedule.relabel_rows` picks it from stored groups and
+    # batch_k). Either gives the same labels to the bit.
+    relabel_rows: int = 0
     # quantized-gradient training (tpu_hist_quantize, ISSUE 20):
     # "none" | "int16" | "int8". Quantized modes expect grad/hess already
     # scaled + stochastically rounded to integer-valued f32 in
@@ -841,8 +845,12 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                         (row_weight > 0).astype(jnp.float32)], axis=-1)
 
     # transposed bin matrix for the routing step: row g is the contiguous
-    # bin column of stored group g (loop-invariant — XLA hoists it out of
-    # the round loop)
+    # bin column of stored group g. Made once a tree, before the round
+    # loop; XLA:TPU keeps the [N, G] uint8 matrix rows-minor already
+    # (`u8[25165824,28]{0,1:T(8,128)(4,1)}`; so at 137 and 2000 groups),
+    # and the transpose is a bitcast: no operation runs for it (TPU v5e,
+    # PR 35's profiles). A pass reads from it the K rows its selected
+    # nodes split on, whole, or every row a block at a time (`route`)
     with scope("lgbm/grow/relabel"):
         binned_T = binned.T
 
@@ -1108,43 +1116,9 @@ def grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                 hist_ids = jnp.concatenate([jnp.where(valid, cl, -1),
                                             jnp.where(valid, cr, -1)])
 
-        def route(lid, col_of_group):
-            """Apply the K selected splits to a leaf-label vector
-            (replaces DataPartition::Split, data_partition.hpp:94-170):
-            each split descriptor is a handful of SCALARS and the
-            feature's bin column is ONE contiguous dynamic slice of the
-            transposed bin matrix — no [N]-indexed gathers anywhere."""
-            for k in range(K):
-                m_k = jnp.clip(sel[k], 0, M - 1)
-                feat = t.feature[m_k]
-                grp = fmeta["group"][feat]
-                off = fmeta["offset"][feat]
-                nb = fmeta["num_bin"][feat]
-                dbin = fmeta["default_bin"][feat]
-                missing = fmeta["missing_type"][feat]
-                col = col_of_group(grp).astype(jnp.int32)
-                # EFB decode (efb.py): inside the feature's bundle slice
-                # the group bin is offset+bin; anywhere else the row sits
-                # at the default bin
-                in_slice = (col >= off) & (col < off + nb)
-                decoded = jnp.where(in_slice, col - off, dbin)
-                col = jnp.where(fmeta["is_bundled"][feat], decoded, col)
-                thr = t.threshold[m_k]
-                dl = t.default_left[m_k]
-                cat = t.is_cat[m_k]
-                nan_bin = nb - 1
-                is_missing = (((missing == MISSING_NAN) & (col == nan_bin))
-                              | ((missing == MISSING_ZERO) & (col == dbin)))
-                go_left = jnp.where(cat, col == thr,
-                                    jnp.where(is_missing, dl, col <= thr))
-                in_k = valid[k] & (lid == sel[k])
-                lid = jnp.where(in_k, jnp.where(go_left, cl[k], cr[k]),
-                                lid)
-            return lid
-
         with scope("lgbm/grow/relabel"):
-            leaf_id = route(carry.leaf_id, lambda grp: jax.lax.dynamic_slice(
-                binned_T, (grp, 0), (1, n))[0])
+            leaf_id = route(carry.leaf_id, binned_T, fmeta, t, sel, valid,
+                            cl, cr, block_rows=cfg.relabel_rows)
 
         if compact:
             # member rows of THIS pass's selected nodes are exactly the
@@ -1497,6 +1471,99 @@ def leaf_path_features(leaf_parent, node_feature, node_left, node_right,
         return feats
 
     return jax.vmap(one_leaf)(leaf_parent.astype(jnp.int32))
+
+
+def _goes_left(bins, s):
+    """The split rule on stored-group bins (int32, any shape): `s` holds
+    each split's scalars, broadcastable against `bins`."""
+    # EFB decode (efb.py): inside the feature's bundle slice the group
+    # bin is offset+bin; anywhere else the row sits at the default bin
+    in_slice = (bins >= s["off"]) & (bins < s["off"] + s["nb"])
+    bins = jnp.where(s["bundled"],
+                     jnp.where(in_slice, bins - s["off"], s["dbin"]), bins)
+    is_missing = (((s["missing"] == MISSING_NAN) & (bins == s["nb"] - 1))
+                  | ((s["missing"] == MISSING_ZERO) & (bins == s["dbin"])))
+    return jnp.where(s["cat"], bins == s["thr"],
+                     jnp.where(is_missing, s["dl"], bins <= s["thr"]))
+
+
+def route(leaf_id, binned_T, fmeta, splits, sel, valid, cl, cr,
+          block_rows: int = 0):
+    """Apply the K selected splits to a leaf-label vector (replaces
+    DataPartition::Split, data_partition.hpp:94-170): a row labelled
+    `sel[k]` with `valid[k]` moves to `cl[k]` or `cr[k]` by node
+    `sel[k]`'s cached split in `splits` (`.feature`, `.threshold`,
+    `.default_left`, `.is_cat`, each [M]); every other row keeps its
+    label. `binned_T` is the [G, N] transposed bin matrix, `fmeta` the
+    per-feature tables; a slot with `valid` false may hold any `sel`.
+    Each split is a handful of scalars and the arithmetic is integer
+    logic, so the two forms give the same labels to the bit; which one a
+    run takes is `schedule.relabel_rows`'s.
+
+    `block_rows` 0, the column form: each node's bin column is ONE row
+    of `binned_T`, a contiguous dynamic slice, and the labels thread
+    through K selects. XLA:TPU fuses the slices into the select up to
+    about 12 nodes; at 24 it writes each out as `s32[1, N]` first and
+    reads them back (`schedule.RELABEL_BLOCK` has the readings).
+
+    `block_rows` > 0, the blocked form: one loop over blocks of that
+    many rows, rows on the minor axis as in `ops/lookup.row_lookup`,
+    `leaf_id` updated in place. A block of b rows reads its labels and
+    the WHOLE `[G, b]` uint8 bin block, picks each node's group row by a
+    `[K, G] x [G, b]` one-hot product in bfloat16 (bins are at most 255
+    and one term is non-zero: exact), compares `[K, b]` at once and
+    writes its labels once. Nothing of length N exists inside a pass but
+    `leaf_id` itself; it reads G bytes a row, so it is the narrow side's.
+    The last block is pulled back inside the rows, so rows it shares
+    with the block before are routed twice. That is routing them once:
+    the children `cl`, `cr` are fresh ids, never a selected node's (the
+    allocation pointer is past every created node)."""
+    (n,), K = leaf_id.shape, sel.shape[0]
+    G = binned_T.shape[0]
+    m = jnp.clip(sel, 0, splits.feature.shape[0] - 1)
+    feat = splits.feature[m]
+    grp = fmeta["group"][feat]
+    s = {"off": fmeta["offset"][feat], "nb": fmeta["num_bin"][feat],
+         "dbin": fmeta["default_bin"][feat],
+         "missing": fmeta["missing_type"][feat],
+         "bundled": fmeta["is_bundled"][feat],
+         "thr": splits.threshold[m], "dl": splits.default_left[m],
+         "cat": splits.is_cat[m]}                               # each [K]
+
+    if not block_rows:
+        for k in range(K):
+            bins = jax.lax.dynamic_slice(
+                binned_T, (grp[k], 0), (1, n))[0].astype(jnp.int32)
+            go_left = _goes_left(bins, {a: v[k] for a, v in s.items()})
+            in_k = valid[k] & (leaf_id == sel[k])
+            leaf_id = jnp.where(in_k, jnp.where(go_left, cl[k], cr[k]),
+                                leaf_id)
+        return leaf_id
+
+    if binned_T.dtype != jnp.uint8:
+        raise TypeError("the blocked relabel moves bins through bfloat16, "
+                        f"exact to 255, not {binned_T.dtype}")
+    b = min(block_rows, n)
+    pick = (grp[:, None] == jnp.arange(G, dtype=grp.dtype)[None, :]
+            ).astype(jnp.bfloat16)                              # [K, G]
+    s = {a: v[:, None] for a, v in s.items()}
+    sel, valid, cl, cr = (v[:, None] for v in (sel, valid, cl, cr))
+
+    def one_block(i, lid):
+        start = jnp.minimum(i * b, n - b)
+        lb = jax.lax.dynamic_slice(lid, (start,), (b,))
+        bins = jnp.dot(
+            pick, jax.lax.dynamic_slice(binned_T, (0, start), (G, b)
+                                        ).astype(jnp.bfloat16),
+            preferred_element_type=jnp.float32).astype(jnp.int32)   # [K, b]
+        in_k = valid & (lb[None, :] == sel)
+        # a row is in at most one selected node; child ids are positive
+        child = jnp.max(jnp.where(
+            in_k, jnp.where(_goes_left(bins, s), cl, cr), 0), axis=0)
+        return jax.lax.dynamic_update_slice(
+            lid, jnp.where(child > 0, child, lb), (start,))
+
+    return jax.lax.fori_loop(0, -(-n // b), one_block, leaf_id)
 
 
 def rows_of_nodes(leaf_id, node_ids):

@@ -201,6 +201,54 @@ def compact_threshold(num_groups: int, max_bins: int, rows: int,
     return CompactChoice(fraction, full, index, gather)
 
 
+# The relabel pass (`grow.route`) has two forms that give the same labels
+# to the bit. The column form slices each selected node's bin column out
+# of the transposed matrix and threads the labels through K selects: its
+# cost a row follows the nodes a pass and not the width, and between 12
+# and 24 nodes XLA:TPU stops fusing the slices into the select (24
+# `s32[1, N]` columns written and read back). The blocked form loops over
+# blocks of rows, reads the whole [G, block] uint8 bin block and picks
+# each node's row by a one-hot product: G bytes a row, little more for
+# more nodes. Both were read ALONE on a TPU v5e, 8 passes a call (PR 35's
+# sweeps, PERF.md section 6), ms a pass, column / blocked at 262,144:
+#   25,165,824 x 28:  8 nodes 4.79 / 3.59, 12: 7.15 / 4.82, 24: 26.05 / 5.98
+#   12,582,912 x 137: 8 nodes 2.35 / 4.68, 12: 3.62 / 4.40, 24: 12.87 / 4.57
+#   1,048,576 x 2000: 8 nodes 0.29 / not run (2.1 GB a pass)
+# In the cells (`higgs-train-1chip`, `higgs-train-dp4`: 18 passes of 24
+# nodes a tree) the scope read 24.4 -> 6.0 ms a pass. As ns a row:
+_RELABEL_NODES = (8, 12, 24)
+_RELABEL_COLUMN_NS = (0.190, 0.286, 1.030)
+_RELABEL_GROUPS = (28, 137)
+_RELABEL_BLOCKED_NS = ((0.142, 0.192, 0.237), (0.372, 0.349, 0.363))
+# Rows a step of the blocked form routes. At 28 groups and 24 nodes
+# blocks of 65,536 / 131,072 / 262,144 / 524,288 / 1,048,576 rows read
+# 8.50 / 6.10 / 5.98 / 5.77 / 5.74 ms a pass; at 137 groups 4.82 / 4.65 /
+# 4.57 / 4.46 / 4.73. One-row slices in place of the whole block (16,384
+# to 262,144 rows) read 23.2-28.9 ms: a row of the packed uint8 tile
+# costs what the tile does.
+RELABEL_BLOCK = 262144
+
+
+def relabel_rows(groups: int, max_bins: int, batch_k: int,
+                 rows_padded: int, classes: int = 1) -> int:
+    """Rows a step of the blocked relabel routes on a shard of
+    `rows_padded` rows of `groups` stored groups at `batch_k` nodes a
+    pass; 0 means the column form (`grow.GrowerConfig.relabel_rows`).
+    Blocked where the readings above say it is the cheaper: linear
+    between the measured widths and node counts, flat past the node
+    counts. No reading is trusted outside the widths or under 8 nodes,
+    nor for bins past uint8 (the one-hot product is exact to 255) or the
+    vmapped class trees, which were not run: those keep the column form."""
+    if (max_bins > 256 or classes > 1 or batch_k < _RELABEL_NODES[0]
+            or groups > _RELABEL_GROUPS[-1]):
+        return 0
+    column = np.interp(batch_k, _RELABEL_NODES, _RELABEL_COLUMN_NS)
+    blocked = np.interp(
+        groups, _RELABEL_GROUPS,
+        [np.interp(batch_k, _RELABEL_NODES, ns) for ns in _RELABEL_BLOCKED_NS])
+    return int(min(RELABEL_BLOCK, rows_padded)) if blocked < column else 0
+
+
 class Schedule(NamedTuple):
     """`pick_schedule`'s answer: what `GBDT.init` hands the grower."""
     wide: bool                 # groups x bins > 8192: channel-cost-bound
@@ -376,6 +424,11 @@ def schedule_info(picked: Schedule, layout: RowLayout, cfg, *, rows: int,
         "batch_k": int(picked.batch_k), "table_mult": int(picked.table_mult),
         "chunk": int(layout.chunk), "rows": int(rows),
         "rows_padded": int(layout.n_pad),
+        # the relabel pass (`grow.route`, `relabel_rows`): its form and,
+        # blocked, the rows a step of its loop routes (0: the column
+        # form, whole columns)
+        "relabel": {"form": "blocked" if cfg.relabel_rows else "columns",
+                    "block_rows": int(cfg.relabel_rows)},
         "hist_quantize": cfg.hist_quantize, "hist_qmax": int(cfg.hist_qmax),
         "hist_hess_const": bool(cfg.hist_hess_const),
         "grower": dict(

@@ -39,7 +39,11 @@ SCOPES = (
     "lgbm/gradients/rank_scatter",  # lambdas and hessians back to rows
     "lgbm/grow/root_hist",    # the root's full pass and its totals
     "lgbm/grow/select",       # gain ranking, top_k, child slot allocation
-    "lgbm/grow/relabel",      # route(): rows of the selected nodes -> children
+    "lgbm/grow/relabel",      # grow.route(): rows of the selected nodes ->
+                              # children; blocked (a block's labels, its
+                              # [G, b] uint8 bins, a one-hot product, one
+                              # in-place write) or by whole bin columns
+                              # (schedule.relabel_rows); and binned.T
     "lgbm/grow/compact_index",  # member mask, cumsum, scatter of row indices
     "lgbm/hist/gather",       # bins and (g, h, w) gathered through the index
     "lgbm/hist/contract",     # the one-hot contraction, full or gathered
