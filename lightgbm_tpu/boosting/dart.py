@@ -130,8 +130,8 @@ class DART(GBDT):
         kdrop = len(self.drop_index)
         # drop activity in the run log / counters: a DART run whose
         # ledger drifted is diagnosed from dropped-per-iteration deltas
-        from .. import tracing
-        tracing.counter("boosting/dart_dropped_trees", kdrop)
+        from .. import telemetry
+        telemetry.counter_add("boosting/dart_dropped_trees", kdrop)
         if not cfg.xgboost_dart_mode:
             self.shrinkage_rate = cfg.learning_rate / (1.0 + kdrop)
         else:

@@ -192,6 +192,18 @@ def _device_memory_bytes(device) -> int:
     return int((device.memory_stats() or {}).get("bytes_limit", 0))
 
 
+def _dispatched(t_enter: float, compiled):
+    """What one tree's enqueue took, for its `telemetry.TreeRecord`:
+    (`dispatch_s`, `trace_lower_s`, `backend_s`, `cache_misses`) since
+    `t_enter`, a `perf_counter`, and `compiled`, the observer's `totals()`
+    read at that moment. The gradient program, bagging and the grow
+    program are all traced, lowered and loaded inside that interval, on
+    the first tree(s) alone."""
+    trace_lower_s, backend_s, _, misses = telemetry.compile_path_since(
+        compiled)
+    return time.perf_counter() - t_enter, trace_lower_s, backend_s, misses
+
+
 def _pad_to(arr: np.ndarray, n: int, value=0):
     pad = n - arr.shape[0]
     if pad <= 0:
@@ -546,10 +558,37 @@ class GBDT:
     # ------------------------------------------------------------------
     def init(self, train_data: Dataset, objective: Optional[ObjectiveFunction],
              metric_names: Sequence[str] = ()) -> None:
-        """Reference: GBDT::Init, gbdt.cpp:65-193."""
+        """Reference: GBDT::Init, gbdt.cpp:65-193. What it took and what
+        it decided about the rows is kept, always, as `init_record` (a
+        `telemetry.InitRecord`: host seconds by `telemetry.INIT_SPANS`
+        phase, the real rows a device's shard holds, jax's compile path
+        over the call), and this booster becomes the process's
+        `telemetry.last_run()`."""
+        compiled = telemetry.observer().totals()
+        t_enter = time.perf_counter()
+        with telemetry.Phases(telemetry.INIT_SPANS) as phase:
+            shards = self._init(phase, train_data, objective, metric_names)
+        self.init_record = telemetry.InitRecord(
+            *(phase.seconds[name] for name in telemetry.INIT_SPANS),
+            time.perf_counter() - t_enter,
+            self._n, int(self._binned.nbytes),
+            telemetry.layers.shard_rows(self._n, self._n_pad, shards),
+            *telemetry.compile_path_since(compiled))
+        # one `telemetry.TreeRecord` a tree (_log_pass_economics)
+        self.pass_log: List[telemetry.TreeRecord] = []
+        telemetry.record_run(getattr(train_data, "construct_record", None),
+                             self.init_record, self.pass_log)
+
+    def _init(self, phase, train_data: Dataset,
+              objective: Optional[ObjectiveFunction],
+              metric_names: Sequence[str]) -> int:
+        """`init` in consecutive stretches, each told to `phase` by its
+        `telemetry.INIT_SPANS` name. Returns the row shards this process
+        holds (1 where the rows are not sharded)."""
         import jax
         import jax.numpy as jnp
 
+        phase("lgbm/init/schedule")
         self.train_data = train_data
         self.objective = objective
         if objective is not None:
@@ -673,6 +712,7 @@ class GBDT:
         fm = train_data.feature_meta_arrays()
         self._max_bins = int(train_data.max_num_bin())
 
+        phase("lgbm/init/objective")
         # the objective captures its statistics (bias, class counts, query
         # DCGs) from the REAL data, then pads its row arrays so the gradient
         # kernels line up with the padded scores (padded rows are masked by
@@ -705,6 +745,7 @@ class GBDT:
                 objective.sync_distributed(_allreduce_sum)
             objective.pad_to(n_pad)
 
+        phase("lgbm/init/state")
         self._base_weight = jnp.asarray(
             _pad_to(np.ones(n, np.float32), n_pad))
 
@@ -762,6 +803,7 @@ class GBDT:
                 self._score = self._score + jnp.asarray(_pad_to(isc, n_pad))[None, :]
 
         # metrics
+        phase("lgbm/init/objective")
         self.metrics = []
         for mname in metric_names:
             m = create_metric(mname, self.config)
@@ -770,6 +812,7 @@ class GBDT:
                 self.metrics.append(m)
 
         # --- quantized-gradient training (tpu_hist_quantize, ISSUE 20) ---
+        phase("lgbm/init/schedule")
         from ..ops.histogram import TRAIN_QUANTIZE_MODES, train_qmax
         quant_mode = str(self.config.tree.tpu_hist_quantize or "none").lower()
         if quant_mode not in TRAIN_QUANTIZE_MODES:  # config validates; belt
@@ -929,9 +972,9 @@ class GBDT:
         # array lands ONCE in the sharding that program declares; left
         # unplaced it sits whole on the first device and is re-split at
         # every tree
+        phase("lgbm/init/state")
         self._row_sharded = (self._tree_learner_kind in ("data", "voting")
                              and ndev > 1 and nproc == 1)
-        self.land_s = 0.0
         if self._row_sharded:
             from jax.sharding import NamedSharding, PartitionSpec
             mesh = self._dist_grower.mesh
@@ -943,20 +986,20 @@ class GBDT:
                 arr = getattr(objective, key)
                 if arr.ndim == 1 and arr.shape[0] == n_pad:
                     setattr(objective, key, jax.device_put(arr, rows_1d))
+        phase("lgbm/init/land")
         if device_binned is not None:
             # already sharded the way the data/voting shard_map wants
             self._binned = device_binned
         elif self._row_sharded:
             # the upload itself, waited for: four shards from one process
             # are this deployment's own share of the set-up
-            t_land = time.perf_counter()
-            with telemetry.span("lgbm/init/land"):
-                self._binned = jax.block_until_ready(jax.device_put(
-                    binned_host, NamedSharding(
-                        mesh, PartitionSpec("data", None))))
-            self.land_s = time.perf_counter() - t_land
+            self._binned = jax.block_until_ready(jax.device_put(
+                binned_host, NamedSharding(
+                    mesh, PartitionSpec("data", None))))
         else:
+            # the host seconds of the enqueue: nothing waits here
             self._binned = jnp.asarray(binned_host)
+        phase("lgbm/init/state")
         # logical (possibly shard-padded) feature count for feature_fraction
         # masks; the stored binned width is the GROUP count (EFB)
         self._num_features_padded = int(fm["num_bin"].shape[0])
@@ -1001,7 +1044,9 @@ class GBDT:
         # it. Runs after boost-from-average so the calibration gradients
         # match the real iteration-0 score.
         if quant_mode != "none":
+            phase("lgbm/init/gate")
             self._hist_quant_gate()
+        return shards
 
     def _hist_quant_gate(self) -> None:
         """Setup-time gate for tpu_hist_quantize (the serving
@@ -1210,6 +1255,7 @@ class GBDT:
         import jax.numpy as jnp
 
         t_enter = time.perf_counter()
+        compiled = telemetry.observer().totals()
         it = self.iter_
         k = self.num_tree_per_iteration
         n_pad = self._n_pad
@@ -1261,8 +1307,8 @@ class GBDT:
         if ((self._dist_grower is None or self._row_sharded) and k == 1
                 and not self.valid_sets and gradients is None
                 and getattr(self, "_supports_pipeline", True)):
-            return self._train_one_iter_pipelined(grad, hess, row_weight,
-                                                  probe, qscales, t_enter)
+            return self._train_one_iter_pipelined(
+                grad, hess, row_weight, probe, qscales, (t_enter, compiled))
         self._raise_if_nonfinite(probe, self.iter_)
 
         # leaving the pipelined path (explicit gradients, a valid set
@@ -1272,7 +1318,8 @@ class GBDT:
 
         could_split_any = False
         for cls in range(k):
-            t_cls = t_enter if cls == 0 else time.perf_counter()
+            entered = (t_enter, compiled) if cls == 0 else (
+                time.perf_counter(), telemetry.observer().totals())
             mask = self._feature_mask()
             qs = None if qscales is None else qscales[cls]
             if getattr(self, "_linear", False):
@@ -1298,7 +1345,7 @@ class GBDT:
                 small["leaf_coeff"] = leaf_coeff
                 small["leaf_features_inner"] = feats
                 tree, timing = self._fetch_tree(
-                    small, it, time.perf_counter() - t_cls)
+                    small, it, _dispatched(*entered))
                 if tree.num_leaves > 1:
                     self._score = self._score.at[cls].add(
                         jnp.float32(self.shrinkage_rate) * vals)
@@ -1313,7 +1360,7 @@ class GBDT:
                         [self._fmeta[key] for key in FMETA_KEYS], cls,
                         self._grower_cfg, qscale=qs)
                 tree, timing = self._fetch_tree(
-                    small, it, time.perf_counter() - t_cls)
+                    small, it, _dispatched(*entered))
             else:
                 with telemetry.span("lgbm/iter/dispatch", iteration=it):
                     state = self._grow(grad[cls], hess[cls], row_weight,
@@ -1321,7 +1368,7 @@ class GBDT:
                 small = {key: getattr(state, key)
                          for key in _SMALL_STATE_KEYS}
                 tree, timing = self._fetch_tree(
-                    small, it, time.perf_counter() - t_cls)
+                    small, it, _dispatched(*entered))
                 if tree.num_leaves > 1:
                     # train score update via leaf ids (UpdateScore,
                     # gbdt.cpp:521)
@@ -1357,7 +1404,7 @@ class GBDT:
         return self._finish_iter(could_split_any)
 
     def _train_one_iter_pipelined(self, grad, hess, row_weight,
-                                  probe, qscales, t_enter) -> bool:
+                                  probe, qscales, entered) -> bool:
         """One-class iteration of the serial learner, or of a data-parallel
         one whose rows live on this process's devices, with the tree fetch
         pipelined one iteration behind the device dispatch (see __init__). The
@@ -1387,16 +1434,16 @@ class GBDT:
                 self._grower_cfg,
                 qscale=None if qscales is None else qscales[0],
                 grower=self._dist_grower)
-        dispatch_s = time.perf_counter() - t_enter
+        dispatch = _dispatched(*entered)
         # fetch + build the PREVIOUS tree while this one runs on device
         ok_prev = self._flush_pending()
         # stash the DISPATCH-TIME shrinkage (a learning-rate schedule
         # changes self.shrinkage_rate before the flush happens one
         # iteration later), the dispatch-time non-finite probe and
         # iteration index, fetched together with the small tree arrays,
-        # and the host seconds this call took to enqueue the tree
+        # and what this call took to enqueue the tree (_dispatched)
         self._pending_small = (small, self.shrinkage_rate, probe, self.iter_,
-                               dispatch_s)
+                               dispatch)
         self.iter_ += 1
         if not ok_prev:
             # previous iteration produced no split: unwind the
@@ -1406,11 +1453,11 @@ class GBDT:
             # score, so roll it back the way rollback_one_iter does —
             # materialize and subtract its traversal values — instead of
             # assuming the delta was zero.
-            small, shrink, probe, it, dispatch_s = self._pending_small
+            small, shrink, probe, it, dispatch = self._pending_small
             self._pending_small = None
             self._raise_if_nonfinite(probe, it)
             self.iter_ -= 1
-            tree, timing = self._fetch_tree(small, it, dispatch_s, shrink)
+            tree, timing = self._fetch_tree(small, it, dispatch, shrink)
             self._log_pass_economics(*timing)
             if tree.num_leaves > 1:
                 neg = copy.deepcopy(tree)
@@ -1425,7 +1472,7 @@ class GBDT:
             return True
         return False
 
-    def _fetch_tree(self, small, iteration, dispatch_s, shrink=None,
+    def _fetch_tree(self, small, iteration, dispatch, shrink=None,
                     fold_bias=False):
         """Device small-state -> host Tree with its shrinkage (the
         dispatch-time one where the pipelined path stashed it) and,
@@ -1433,8 +1480,8 @@ class GBDT:
         single copy every training path uses. The `device_get` is the
         host's wait for the device and has a span of its own. Returns
         the tree and the arguments of `_log_pass_economics`, a call the
-        caller makes once the tree is appended; `dispatch_s` is the host
-        seconds the tree's enqueue took and is only passed through."""
+        caller makes once the tree is appended; `dispatch` is what the
+        tree's enqueue took (`_dispatched`) and is only passed through."""
         import jax
 
         t0 = time.perf_counter()
@@ -1451,9 +1498,9 @@ class GBDT:
                     tree.add_bias(self._pending_bias)
                     self._pending_bias = 0.0
                     self.init_score_bias = 0.0
-        return tree, (host_state, dispatch_s, t1 - t0, t1)
+        return tree, (host_state, dispatch, t1 - t0, t1)
 
-    def _log_pass_economics(self, host_state, dispatch_s=0.0,
+    def _log_pass_economics(self, host_state, dispatch=(0.0, 0.0, 0.0, 0),
                             fetch_wait_s=0.0, t_fetched=None) -> None:
         """Append this tree's `telemetry.TreeRecord` to `pass_log` (read
         by the run log and by benchmarks/layer_metrics/) and feed the
@@ -1463,8 +1510,6 @@ class GBDT:
         full against compacted passes are told from `pass_rows`, which
         the fetch already carried. Called once the tree is appended:
         `build_tree_s` runs from the end of the fetch to here."""
-        if not hasattr(self, "pass_log"):
-            self.pass_log = []
         num_passes = int(host_state.num_passes)
         comm_elems = float(getattr(host_state, "comm_elems", 0.0))
         cfg = self._grower_cfg if self._dist_grower is None \
@@ -1491,9 +1536,11 @@ class GBDT:
             comm_bytes=comm_elems * 4.0,
             full_passes=full, compact_passes=compacted,
             rows_indexed=compacted * self._n_pad, rows_gathered=gathered,
-            dispatch_s=dispatch_s, fetch_wait_s=fetch_wait_s,
+            dispatch_s=dispatch[0], fetch_wait_s=fetch_wait_s,
             build_tree_s=0.0 if t_fetched is None
-            else time.perf_counter() - t_fetched)
+            else time.perf_counter() - t_fetched,
+            trace_lower_s=dispatch[1], backend_s=dispatch[2],
+            cache_misses=dispatch[3])
         self.pass_log.append(rec)
         telemetry.counter_add("tree/num_passes", rec.num_passes)
         telemetry.counter_add("tree/rows_contracted", rec.rows_contracted)
@@ -1529,10 +1576,10 @@ class GBDT:
         tree could not split (its iteration is rolled back here)."""
         if self._pending_small is None:
             return True
-        small, shrink, probe, it, dispatch_s = self._pending_small
+        small, shrink, probe, it, dispatch = self._pending_small
         self._pending_small = None
         self._raise_if_nonfinite(probe, it)
-        tree, timing = self._fetch_tree(small, it, dispatch_s, shrink,
+        tree, timing = self._fetch_tree(small, it, dispatch, shrink,
                                         fold_bias=True)
         if tree.num_leaves > 1:
             self.models.append(tree)

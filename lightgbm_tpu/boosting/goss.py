@@ -49,8 +49,8 @@ class GOSS(GBDT):
             return None
         top_k = max(1, int(n * cfg.top_rate))
         other_k = max(1, int(n * cfg.other_rate))
-        from .. import tracing
-        tracing.counter("boosting/goss_sampled_iters", 1)
+        from .. import telemetry
+        telemetry.counter_add("boosting/goss_sampled_iters", 1)
         return _goss_weights_device(
             grad, hess, cfg.bagging_seed, iter_idx,
             self.num_tree_per_iteration, n, self._n_pad, top_k, other_k)

@@ -69,7 +69,7 @@ class RF(GBDT):
         bag = self._bagging_weights(self.iter_, grad, hess)
         row_weight = self._row_weight_from_bag(bag)
 
-        from .. import tracing
+        from .. import telemetry
         from ..tree import Tree
         from ..ops.predict import predict_value_binned
         could_split_any = False
@@ -78,9 +78,9 @@ class RF(GBDT):
             mask = self._feature_mask()
             # phase spans match the base class's so RF iterations show
             # up under the same tree/grow..tree/extract accounting
-            with tracing.phase("tree/grow"):
+            with telemetry.span("tree/grow"):
                 state = self._grow(grad[cls], hess[cls], row_weight, mask)
-            with tracing.phase("tree/extract"):
+            with telemetry.span("tree/extract"):
                 tree = Tree.from_grower_state(state, self.train_data)
             if tree.num_leaves > 1:
                 could_split_any = True

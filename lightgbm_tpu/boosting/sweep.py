@@ -33,7 +33,7 @@ from typing import Any, Dict, List, Sequence
 
 import numpy as np
 
-from .. import log, tracing
+from .. import log, telemetry
 from ..config import Config, key_alias_transform
 from ..learner.grow import GrowParams
 from ..learner.schedule import subtract_cache_fits
@@ -330,7 +330,7 @@ class SweepTrainer:
             self._base_w, masks)
         self._smalls.append(small)
         self._it += 1
-        tracing.counter("sweep/iterations", 1)
+        telemetry.counter_add("sweep/iterations", 1)
 
     # ------------------------------------------------------------------
     def finish(self) -> List[Any]:
@@ -341,7 +341,7 @@ class SweepTrainer:
         import jax
 
         from ..basic import Booster
-        with tracing.phase("sweep/materialize"):
+        with telemetry.span("sweep/materialize"):
             hosts = jax.device_get(self._smalls)
         gb = self.lead
         kc = self.kc
@@ -391,7 +391,7 @@ class SweepTrainer:
             booster = Booster(params=dict(self.params_list[ki]),
                               model_str=shell.save_model_to_string())
             boosters.append(booster)
-            tracing.counter("sweep/trees", len(trees))
-        tracing.counter("sweep/models", self.num_models)
-        tracing.counter("sweep/passes", num_passes)
+            telemetry.counter_add("sweep/trees", len(trees))
+        telemetry.counter_add("sweep/models", self.num_models)
+        telemetry.counter_add("sweep/passes", num_passes)
         return boosters

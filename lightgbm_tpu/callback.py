@@ -121,13 +121,13 @@ def checkpoint(save_fn: Callable, interval: int = 1) -> Callable:
     error and must propagate."""
     def _callback(env: CallbackEnv) -> None:
         if interval > 0 and (env.iteration + 1) % interval == 0:
-            from . import tracing
+            from . import telemetry
             from .checkpoint import CheckpointError
             from .testing.faults import InjectedFault
             try:
                 # timed as its own phase: snapshots drain the async tree
                 # pipeline, so their cost must not masquerade as tree/grow
-                with tracing.phase("checkpoint/save"):
+                with telemetry.span("checkpoint/save"):
                     save_fn(env)
             except (OSError, CheckpointError, InjectedFault) as exc:
                 # deliberately NOT RuntimeError: jax backend failures

@@ -16,8 +16,6 @@ construction bit-for-bit (tests/test_ingest.py holds the matrix).
 """
 from __future__ import annotations
 
-import contextlib
-import time
 from typing import Callable, List, Optional, Sequence
 
 import numpy as np
@@ -30,30 +28,6 @@ from .landing import HostLanding
 #: feature-count floor for parallel per-feature binning inside a chunk
 _POOL_MIN_FEATURES = 4
 _POOL_MIN_ROWS = 100_000
-
-
-class _Phases:
-    """Host seconds of one build by `telemetry.DATASET_SPANS` name: a
-    `perf_counter` pair around each phase, always taken, inside the host
-    span of the same name (a no-op unless telemetry or a profiler session
-    is on). Nothing here waits for the device."""
-
-    def __init__(self):
-        self.seconds = dict.fromkeys(telemetry.DATASET_SPANS, 0.0)
-
-    @contextlib.contextmanager
-    def __call__(self, name: str):
-        t = time.perf_counter()
-        try:
-            with telemetry.span(name):
-                yield
-        finally:
-            self.seconds[name] += time.perf_counter() - t
-
-    def record(self, values: int) -> "telemetry.ConstructRecord":
-        return telemetry.ConstructRecord(
-            *(self.seconds[name] for name in telemetry.DATASET_SPANS),
-            values=int(values))
 
 
 def build_inner(source: ChunkSource, *,
@@ -90,7 +64,7 @@ def build_inner(source: ChunkSource, *,
     ds.feature_names = list(feature_names) if feature_names is not None \
         else [f"Column_{i}" for i in range(f)]
     telemetry.counter_add("ingest/builds", 1)
-    phase = _Phases()
+    phase = telemetry.Phases(telemetry.DATASET_SPANS)
 
     # ------------------------------------------------------------- pass 1
     if reference is not None:
@@ -158,7 +132,7 @@ def build_inner(source: ChunkSource, *,
     collect_raw = keep_raw and not isinstance(source, ArraySource)
     raw_blocks: List[np.ndarray] = []
     try:
-        with phase("lgbm/dataset/bin"), telemetry.span("ingest/pass2"):
+        with phase("lgbm/dataset/bin"):
             lo = 0
             for chunk, chunk_labels in source.chunks():
                 m = len(chunk)
@@ -212,7 +186,9 @@ def build_inner(source: ChunkSource, *,
         ds.metadata.set_group(group)
     if init_score is not None:
         ds.metadata.set_init_score(init_score)
-    ds.construct_record = phase.record(n * len(used))
+    ds.construct_record = telemetry.ConstructRecord(
+        *(phase.seconds[name] for name in telemetry.DATASET_SPANS),
+        values=n * len(used))
     telemetry.record_construct(ds.construct_record)
     return ds
 

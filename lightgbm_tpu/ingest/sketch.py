@@ -84,34 +84,33 @@ def sketch_pass(source: ChunkSource, *, max_bin: int,
         sample_row_indices(n, bin_construct_sample_cnt, seed))
     efb_gather = _RowGatherer(efb_sample_indices(n, efb_sample_cnt, seed))
 
-    with telemetry.span("ingest/pass1"):
-        global_lo = 0
-        for chunk, _labels in source.chunks():
-            if chunk.shape[1] != f:
-                from .. import log
-                log.fatal("Chunk at row %d has %d columns, expected %d"
-                          % (global_lo, chunk.shape[1], f))
-            if bin_gather is not None:
-                bin_gather.feed(global_lo, chunk)
-            efb_gather.feed(global_lo, chunk)
-            global_lo += len(chunk)
-            telemetry.counter_add("ingest/pass1_rows", len(chunk))
-            telemetry.counter_add("ingest/bytes", chunk.nbytes)
-            telemetry.counter_add("ingest/chunks", 1)
-        if global_lo != n:
+    global_lo = 0
+    for chunk, _labels in source.chunks():
+        if chunk.shape[1] != f:
             from .. import log
-            log.fatal("Source reported %d rows but streamed %d"
-                      % (n, global_lo))
-        if mappers is None:
-            sample = bin_gather.rows(f)
-            total = n if bin_gather.indices is None \
-                else int(len(bin_gather.indices))
-            mappers = mappers_from_sample(
-                sample, total, max_bin, min_data_in_bin, min_split_data,
-                categorical_features, use_missing, zero_as_missing)
-            del sample
-        total_sample = n if bin_gather is None or bin_gather.indices is None \
+            log.fatal("Chunk at row %d has %d columns, expected %d"
+                      % (global_lo, chunk.shape[1], f))
+        if bin_gather is not None:
+            bin_gather.feed(global_lo, chunk)
+        efb_gather.feed(global_lo, chunk)
+        global_lo += len(chunk)
+        telemetry.counter_add("ingest/pass1_rows", len(chunk))
+        telemetry.counter_add("ingest/bytes", chunk.nbytes)
+        telemetry.counter_add("ingest/chunks", 1)
+    if global_lo != n:
+        from .. import log
+        log.fatal("Source reported %d rows but streamed %d"
+                  % (n, global_lo))
+    if mappers is None:
+        sample = bin_gather.rows(f)
+        total = n if bin_gather.indices is None \
             else int(len(bin_gather.indices))
+        mappers = mappers_from_sample(
+            sample, total, max_bin, min_data_in_bin, min_split_data,
+            categorical_features, use_missing, zero_as_missing)
+        del sample
+    total_sample = n if bin_gather is None or bin_gather.indices is None \
+        else int(len(bin_gather.indices))
 
     return SketchResult(n, f, mappers, efb_gather.rows(f), total_sample)
 

@@ -47,7 +47,7 @@ import threading
 import time
 from typing import Any, Dict, Optional
 
-from .. import log, telemetry, tracing
+from .. import log, telemetry
 
 
 class ServingOverload(log.LightGBMError):
@@ -165,8 +165,8 @@ class AdmissionController:
             self.counts[kind] += 1
             self.counts["rejected"] += 1
             total = self.counts["rejected"]
-        tracing.counter("serving/" + kind, 1)
-        tracing.counter("serving/rejected", 1)
+        telemetry.counter_add("serving/" + kind, 1)
+        telemetry.counter_add("serving/rejected", 1)
         if total == 1 or total % self.EVENT_EVERY == 0:
             self._overload_event(kind, total)
         return exc
@@ -220,7 +220,7 @@ class AdmissionController:
                     reason="shed", retry_after_s=max(est, 0.001)))
         with self._lock:
             self.counts["admitted"] += 1
-        tracing.counter("serving/admitted", 1)
+        telemetry.counter_add("serving/admitted", 1)
 
     def admit_sync(self, deadline_abs: Optional[float]) -> None:
         """Admission for one synchronous predict(): in-flight cap plus
@@ -260,7 +260,7 @@ class AdmissionController:
         if refusal is not None:
             # _reject re-takes the lock, so it must run OUTSIDE it
             raise self._reject(*refusal)
-        tracing.counter("serving/admitted", 1)
+        telemetry.counter_add("serving/admitted", 1)
 
     def release_sync(self) -> None:
         with self._lock:
@@ -274,8 +274,8 @@ class AdmissionController:
             self.counts["deadline_expired"] += 1
             self.counts["rejected"] += 1
             total = self.counts["rejected"]
-        tracing.counter("serving/deadline_expired", 1)
-        tracing.counter("serving/rejected", 1)
+        telemetry.counter_add("serving/deadline_expired", 1)
+        telemetry.counter_add("serving/rejected", 1)
         if total == 1 or total % self.EVENT_EVERY == 0:
             self._overload_event("deadline_expired", total)
         over_ms = (time.perf_counter() - deadline_abs) * 1e3
@@ -415,7 +415,7 @@ class CircuitBreaker:
             self._probing = False
             self._backoff = self.reset_s
         if recovered:
-            tracing.counter("serving/breaker_recoveries", 1)
+            telemetry.counter_add("serving/breaker_recoveries", 1)
 
     def record_failure(self) -> None:
         tripped = False
@@ -438,7 +438,7 @@ class CircuitBreaker:
                     self.counts["trips"] += 1
                     tripped = True
         if tripped:
-            tracing.counter("serving/breaker_trips", 1)
+            telemetry.counter_add("serving/breaker_trips", 1)
 
     def stats(self) -> Dict[str, Any]:
         with self._lock:

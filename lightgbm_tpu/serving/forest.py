@@ -176,7 +176,7 @@ class CompiledForest:
             return self.stats["bytes"]
 
     def _get(self, key: Tuple, build: Callable[[], Any]) -> Any:
-        from .. import tracing
+        from .. import telemetry
         with self._lock:
             key = key + (self._version,)
             if self.enabled:
@@ -184,15 +184,15 @@ class CompiledForest:
                 if hit is not None:
                     self._cache.move_to_end(key)
                     self.stats["hits"] += 1
-                    tracing.counter("predict/stack_cache_hit", 1)
+                    telemetry.counter_add("predict/stack_cache_hit", 1)
                     # serving/* mirror: the hit-rate series the export
                     # surfaces next to the latency histogram
-                    tracing.counter("serving/stack_cache_hit", 1)
+                    telemetry.counter_add("serving/stack_cache_hit", 1)
                     return hit
             value = build()
             self.stats["restacks"] += 1
-            tracing.counter("predict/restack", 1)
-            tracing.counter("serving/restack", 1)
+            telemetry.counter_add("predict/restack", 1)
+            telemetry.counter_add("serving/restack", 1)
             if self.enabled:
                 self._cache[key] = value
                 self._entry_bytes[key] = _tree_bytes(value)
@@ -331,7 +331,7 @@ class SingleFlight:
     def begin(self, key, timeout: Optional[float] = None) -> bool:
         """True = caller is the leader and MUST call finish(). False =
         a leader already built the key (possibly after a wait)."""
-        from .. import tracing
+        from .. import telemetry
         deadline = None if timeout is None \
             else time.monotonic() + max(0.0, timeout)
         while True:
@@ -342,21 +342,21 @@ class SingleFlight:
                 if ev is None:
                     self._leading[key] = threading.Event()
                     self.counts["leads"] += 1
-                    tracing.counter("serving/single_flight_leads", 1)
+                    telemetry.counter_add("serving/single_flight_leads", 1)
                     return True
                 self.counts["waits"] += 1
-            tracing.counter("serving/single_flight_waits", 1)
+            telemetry.counter_add("serving/single_flight_waits", 1)
             remaining = None if deadline is None \
                 else deadline - time.monotonic()
             if remaining is not None and remaining <= 0:
                 with self._lock:
                     self.counts["expired"] += 1
-                tracing.counter("serving/single_flight_expired", 1)
+                telemetry.counter_add("serving/single_flight_expired", 1)
                 raise SingleFlightExpired(key)
             if not ev.wait(timeout=remaining):
                 with self._lock:
                     self.counts["expired"] += 1
-                tracing.counter("serving/single_flight_expired", 1)
+                telemetry.counter_add("serving/single_flight_expired", 1)
                 raise SingleFlightExpired(key)
             # woken: either the leader succeeded (key in done -> return
             # False) or it failed (loop; first caller back in becomes

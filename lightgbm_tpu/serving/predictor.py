@@ -15,7 +15,7 @@ layer adds what a serving process needs around it:
   ladder's programs persist to disk, so a RESTARTED replica's warmup
   loads them back instead of re-tracing;
 - `predict()` / `predict_one()` time every request into a latency ring
-  and tracing counters (`serving/requests`, `serving/rows`), the same
+  and telemetry counters (`serving/requests`, `serving/rows`), the same
   surface as the training-side counters;
 - `submit()` optionally coalesces concurrent single-row requests into
   one device dispatch (micro-batching): rows arriving within
@@ -43,7 +43,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from .. import log, telemetry, tracing
+from .. import log, telemetry
 from ..testing import faults
 from .admission import (AdmissionController, DeadlineExceeded,
                         PredictorShutdown, ServingOverload)
@@ -184,7 +184,7 @@ class Predictor:
             self._gbdt._quant_gate_defer = False
         self._warmup_seconds = time.perf_counter() - t0
         self._warmup_buckets = ladder
-        tracing.counter("serving/warmup_buckets", len(ladder))
+        telemetry.counter_add("serving/warmup_buckets", len(ladder))
         log.debug("Predictor warmup: %d bucket programs in %.3fs",
                   len(ladder), self._warmup_seconds)
         return {"buckets": ladder, "seconds": self._warmup_seconds}
@@ -257,8 +257,8 @@ class Predictor:
                 self._counts["requests"] += 1
                 self._counts["rows"] += int(arr.shape[0])
             self._latency_hist.observe(dt)
-            tracing.counter("serving/requests", 1)
-            tracing.counter("serving/rows", int(arr.shape[0]))
+            telemetry.counter_add("serving/requests", 1)
+            telemetry.counter_add("serving/rows", int(arr.shape[0]))
         return out
 
     def predict(self, data, deadline_ms: Optional[float] = None,
@@ -396,7 +396,7 @@ class Predictor:
                 self._counts["micro_batches"] += 1
                 self._counts["micro_rows"] += len(live)
             self._batch_hist.observe(len(live))
-            tracing.counter("serving/micro_batches", 1)
+            telemetry.counter_add("serving/micro_batches", 1)
             for i, item in enumerate(live):
                 _resolve(item.fut, res[i])
 
@@ -413,7 +413,7 @@ class Predictor:
         if len(live) == 1:
             _fail(live[0].fut, exc)
             return
-        tracing.counter("serving/batch_isolated", 1)
+        telemetry.counter_add("serving/batch_isolated", 1)
         with self._lock:
             self._counts["batch_isolated_rows"] += len(live)
         for item in live:
@@ -462,11 +462,11 @@ class Predictor:
         for item in leftovers:
             if item.fut.set_running_or_notify_cancel():
                 _fail(item.fut, PredictorShutdown())
-                tracing.counter("serving/shutdown_failed_futures", 1)
+                telemetry.counter_add("serving/shutdown_failed_futures", 1)
 
     # ------------------------------------------------------------------
     def stats(self) -> Dict[str, Any]:
-        """Counters in the same spirit as tracing's training counters:
+        """Counters in the same spirit as the training-side counters:
         request/row totals, service-lifetime latency percentiles (from
         the bucketed telemetry histogram — bucket-resolution estimates,
         not a bounded recent-window sort), throughput, admission /

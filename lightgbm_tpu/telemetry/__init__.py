@@ -1,4 +1,4 @@
-"""Unified telemetry subsystem (subsumes the old flat `tracing.py`).
+"""Unified telemetry subsystem.
 
 Six pieces, one import surface:
 
@@ -8,47 +8,55 @@ Six pieces, one import surface:
   the device. Zero-allocation when disabled.
 - `layers` — the names of this program's layers: `jax.named_scope`
   names inside the jitted programs (`scope()`), the host spans of one
-  boosting iteration and of one dataset construction, `TreeRecord`, the
-  per-tree `pass_log` entry, and `ConstructRecord`, the phases of one
-  dataset's build (its `construct_record`; `last_construct()` for the
-  one this process built last).
+  boosting iteration, of one dataset construction and of one
+  `GBDT.init`, and the records that are always taken, telemetry on or
+  off: `TreeRecord`, the per-tree `pass_log` entry; `ConstructRecord`,
+  the phases of one dataset's build (its `construct_record`;
+  `last_construct()` for the one this process built last); `InitRecord`,
+  the phases of one `GBDT.init` (the booster's `init_record`).
+  `last_run()` hands all three of the booster this process initialised
+  last to a caller that no longer holds it.
 - `devtrace` — from a profiler trace (`.xplane.pb`) to device seconds by
   scope, host seconds by span and idle gaps by span.
 - `runlog` — the structured JSONL run log: header + one record per
   boosting iteration + events + summary, written alongside PR 3's
   checkpoints so a preempted run leaves a readable trail.
-- `observer` — compile/retrace accounting hooked into `jax.monitoring`,
-  attributed to the innermost open span; warns on retrace storms.
+- `observer` — jax's compile path from `jax.monitoring`: trace, lower,
+  cache load against compile, in running totals and by program; with
+  telemetry enabled also by innermost open span; warns on retrace storms.
 - `export` — Prometheus text-exposition file dump with multihost rank
   labels and end-of-run cross-rank aggregation.
 
 Enablement: metric collection turns on via `LGBM_TPU_TIMETAG=1` /
-`LGBM_TPU_TELEMETRY=1` (the historical tracing switch), the
+`LGBM_TPU_TELEMETRY=1`, the
 `tpu_telemetry` config param, or automatically for the duration of a
-run when `tpu_telemetry_dir` is set. `lightgbm_tpu.tracing` remains as
-a thin back-compat shim over this package.
+run when `tpu_telemetry_dir` is set. A process that ends with it enabled
+logs the accumulated timers and counters (`dump()`) at exit.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+import atexit
+from typing import Any, Dict, List, Optional, Tuple
 
 from .layers import (DATASET_SPANS, INIT_SPANS, ITER_SPANS, SCOPES,
-                     ConstructRecord, TreeRecord, scope)
+                     ConstructRecord, InitRecord, Phases, TreeRecord, scope)
 from .metrics import (DEFAULT_TIME_BUCKETS, Counter, Gauge, Histogram,
-                      Registry, block, counter_add, current_site, enable,
+                      Registry, counter_add, current_site, enable,
                       enabled, gauge_set, heartbeat, observe, registry,
                       reset, set_heartbeat_file, span)
-from .observer import CompileObserver, install as install_observer, observer
+from .observer import (CompileObserver, CompileTotals, compile_path_since,
+                       install as install_observer, observer)
 from .runlog import (SCHEMA_VERSION, RunLog, TrainRecorder, read_records,
                      validate_record)
 
 __all__ = [
     "DEFAULT_TIME_BUCKETS", "Counter", "Gauge", "Histogram", "Registry",
     "RunLog", "TrainRecorder", "CompileObserver", "SCHEMA_VERSION",
-    "DATASET_SPANS", "INIT_SPANS", "ITER_SPANS", "SCOPES", "ConstructRecord",
-    "TreeRecord", "scope", "last_construct", "record_construct",
-    "active_recorder", "block", "counter_add", "current_site", "enable",
-    "enabled", "gauge_set", "heartbeat", "observe", "observer",
+    "CompileTotals", "DATASET_SPANS", "INIT_SPANS", "ITER_SPANS", "SCOPES",
+    "ConstructRecord", "InitRecord", "Phases", "TreeRecord", "scope",
+    "last_construct", "record_construct", "last_run", "record_run",
+    "active_recorder", "compile_path_since", "counter_add", "current_site",
+    "enable", "enabled", "gauge_set", "heartbeat", "observe", "observer",
     "install_observer", "registry", "reset", "read_records",
     "set_active_recorder", "set_heartbeat_file", "span",
     "start_run", "validate_record", "dump",
@@ -84,6 +92,28 @@ def record_construct(rec: ConstructRecord) -> None:
 
 def last_construct() -> Optional[ConstructRecord]:
     return _LAST_CONSTRUCT
+
+
+# the records of the booster this process initialised last (`GBDT.init`
+# writes them), for a caller that no longer holds it, as a benchmark
+# reader after the mode has freed the booster: its dataset's
+# `construct_record` (None where the dataset was not built by
+# ingest/build), its `init_record`, and its own `pass_log` list, which
+# goes on growing a `TreeRecord` a tree. Tuples of numbers: holding them
+# pins no device memory.
+_LAST_RUN: Optional[Tuple[Optional[ConstructRecord], InitRecord,
+                          List[TreeRecord]]] = None
+
+
+def record_run(construct: Optional[ConstructRecord], init: InitRecord,
+               trees: List[TreeRecord]) -> None:
+    global _LAST_RUN
+    _LAST_RUN = (construct, init, trees)
+
+
+def last_run():
+    """(construct, init, trees) of the booster initialised last, or None."""
+    return _LAST_RUN
 
 
 def start_run(gbdt, params: Dict[str, Any]) -> Optional[TrainRecorder]:
@@ -138,7 +168,7 @@ def start_run(gbdt, params: Dict[str, Any]) -> Optional[TrainRecorder]:
 
 def dump() -> None:
     """Log the accumulated phase timers + counters (the TIMETAG exit
-    printout shape; kept for tracing back-compat)."""
+    printout shape)."""
     from .. import log
     reg = registry()
     if reg.phases:
@@ -166,3 +196,11 @@ def dump() -> None:
                                 reverse=True):
             log.info("%-28s %8.3f s  x%d", site, rec["seconds"],
                      rec["compiles"])
+
+
+@atexit.register
+def _dump_at_exit() -> None:
+    """`LGBM_TPU_TIMETAG=1` (or telemetry left enabled any other way):
+    the accumulated timers and counters, once, as the process ends."""
+    if enabled():
+        dump()

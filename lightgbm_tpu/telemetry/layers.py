@@ -19,13 +19,20 @@ benchmark's per-layer readers:
 - `DATASET_SPANS` — host spans of one `Dataset` construction
   (`ingest/build.build_inner`), by phase. Their seconds are also kept,
   always, as the `ConstructRecord` on the dataset they built.
-- `INIT_SPANS` — host spans of `GBDT.init`.
+- `INIT_SPANS` — host spans of one `GBDT.init`, by phase. Their seconds
+  are also kept, always, as the `InitRecord` on the booster
+  (`GBDT.init_record`).
 - `TreeRecord` — the per-tree entry of `GBDT.pass_log`.
+
+`Phases` is how the two record-keeping layers take their seconds.
 """
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+import time
+from typing import NamedTuple, Tuple
+
+from .metrics import span
 
 SCOPES = (
     "lgbm/gradients",         # objective gradients (boosting/gbdt.py)
@@ -67,9 +74,18 @@ ITER_SPANS = (
 )
 
 INIT_SPANS = (
-    "lgbm/init/land",         # the binned matrix uploaded as one row shard
-                              # a device (tree_learner=data/voting, one
-                              # process), waited for; GBDT.land_s
+    "lgbm/init/objective",    # objective.init (label statistics; lambdarank's
+                              # pair layout), its padding, the metrics' init
+    "lgbm/init/schedule",     # row layout, the padded host matrix,
+                              # pick_schedule, grower configuration, the
+                              # distributed grower and its mesh
+    "lgbm/init/state",        # score, weights, per-row objective arrays to
+                              # their sharding, _fmeta, boost-from-average
+    "lgbm/init/land",         # the binned matrix to the device: waited for
+                              # where the rows are sharded over this
+                              # process's devices, the host seconds of the
+                              # enqueue on one device (no wait is added)
+    "lgbm/init/gate",         # _hist_quant_gate (tpu_hist_quantize only)
 )
 
 DATASET_SPANS = (
@@ -80,6 +96,43 @@ DATASET_SPANS = (
 
 PREFIX = "lgbm/"
 UNSCOPED = "unscoped"
+
+
+class Phases:
+    """Host seconds of one piece of set-up by span name: a `perf_counter`
+    pair around each phase, always taken, inside the host span of the
+    same name (a no-op unless telemetry or a profiler session is on).
+    Nothing here waits for the device. A name may be entered more than
+    once: its seconds add up.
+
+    `phase(name)` closes the phase that is open and opens `name`; leaving
+    a `with` block of the object closes the open one. So `with
+    phase(name):` times a block, and a long function told in consecutive
+    stretches calls `phase(name)` at the head of each, inside one `with
+    Phases(names) as phase:`."""
+
+    def __init__(self, names: Tuple[str, ...]):
+        self.seconds = dict.fromkeys(names, 0.0)
+        self._open = None           # (name, span, perf_counter at entry)
+
+    def __call__(self, name: str) -> "Phases":
+        self.__exit__(None, None, None)
+        if name not in self.seconds:
+            raise KeyError(name)
+        cm = span(name)
+        cm.__enter__()
+        self._open = (name, cm, time.perf_counter())
+        return self
+
+    def __enter__(self) -> "Phases":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._open is not None:
+            name, cm, t = self._open
+            self._open = None
+            cm.__exit__(*exc)
+            self.seconds[name] += time.perf_counter() - t
 
 
 class scope:
@@ -131,6 +184,12 @@ class TreeRecord(NamedTuple):
     dispatch_s: float = 0.0     # train_one_iter entry -> grow enqueue returned
     fetch_wait_s: float = 0.0   # the device_get of this tree's small state
     build_tree_s: float = 0.0   # end of the fetch -> tree appended
+    # jax's compile path inside the interval `dispatch_s` times, from
+    # `observer().totals()`: 0 where no observer is installed and in
+    # every tree after the programs exist
+    trace_lower_s: float = 0.0  # Python trace to jaxpr + lowering to MLIR
+    backend_s: float = 0.0      # XLA compile, or the persistent cache's load
+    cache_misses: int = 0       # programs compiled and written to the cache
 
 
 class ConstructRecord(NamedTuple):
@@ -144,6 +203,38 @@ class ConstructRecord(NamedTuple):
     groups_s: float
     bin_s: float
     values: int                 # rows x used columns, what pass 2 binned
+
+
+class InitRecord(NamedTuple):
+    """Host seconds of one `GBDT.init` by phase, one field per name of
+    `INIT_SPANS` in its order: `perf_counter` differences that are always
+    taken and never wait for the device, but for `land_s` where the rows
+    are sharded (that upload is waited for, record or no record). With
+    them what `init` decided about the rows and what jax's compile path
+    did meanwhile (`observer().totals()` over the interval of `init`; 0
+    where no observer is installed). It is the `init_record` of the
+    booster it tells of."""
+    objective_s: float
+    schedule_s: float
+    state_s: float
+    land_s: float
+    gate_s: float
+    total_s: float              # entry to return of init
+    rows: int                   # this process's real rows
+    binned_bytes: int           # the binned matrix as it sits on the device(s)
+    shard_rows: Tuple[int, ...]  # real rows a device's shard holds
+    trace_lower_s: float = 0.0
+    backend_s: float = 0.0
+    cache_hits: int = 0
+    cache_misses: int = 0
+
+
+def shard_rows(rows: int, rows_padded: int, shards: int) -> Tuple[int, ...]:
+    """Real rows in each of `shards` equal blocks of a row axis whose
+    padding is one suffix (what `GBDT.init`'s `_pad_to` makes)."""
+    block = rows_padded // max(1, shards)
+    return tuple(max(0, min(block, rows - i * block))
+                 for i in range(max(1, shards)))
 
 
 def split_passes(pass_rows, num_passes: int, cap: int):

@@ -43,8 +43,8 @@ def _label_key(labels: Optional[Dict[str, Any]]) -> LabelItems:
 
 class Counter:
     """Monotonic accumulator. `value` is the accumulated total, `events`
-    the number of inc() calls (the (value, count) pair tracing.counters()
-    always reported)."""
+    the number of inc() calls (the (value, count) pair the exit dump
+    reports)."""
 
     __slots__ = ("name", "labels", "value", "events")
 
@@ -146,8 +146,8 @@ class Histogram:
 
 
 class _PhaseAccum:
-    """Span-timer accumulator: total seconds + span count per name (the
-    shape tracing.totals() always reported)."""
+    """Span-timer accumulator: total seconds + span count per name (what
+    the exit dump and the run log report)."""
 
     __slots__ = ("total", "count")
 
@@ -352,23 +352,13 @@ def span(name: str, **ids):
     lands in the profiler's trace as a `TraceAnnotation` carrying `ids`
     (e.g. `iteration=i`: spans of one tree share it) when a profiler
     session is open; it accumulates host seconds into the registry
-    (tracing.phase semantics) only when telemetry is enabled. With both
+    (`registry().phases`) only when telemetry is enabled. With both
     off it is the shared no-op singleton."""
     if _enabled:
         return _Span(name, ids)
     if TraceAnnotation.is_enabled():
         return TraceAnnotation(name, **ids)
     return _NULL_SPAN
-
-
-def block(x):
-    """block_until_ready when telemetry is enabled, for scripts that time
-    a device result by hand. Never called under a span of the training
-    path: that would serialise the pipelined loop it measures."""
-    if _enabled and x is not None:
-        import jax
-        jax.block_until_ready(x)
-    return x
 
 
 # ---------------------------------------------------------------------------

@@ -5,8 +5,8 @@ this sink makes every run leave one. A `RunLog` appends self-contained
 JSON records to `<tpu_telemetry_dir>/runlog_r<rank>.jsonl`:
 
 - one `header` record per run start (config fingerprint, device
-  topology, schedule, library versions) — a resumed run appends a new
-  header, so the file reads as the full preemption history;
+  topology, schedule, `init_record`, library versions) — a resumed run
+  appends a new header, so the file reads as the full preemption history;
 - one `iteration` record per boosting iteration: eval metric values,
   per-phase wall deltas, counter deltas (pass economics
   `rows_contracted`/`pass_rows`, bagging/DART activity), compile-event
@@ -213,6 +213,7 @@ class TrainRecorder:
             key: (c.value, c.events) for key, c in reg.counters.items()}
         self._compile_prev = _observer().snapshot()
         if run_log is not None:
+            init_record = getattr(gbdt, "init_record", None)
             run_log.write({
                 "type": "header", "schema": SCHEMA_VERSION,
                 "rank": rank, "world": world, "run_id": self.run_id,
@@ -221,6 +222,9 @@ class TrainRecorder:
                 "versions": _versions(),
                 "params": {str(k): str(v) for k, v in params.items()},
                 "schedule": dict(getattr(gbdt, "_schedule_info", {}) or {}),
+                # GBDT.init by phase, the rows a shard holds, what was
+                # traced, loaded and compiled meanwhile (InitRecord)
+                "init_record": init_record._asdict() if init_record else {},
                 "boosting": gbdt.model_name(),
                 "num_data": int(getattr(gbdt, "_n", 0)),
                 "start_iteration": int(getattr(gbdt, "iter_", 0)),

@@ -23,7 +23,6 @@ the scope on a new libtpu (`devtrace.SCOPE_STATS`).
 from __future__ import annotations
 
 import argparse
-import contextlib
 import json
 import os
 import shutil
@@ -68,8 +67,6 @@ def main(argv=None) -> int:
                     metavar="KEY=VALUE", help="override one parameter of "
                     "the configuration (an experiment, not the cell)")
     ap.add_argument("--name", default="profile_train")
-    ap.add_argument("--trace-init", action="store_true",
-                    help="also trace Booster init, for its host spans")
     ap.add_argument("--keep-trace", action="store_true",
                     help="copy the .xplane.pb beside the JSON")
     args = ap.parse_args(argv)
@@ -79,6 +76,9 @@ def main(argv=None) -> int:
     import lightgbm_tpu as lgb
     from lightgbm_tpu import telemetry
     from lightgbm_tpu.telemetry import devtrace
+
+    # the compile path's totals feed InitRecord and TreeRecord
+    telemetry.install_observer()
 
     # jax's persistent compile cache keys a program WITHOUT its metadata,
     # so an executable cached before a scope was added or renamed would be
@@ -102,34 +102,23 @@ def main(argv=None) -> int:
     ds = lgb.Dataset(X, y, group=group[0] if group else None,
                      params=dict(config["params"])).construct()
     construct_host_s = time.perf_counter() - t
-    # with `--trace-init` traced too, in a trace of its own: `GBDT.init`'s
-    # host spans (`lgbm/init/land`) are set-up and not in the iterations'
-    # trace. Off by default: the profiler's Python tracer stretched this
-    # step from ~10 s to 187 s at 84M rows (TPU v5e host, PR 32)
-    init_dir = tempfile.mkdtemp(prefix="profile_init_") \
-        if args.trace_init else None
-    init_spans = {}
     t = time.perf_counter()
-    try:
-        with (jax.profiler.trace(init_dir) if init_dir
-              else contextlib.nullcontext()):
-            booster = lgb.Booster(dict(config["params"]), ds)
-            inner = booster._inner
-            jax.block_until_ready(inner._binned)
-        if init_dir:
-            init_spans = devtrace.host_spans(devtrace.newest_xplane(init_dir))
-    finally:
-        if init_dir:
-            shutil.rmtree(init_dir, ignore_errors=True)
-    # `land_s`: of those seconds, the upload of the binned matrix as one
-    # row shard a device (0 where the rows are on one device)
+    booster = lgb.Booster(dict(config["params"]), ds)
+    inner = booster._inner
+    jax.block_until_ready(inner._binned)
     setup = dict(ds._lazy_init().construct_record._asdict(),
                  construct_host_s=construct_host_s,
-                 booster_to_device_s=time.perf_counter() - t,
-                 land_s=getattr(inner, "land_s", 0.0), **init_spans)
-    print("set-up, host seconds: " + ", ".join(
-        f"{k} {v:.2f}" if k != "values" else f"{v} values"
-        for k, v in setup.items()), flush=True)
+                 booster_to_device_s=time.perf_counter() - t)
+
+    def line(title, fields):
+        print(title + ": " + ", ".join(
+            f"{k} {v:.2f}" if isinstance(v, float) else f"{k} {v}"
+            for k, v in fields.items()), flush=True)
+
+    # `GBDT.init` by phase, the rows a device's shard holds and what jax
+    # traced, loaded and compiled meanwhile: the booster's own InitRecord
+    line("set-up, host seconds", setup)
+    line("set-up, GBDT.init", inner.init_record._asdict())
 
     def drain():
         booster.current_iteration()       # flushes the pipelined tree
@@ -138,6 +127,18 @@ def main(argv=None) -> int:
     for _ in range(args.warmup):
         booster.update()
     drain()
+    # the warm-up trees hold the tracing, the cache loads and the first
+    # run of each program: their TreeRecords' host and compile-path fields
+    for i, rec in enumerate(inner.pass_log[:args.warmup]):
+        line(f"set-up, warm-up tree {i}", {
+            k: getattr(rec, k) for k in (
+                "dispatch_s", "fetch_wait_s", "build_tree_s",
+                "trace_lower_s", "backend_s", "cache_misses")})
+    # which programs the set-up's compile path went to, by what each cost
+    programs = telemetry.observer().snapshot()["programs"]
+    for name in sorted(programs, key=lambda p: -sum(
+            programs[p][k] for k in ("trace_s", "lower_s", "backend_s")))[:6]:
+        line(f"set-up, program {name}", programs[name])
     trace_dir = tempfile.mkdtemp(prefix="profile_train_")
     try:
         with jax.profiler.trace(trace_dir):
@@ -166,6 +167,9 @@ def main(argv=None) -> int:
         # the dataset layer's host phases (set-up, not in the trace) and
         # the schedule the program picked for this shape
         "construct": setup,
+        "init": inner.init_record._asdict(),
+        "warmup_trees": [r._asdict() for r in inner.pass_log[:args.warmup]],
+        "programs": programs,
         "schedule": {k: v for k, v in inner._schedule_info.items()
                      if k != "grower"},
     })

@@ -176,7 +176,7 @@ def test_cache_skips_passes_and_counts_hit(tmp_path):
         assert counters.get("ingest/cache_hit") == 1
         assert "ingest/chunks" not in counters  # no pass streamed
         assert not any(name in reg.phases
-                       for name in ("ingest/pass1", "ingest/pass2"))
+                       for name in telemetry.DATASET_SPANS)
         np.testing.assert_array_equal(np.asarray(loaded.binned),
                                       inner.binned)
     finally:

@@ -441,7 +441,7 @@ def test_benchmark_json_names_the_configuration_the_cell_and_the_readers():
     assert [m["name"] for m in mine] == [
         "gradients.device_share", "gradients.pair_slots_per_row",
         "gradients.roofline_share"] == [m["name"]
-                                        for m in bench["per_layer"][-3:]]
+                                        for m in bench["per_layer"][14:17]]
     for m in mine:
         assert (m["layer"], m["moves"], m["workloads"]) == (
             "gradients", "train_mrow_iters_per_s", [CELL])

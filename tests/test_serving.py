@@ -345,21 +345,23 @@ def test_zero_tree_and_empty_input(base):
 
 
 def test_tracing_counters_surfaced():
-    from lightgbm_tpu import tracing
+    from lightgbm_tpu import telemetry
     X, y = _make()
     b = _train(X, y)
-    tracing.enable(True)
-    tracing.reset()
+    telemetry.enable(True)
+    telemetry.reset()
     try:
         b.predict(X)
         b.predict(X)
-        counters = tracing.counters()
-        assert counters.get("predict/restack", (0, 0))[0] == 1
-        assert counters.get("predict/stack_cache_hit", (0, 0))[0] == 1
-        assert counters.get("predict/chunks", (0, 0))[0] == 2
+        counters = {c.name: c.value
+                    for c in telemetry.registry().counters.values()
+                    if not c.labels}
+        assert counters.get("predict/restack") == 1
+        assert counters.get("predict/stack_cache_hit") == 1
+        assert counters.get("predict/chunks") == 2
     finally:
-        tracing.enable(False)
-        tracing.reset()
+        telemetry.enable(False)
+        telemetry.reset()
 
 
 # ---------------------------------------------------------------------------

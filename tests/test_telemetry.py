@@ -1,6 +1,5 @@
 """Unified telemetry subsystem tests (lightgbm_tpu/telemetry/):
-registry semantics, run-log schema round-trip, tracing shim
-back-compat, the disabled-path zero-allocation contract, and the
+registry semantics, run-log schema round-trip, the disabled-path zero-allocation contract, and the
 compile/retrace observer."""
 import json
 import os
@@ -9,7 +8,7 @@ import tracemalloc
 import numpy as np
 import pytest
 
-from lightgbm_tpu import telemetry, tracing
+from lightgbm_tpu import telemetry
 from lightgbm_tpu.telemetry import export as telemetry_export
 from lightgbm_tpu.telemetry import metrics as telemetry_metrics
 
@@ -114,36 +113,6 @@ def test_disabled_path_allocates_nothing():
     reg = telemetry.registry()
     assert not reg.counters and not reg.phases \
         and not reg.gauges and not reg.histograms
-
-
-# ---------------------------------------------------------------------------
-# tracing shim back-compat
-# ---------------------------------------------------------------------------
-def test_tracing_shim_phase_counter_totals_dump(clean_registry):
-    with tracing.phase("boosting/test_phase"):
-        pass
-    tracing.counter("test/counter", 2.0)
-    tracing.counter("test/counter", 3.0)
-    totals = tracing.totals()
-    assert totals["boosting/test_phase"][1] == 1
-    assert tracing.counters()["test/counter"] == (5.0, 2)
-    tracing.dump()  # must not raise
-    tracing.reset()
-    assert tracing.totals() == {} and tracing.counters() == {}
-
-
-def test_tracing_shim_enable_roundtrip():
-    tracing.enable(True)
-    assert tracing.enabled() and telemetry.enabled()
-    tracing.enable(False)
-    assert not tracing.enabled() and not telemetry.enabled()
-
-
-def test_tracing_block_passthrough(clean_registry):
-    import jax.numpy as jnp
-    x = jnp.ones(4)
-    assert tracing.block(x) is x
-    assert tracing.block(None) is None
 
 
 # ---------------------------------------------------------------------------

@@ -480,8 +480,7 @@ def test_construct_record_accounts_for_construct():
 
 
 def test_dataset_spans_land_in_the_profiler_trace(tmp_path):
-    """Telemetry off: a profiler session alone turns the spans on, and the
-    old names (`ingest/pass1`, `ingest/pass2`) still arrive beside them."""
+    """Telemetry off: a profiler session alone turns the spans on."""
     import glob
 
     import jax
@@ -495,7 +494,7 @@ def test_dataset_spans_land_in_the_profiler_trace(tmp_path):
     names = {ev.name for plane in ProfileData.from_file(path).planes
              for line in plane.lines for ev in line.events}
     assert set(telemetry.DATASET_SPANS) <= names
-    assert {"ingest/pass1", "ingest/pass2"} <= names
+    assert not {"ingest/pass1", "ingest/pass2"} & names   # gone, PR 36
     # and with telemetry on the same names accumulate host seconds
     telemetry.enable(True)
     try:
@@ -503,7 +502,6 @@ def test_dataset_spans_land_in_the_profiler_trace(tmp_path):
         lgb.Dataset(X, (X[:, 0] > 0).astype(np.float32)).construct()
         phases = telemetry.registry().phases
         assert set(telemetry.DATASET_SPANS) <= set(phases)
-        assert "ingest/pass2" in phases
     finally:
         telemetry.reset()
         telemetry.enable(False)
@@ -573,7 +571,7 @@ def test_benchmark_json_names_the_cell_and_its_readers():
         # with a list of cells came with higgs-train-dp4 (the merge) and
         # msltr-rank-1chip (the ranking gradients)
         assert ("workloads" in m) == (
-            m["name"] == "merge.comm_mb_per_tree"
+            m["name"].startswith("merge.")
             or m["name"].startswith("gradients."))
         assert os.path.isfile(os.path.join(BENCH, "layer_metrics",
                                            m["name"] + ".py"))
