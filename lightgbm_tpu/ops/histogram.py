@@ -47,15 +47,22 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..telemetry.layers import scope
 
 
 def _hi_lo(w):
-    """Split f32 into two bf16s with hi+lo ~= w to f32 precision."""
-    hi = w.astype(jnp.bfloat16)
-    lo = (w - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    return hi, lo
+    """Split f32 into two bf16s with hi+lo ~= w to f32 precision.
+
+    The rounding to bf16 is a `reduce_precision`, which no compiler pass
+    may drop. Written as `w - w.astype(bf16).astype(f32)` the round trip
+    is a convert pair that XLA:TPU elides when both converts land in one
+    fusion (it keeps the f32 it has: "excess precision"), and lo comes
+    out 0: the gathered kernel on the chip did that once the operand was
+    built another way (PR 37), and its histograms read like bf16 alone."""
+    hi = jax.lax.reduce_precision(w, exponent_bits=8, mantissa_bits=7)
+    return hi.astype(jnp.bfloat16), (w - hi).astype(jnp.bfloat16)
 
 
 # ---------------------------------------------------------------------------
@@ -229,11 +236,11 @@ def _contract_block_parts(get_block, blocks, num_bins, u, bf16):
     return tuple(parts)
 
 
-def _contract_blocks(binned, row0, chunk, blocks, num_bins, u, bf16):
-    return _contract_block_parts(
-        lambda gs, gc: jax.lax.dynamic_slice(binned, (row0, gs),
-                                             (chunk, gc)),
-        blocks, num_bins, u, bf16)
+def _chunk_blocks(binned, c, chunk):
+    """get_block of the full-pass kernels: chunk c's rows of a group
+    block, a dynamic slice of the resident bin matrix."""
+    return lambda gs, gc: jax.lax.dynamic_slice(binned, (c * chunk, gs),
+                                                (chunk, gc))
 
 
 def _blocks_zeros(blocks, num_bins, s, dtype=jnp.float32):
@@ -283,51 +290,83 @@ def _accumulate_chunks(one, n_chunks, blocks, num_bins, s, n_valid, chunk,
         jax.lax.fori_loop(0, trip, body, init), num_bins)
 
 
-def _quant_s(quantize: str, c_ids: int = 1) -> int:
-    """Live channel count per id under a quantized mode: int8 contracts
-    (g, h, cnt) directly; int16 adds the two lo-digit channels in the
-    same slots the bf16 hi+lo layout uses."""
-    return c_ids * (5 if quantize == "int16" else 3)
+def _channels(w_chunk, bf16, quantize):
+    """A chunk's [chunk, 3] weight channels as the contraction's (base, lo)
+    column blocks; lo is None where the path has no correction channels.
 
-
-def _quant_u(w_chunk, quantize, member=None):
-    """Channel matrix for a quantized chunk, already bf16 (exact: every
-    entry is an integer of magnitude <= 128 for int16 digits, <= 127 for
-    int8). Layout matches the bf16 hi+lo path — [g_hi, h_hi, cnt,
-    g_lo, h_lo] per id for int16 (the count channel is a raw 0/1, never
-    digit-split), [g, h, cnt] for int8 — so the post-loop merge reuses
-    the same slot arithmetic with *256 instead of +."""
+    bf16: the hi and lo bf16 halves of (g, h, cnt); the count channel is
+    0/1 = bf16-exact, so only grad/hess carry a lo half. float32: the
+    channels as they are. Quantized, already bf16 (exact: every entry is
+    an integer of magnitude <= 128 for int16 digits, <= 127 for int8):
+    int16 is [g_hi, h_hi, cnt] + [g_lo, h_lo] (the count channel is a
+    raw 0/1, never digit-split), int8 is [g, h, cnt] alone — the bf16
+    hi+lo slots, so the post-loop merge reuses the same slot arithmetic
+    with *256 instead of +."""
     if quantize == "int16":
         hi, lo = _digits(w_chunk[:, 0:2])
         base = jnp.concatenate([hi, w_chunk[:, 2:3]], axis=1)
-    else:
-        base, lo = w_chunk, None
-    if member is None:
-        u = base if lo is None else jnp.concatenate([base, lo], axis=1)
-        return u.astype(jnp.bfloat16)
-    c_ids = member.shape[1]
-    mb = member[:, :, None].astype(jnp.bfloat16)
-    u = (mb * base.astype(jnp.bfloat16)[:, None, :]).reshape(-1, c_ids * 3)
-    if lo is not None:
-        u_lo = (mb[:, :, 0:2] * lo.astype(jnp.bfloat16)[:, None, :]
-                ).reshape(-1, c_ids * 2)
-        u = jnp.concatenate([u, u_lo], axis=1)
-    return u
+        return base.astype(jnp.bfloat16), lo.astype(jnp.bfloat16)
+    if quantize == "int8":
+        return w_chunk.astype(jnp.bfloat16), None
+    if bf16:
+        hi, lo = _hi_lo(w_chunk)
+        return hi, lo[:, 0:2]
+    return w_chunk, None
 
 
-def _quant_merge(hist, quantize, f, num_bins, c_ids=None):
-    """Recombine int16 digit channels after the int32 accumulation:
-    value = hi * 256 + lo (exact in int32 — train_qmax caps the per-row
-    magnitude so the worst-case carry fits). int8 has no digit channels."""
-    if quantize != "int16":
-        return hist
-    if c_ids is None:
-        return hist[:, :, 0:3].at[:, :, 0:2].set(
-            hist[:, :, 0:2] * 256 + hist[:, :, 3:5])
-    main = hist[:, :, :c_ids * 3].reshape(f, num_bins, c_ids, 3)
-    corr = hist[:, :, c_ids * 3:].reshape(f, num_bins, c_ids, 2)
-    return (main.at[:, :, :, 0:2].set(main[:, :, :, 0:2] * 256 + corr)
-            .reshape(f, num_bins, c_ids * 3))
+def _n_lo(bf16, quantize):
+    """Correction channels an id: the two lo halves (digits) of grad and
+    hess in the bf16 hi+lo and int16 paths, none in float32 and int8."""
+    return 2 if (quantize == "int16" or (quantize == "none" and bf16)) else 0
+
+
+def _channel_operand(lid, ids, base, lo=None):
+    """The contraction's channel operand u [chunk, S] for a chunk whose
+    rows carry leaf labels `lid`: column (k, c) holds channel c of the
+    rows labelled ids[k] and 0 elsewhere, in two blocks, [id0: c0 c1 c2,
+    id1: ...] of `base` then [id0: lo0 lo1, id1: ...] of `lo` (the order
+    `_fold_lo` reads).
+
+    Each block is built AT [chunk, 3K] (or 2K): a compare of the labels
+    against the ids repeated over their channels, a select chain over
+    the row's channel columns by the column's static channel number —
+    nothing chunk-sized of rank 3 is written out and relaid into the
+    matmul's columns (batched_leaves_histogram's fourth design choice).
+    The barrier holds the operand as a buffer of its own: without it
+    XLA:TPU takes the whole producer INTO every matmul fusion of the
+    chunk (2 at 28 features, 16 at 2000), which redo it once each."""
+    with scope("lgbm/hist/operand"):
+        zero = jnp.zeros((), base.dtype)
+
+        def block(channels):
+            n_c = channels.shape[1]
+            chan = np.arange(ids.shape[0] * n_c) % n_c   # static, [K * n_c]
+            val = channels[:, 0:1]
+            for c in range(1, n_c):
+                val = jnp.where(chan[None, :] == c, channels[:, c:c + 1], val)
+            hit = lid[:, None] == jnp.repeat(ids, n_c)[None, :]
+            return jnp.where(hit, val, zero)
+
+        u = block(base) if lo is None else \
+            jnp.concatenate([block(base), block(lo)], axis=1)
+        return jax.lax.optimization_barrier(u)
+
+
+def _fold_lo(hist, c_ids: int, quantize: str):
+    """After the chunk loop: [F, B, S] in the operand's columns ->
+    [F, B, c_ids, 3], the correction block (if the path has one) folded
+    into grad and hess: hi + lo (the bf16 halves, f32) or hi * 256 + lo
+    (the int16 digits; exact in int32 — train_qmax caps the per-row
+    magnitude so the worst-case carry fits)."""
+    f, b, s = hist.shape
+    main = hist[:, :, :c_ids * 3].reshape(f, b, c_ids, 3)
+    if s == c_ids * 3:
+        return main
+    hi = main[:, :, :, 0:2]
+    if quantize == "int16":
+        hi = hi * 256
+    corr = hist[:, :, c_ids * 3:].reshape(f, b, c_ids, 2)
+    return main.at[:, :, :, 0:2].set(hi + corr)
 
 
 @functools.partial(jax.jit, static_argnames=("num_bins", "chunk", "bf16",
@@ -373,30 +412,45 @@ def leaf_histogram(binned: jnp.ndarray, weights: jnp.ndarray,
     n_chunks = n // chunk
     widths = group_widths if group_widths else (num_bins,) * f
     blocks = plan_group_blocks(widths, chunk)
-    s = _quant_s(quantize) if q else (5 if bf16 else 3)
 
     def one(c):
         w_chunk = jax.lax.dynamic_slice(weights, (c * chunk, 0), (chunk, 3))
-        if q:
-            u = _quant_u(w_chunk, quantize)
-        elif bf16:
-            hi, lo = _hi_lo(w_chunk)
-            # count channel is 0/1 = bf16-exact, so only grad/hess need
-            # the lo correction: S = 3 hi + 2 lo
-            u = jnp.concatenate([hi, lo[:, 0:2]], axis=1)
-        else:
-            u = w_chunk
-        return _contract_blocks(binned, c * chunk, chunk, blocks,
-                                num_bins, u, bf16 or q)
+        base, lo = _channels(w_chunk, bf16, quantize)
+        u = base if lo is None else jnp.concatenate([base, lo], axis=1)
+        return _contract_block_parts(_chunk_blocks(binned, c, chunk),
+                                     blocks, num_bins, u, bf16 or q)
 
-    hist = _accumulate_chunks(one, n_chunks, blocks, num_bins, s,
+    hist = _accumulate_chunks(one, n_chunks, blocks, num_bins,
+                              3 + _n_lo(bf16, quantize), n_valid, chunk,
+                              dtype=jnp.int32 if q else jnp.float32)
+    return _fold_lo(hist, 1, quantize)[:, :, 0, :]
+
+
+def _leaves_histogram(rows_of, ids, n_chunks, f, num_bins, chunk, bf16,
+                      n_valid, group_widths, quantize):
+    """What the full-pass and the gathered kernel share: the chunk loop
+    over `rows_of(c)` -> (get_block, w_chunk, lid), a chunk's bin slices
+    by group block, its [chunk, 3] channels (all zero on a row that must
+    not count) and its leaf labels; the channel operand of the ids, the
+    block contraction, and the fold of the correction columns. Returns
+    [C, F, B, 3]."""
+    q = quantize != "none"
+    c_ids = ids.shape[0]
+    widths = group_widths if group_widths else (num_bins,) * f
+    blocks = plan_group_blocks(widths, chunk)
+
+    def one(c):
+        get_block, w_chunk, lid = rows_of(c)
+        base, lo = _channels(w_chunk, bf16, quantize)
+        u = _channel_operand(lid, ids, base, lo)
+        return _contract_block_parts(get_block, blocks, num_bins, u,
+                                     bf16 or q)
+
+    hist = _accumulate_chunks(one, n_chunks, blocks, num_bins,
+                              c_ids * (3 + _n_lo(bf16, quantize)),
                               n_valid, chunk,
                               dtype=jnp.int32 if q else jnp.float32)
-    if q:
-        return _quant_merge(hist, quantize, f, num_bins)
-    if bf16:
-        hist = hist[:, :, 0:3].at[:, :, 0:2].add(hist[:, :, 3:5])
-    return hist
+    return _fold_lo(hist, c_ids, quantize).transpose(2, 0, 1, 3)
 
 
 @functools.partial(jax.jit,
@@ -415,7 +469,8 @@ def batched_leaves_histogram(binned: jnp.ndarray, weights: jnp.ndarray,
     ids BEFORE building their histograms, so membership is a direct
     `leaf_id == ids[k]` compare — no split bit. Returns [C, F, B, 3].
 
-    Three deliberate design choices, the first two profiled on hardware:
+    Four deliberate design choices, all but the third profiled on
+    hardware:
     - rows are walked with `lax.dynamic_slice` chunks instead of an
       upfront reshape to [n_chunks, chunk, F]: the reshape forced XLA to
       materialize two layout copies of the whole bin matrix per pass
@@ -428,48 +483,37 @@ def batched_leaves_histogram(binned: jnp.ndarray, weights: jnp.ndarray,
       blocks (plan_group_blocks), each scanned at its own bin width —
       the row chunk no longer shrinks with G*B, and <=16-bin features
       get the reference 4-bit path's cost discount
-      (src/io/dense_nbits_bin.hpp:1-405).
+      (src/io/dense_nbits_bin.hpp:1-405);
+    - the channel operand u [chunk, C*5] is built AT that shape
+      (`_channel_operand`: a compare against the ids repeated over
+      their channels, a select chain over the row's channel columns),
+      not as [chunk, C, 3] and [chunk, C, 2] products reshaped into the
+      matmul's columns: XLA:TPU wrote each product out (three channels
+      padded to four) and relaid it, four copy operations a chunk that
+      were a fifth of a 28-feature pass (0.599 of 2.878 s of device
+      time a pair of trees at 21M x 28; TPU v5e, PR 37, `PERF.md`
+      section 6). Read alone at (25.2M x 28, 24 ids) / (12.6M x 137, 8
+      ids) / (1M x 2000, 8 ids), ms a pass: the old form 74.8 / 107.1 /
+      125.8, this one 61 / 104 / 126. What lost, so nobody tries
+      it again: the same compare-and-select WITHOUT the barrier (66.8 /
+      115.5 / 138.7: fused into every matmul fusion and redone there);
+      widening by two constant 0/1 matmuls (67.5 / 104.8); CHANNEL-major
+      columns (five [chunk, C] selects concatenated), the fastest alone
+      (60.6 / 104.0 / 125.4) and cheapest to build, but in the grow
+      program its [F][C][K][B] accumulators make XLA re-lay the whole
+      subtraction cache every pass (0.74 s a pair at 2000 features).
     """
     n, f = binned.shape
     if n % chunk != 0:
         raise ValueError(f"rows ({n}) must be padded to a multiple of chunk ({chunk})")
-    q = quantize != "none"
-    c_ids = ids.shape[0]
-    n_chunks = n // chunk
-    widths = group_widths if group_widths else (num_bins,) * f
-    blocks = plan_group_blocks(widths, chunk)
-    s = _quant_s(quantize, c_ids) if q else \
-        (c_ids * 5 if bf16 else c_ids * 3)
 
-    def one(c):
+    def rows_of(c):
         w_chunk = jax.lax.dynamic_slice(weights, (c * chunk, 0), (chunk, 3))
         lid = jax.lax.dynamic_slice(leaf_id, (c * chunk,), (chunk,))
-        member = lid[:, None] == ids[None, :]                  # [C, K]
-        if q:
-            u = _quant_u(w_chunk, quantize, member)
-        elif bf16:
-            hi, lo = _hi_lo(w_chunk)
-            mb = member[:, :, None].astype(jnp.bfloat16)
-            u_hi = (mb * hi[:, None, :]).reshape(chunk, c_ids * 3)
-            u_lo = (mb[:, :, 0:2] * lo[:, None, 0:2]).reshape(chunk, c_ids * 2)
-            u = jnp.concatenate([u_hi, u_lo], axis=1)
-        else:
-            u = (member[:, :, None].astype(jnp.float32)
-                 * w_chunk[:, None, :]).reshape(chunk, c_ids * 3)
-        return _contract_blocks(binned, c * chunk, chunk, blocks,
-                                num_bins, u, bf16 or q)
+        return _chunk_blocks(binned, c, chunk), w_chunk, lid
 
-    hist = _accumulate_chunks(one, n_chunks, blocks, num_bins, s,
-                              n_valid, chunk,
-                              dtype=jnp.int32 if q else jnp.float32)
-    if q:
-        hist = _quant_merge(hist, quantize, f, num_bins, c_ids)
-    elif bf16:
-        main = hist[:, :, :c_ids * 3].reshape(f, num_bins, c_ids, 3)
-        corr = hist[:, :, c_ids * 3:].reshape(f, num_bins, c_ids, 2)
-        hist = (main.at[:, :, :, 0:2].add(corr)
-                .reshape(f, num_bins, c_ids * 3))
-    return hist.reshape(f, num_bins, c_ids, 3).transpose(2, 0, 1, 3)
+    return _leaves_histogram(rows_of, ids, n // chunk, f, num_bins, chunk,
+                             bf16, n_valid, group_widths, quantize)
 
 
 @functools.partial(jax.jit,
@@ -510,17 +554,10 @@ def gathered_leaves_histogram(binned: jnp.ndarray, weights: jnp.ndarray,
     if cap % chunk != 0:
         raise ValueError(
             f"row buffer ({cap}) must be a multiple of chunk ({chunk})")
-    q = quantize != "none"
-    c_ids = ids.shape[0]
-    n_chunks = cap // chunk
-    widths = group_widths if group_widths else (num_bins,) * f
-    blocks = plan_group_blocks(widths, chunk)
-    s = _quant_s(quantize, c_ids) if q else \
-        (c_ids * 5 if bf16 else c_ids * 3)
     nv = jnp.int32(cap) if n_valid is None else \
         jnp.minimum(jnp.asarray(n_valid, jnp.int32), cap)
 
-    def one(c):
+    def rows_of(c):
         r = jax.lax.dynamic_slice(rows, (c * chunk,), (chunk,))
         live = (c * chunk + jnp.arange(chunk, dtype=jnp.int32)) < nv
         def take(a):
@@ -529,36 +566,11 @@ def gathered_leaves_histogram(binned: jnp.ndarray, weights: jnp.ndarray,
 
         w_chunk = jnp.where(live[:, None], take(weights), 0.0)
         b_rows = take(binned)                                  # [chunk, F]
-        member = (take(leaf_id)[:, None] == ids[None, :]) \
-            & live[:, None]                                    # [C, K]
-        if q:
-            u = _quant_u(w_chunk, quantize, member)
-        elif bf16:
-            hi, lo = _hi_lo(w_chunk)
-            mb = member[:, :, None].astype(jnp.bfloat16)
-            u_hi = (mb * hi[:, None, :]).reshape(chunk, c_ids * 3)
-            u_lo = (mb[:, :, 0:2] * lo[:, None, 0:2]).reshape(chunk,
-                                                              c_ids * 2)
-            u = jnp.concatenate([u_hi, u_lo], axis=1)
-        else:
-            u = (member[:, :, None].astype(jnp.float32)
-                 * w_chunk[:, None, :]).reshape(chunk, c_ids * 3)
-        return _contract_block_parts(
-            lambda gs, gc: jax.lax.slice_in_dim(b_rows, gs, gs + gc,
-                                                axis=1),
-            blocks, num_bins, u, bf16 or q)
+        return (lambda gs, gc: jax.lax.slice_in_dim(
+            b_rows, gs, gs + gc, axis=1)), w_chunk, take(leaf_id)
 
-    hist = _accumulate_chunks(one, n_chunks, blocks, num_bins, s,
-                              nv, chunk,
-                              dtype=jnp.int32 if q else jnp.float32)
-    if q:
-        hist = _quant_merge(hist, quantize, f, num_bins, c_ids)
-    elif bf16:
-        main = hist[:, :, :c_ids * 3].reshape(f, num_bins, c_ids, 3)
-        corr = hist[:, :, c_ids * 3:].reshape(f, num_bins, c_ids, 2)
-        hist = (main.at[:, :, :, 0:2].add(corr)
-                .reshape(f, num_bins, c_ids * 3))
-    return hist.reshape(f, num_bins, c_ids, 3).transpose(2, 0, 1, 3)
+    return _leaves_histogram(rows_of, ids, cap // chunk, f, num_bins, chunk,
+                             bf16, nv, group_widths, quantize)
 
 
 def leaf_weights(grad: jnp.ndarray, hess: jnp.ndarray, leaf_id: jnp.ndarray,

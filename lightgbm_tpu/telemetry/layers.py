@@ -54,6 +54,8 @@ SCOPES = (
     "lgbm/grow/compact_index",  # member mask, cumsum, scatter of row indices
     "lgbm/hist/gather",       # bins and (g, h, w) gathered through the index
     "lgbm/hist/contract",     # the one-hot contraction, full or gathered
+    "lgbm/hist/operand",      # its [chunk, S] channel operand: the rows'
+                              # channels in their node's columns, 0 elsewhere
     "lgbm/hist/merge",        # data-axis psum / psum_scatter of histograms
     "lgbm/grow/subtract",     # larger child = parent - smaller
     "lgbm/split/scan",        # ops/split.find_best_splits and its vmaps
