@@ -42,6 +42,10 @@ def _data_to_2d(data) -> np.ndarray:
     try:
         import scipy.sparse as sp
         if sp.issparse(data):
+            # still called with a sparse matrix by Booster.predict (and
+            # refit, and the sklearn wrappers' predict): scoring densifies
+            # its input. Dataset() does not come here: a sparse training
+            # set is streamed by ingest.SparseSource (_lazy_init)
             return np.asarray(data.todense(), np.float64)
     except ImportError:
         pass
@@ -49,6 +53,15 @@ def _data_to_2d(data) -> np.ndarray:
     if arr.ndim == 1:
         arr = arr.reshape(1, -1)
     return arr
+
+
+def _is_sparse(data) -> bool:
+    """A scipy sparse matrix? Whoever holds one has imported
+    `scipy.sparse`; a dense input must not pay that import here (0.7-1.3 s
+    of every `construct()`, my chip runs, PR 38)."""
+    import sys
+    sp = sys.modules.get("scipy.sparse")
+    return sp is not None and sp.issparse(data)
 
 
 def _device_landing_factory(params: Dict[str, Any]):
@@ -205,6 +218,18 @@ class Dataset:
                 if self.label is None and label is not None:
                     self.label = label
                 data = arr
+        elif _is_sparse(data):
+            # a scipy sparse matrix is binned and bundled from its stored
+            # entries (ingest.SparseSource): the dense [rows, features]
+            # matrix is never formed. The input's type decides; the
+            # default chunk holds more rows than a dense source's, since
+            # a row is a handful of entries
+            from .ingest import SparseSource
+            from .ingest.sources import SPARSE_CHUNK_ROWS
+            if self.used_indices is not None:
+                data = data.tocsr()[self.used_indices]
+            streamed_source = SparseSource(data, chunk_rows=int(params.get(
+                "tpu_ingest_chunk_rows", SPARSE_CHUNK_ROWS)))
         elif not (isinstance(data, np.ndarray) and data.ndim == 2
                   and data.dtype == np.float32):
             # a float32 table stays as it is: ingest widens each chunk as
@@ -212,7 +237,7 @@ class Dataset:
             # same), where a float64 copy of 1M x 2000 floats was 16.8 GB
             # of host memory and a third of construct() (PERF.md, PR 28)
             data = _data_to_2d(data)
-        if self.used_indices is not None:
+        if self.used_indices is not None and streamed_source is None:
             data = data[self.used_indices]
 
         feature_names = None

@@ -517,12 +517,17 @@ def mappers_from_sample(sample: np.ndarray, total: int, max_bin: int,
 
     The shared core of `find_bin_mappers` (in-memory) and the ingest
     pass-1 sketch (streamed): both hand it the same sampled rows, so both
-    produce bit-identical bounds."""
+    produce bit-identical bounds. `sample` may be a scipy CSC matrix (the
+    sketch of a sparse source): a column is then its stored entries, and
+    the zeros it leaves out are the ones the filter below drops."""
     f = sample.shape[1]
     cats = set(categorical_features or [])
+    sparse = hasattr(sample, "indptr")
 
     def _one(j):
-        col = np.asarray(sample[:, j], dtype=np.float64)
+        col = np.asarray(
+            sample.data[sample.indptr[j]:sample.indptr[j + 1]] if sparse
+            else sample[:, j], dtype=np.float64)
         m = BinMapper()
         nonzero = col[(col != 0.0) | np.isnan(col)]
         m.find_bin(nonzero, total, max_bin, min_data_in_bin, min_split_data,

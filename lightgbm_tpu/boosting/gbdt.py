@@ -1026,6 +1026,15 @@ class GBDT:
                      ",".join("%d:%dx%d" % b for b in rank.buckets),
                      rank.slots, rank.pair_slots, rank.valid_pairs)
 
+        efb = getattr(train_data, "efb_counters", None)
+        if efb is not None and efb.groups < efb.features:
+            # the bundle layout, counted once in ingest/build.build_inner
+            self._schedule_info["efb"] = efb.as_dict()
+            log.info("Schedule: efb features=%d groups=%d bundles=%d "
+                     "widest_group_bins=%d sample_conflicts=%d",
+                     efb.features, efb.groups, efb.bundles,
+                     efb.widest_group_bins, efb.sample_conflicts)
+
         # boost from average (gbdt.cpp:358-378): the score bump happens at
         # init; the bias itself is folded into the first trained tree via
         # AddBias (gbdt.cpp:446) so the saved model is self-contained

@@ -125,6 +125,7 @@ class Dataset:
         # telemetry.ConstructRecord: host seconds of the build by phase
         # (ingest/build.build_inner); None for a dataset made otherwise
         self.construct_record = None
+        self.efb_counters = None  # telemetry.EfbCounters (ingest/build)
 
     # ------------------------------------------------------------------
     @classmethod

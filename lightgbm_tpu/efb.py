@@ -21,6 +21,12 @@ from leaf totals (the FixHistogram trick, dataset.cpp:747-767).
 
 Single-feature groups store the feature's bins unshifted (offset 0) and
 need no reconstruction.
+
+A sparse source (scipy CSR through `ingest.SparseSource`) is bundled from
+its stored entries alone (`FeatureGroups.bundle_sparse`): a row's group
+value starts at the group's all-default bin and each stored entry writes
+its member's shifted bin, so the work is per entry, not per value, and the
+dense matrix is never formed. The result is `bundle_rows`'s to the bit.
 """
 from __future__ import annotations
 
@@ -64,6 +70,9 @@ class FeatureGroups:
         self.offset_of = np.zeros(f, np.int32)
         self.is_bundled = np.zeros(f, bool)
         self.group_num_bin = np.zeros(len(groups), np.int32)
+        #: conflicting sample rows the grouping accepted (within
+        #: max_conflict_rate); 0 for a layout not found from a sample
+        self.sample_conflicts = 0
         for g, members in enumerate(groups):
             if len(members) == 1:
                 j = members[0]
@@ -83,6 +92,12 @@ class FeatureGroups:
     def num_groups(self) -> int:
         return len(self.groups)
 
+    @property
+    def storage_dtype(self):
+        """uint8 while the widest group fits it, else uint16."""
+        return np.uint8 if int(self.group_num_bin.max(initial=1)) <= 256 \
+            else np.uint16
+
     def to_dict(self) -> dict:
         return {"groups": [[int(j) for j in g] for g in self.groups],
                 "num_bins": [0] * 0}  # groups are sufficient to rebuild
@@ -95,8 +110,7 @@ class FeatureGroups:
         feature_bins[j]: [N] integer bins of used feature j.
         """
         n = len(feature_bins[0]) if feature_bins else 0
-        dtype = np.uint8 if int(self.group_num_bin.max(initial=1)) <= 256 \
-            else np.uint16
+        dtype = self.storage_dtype
         out = np.zeros((n, self.num_groups), dtype)
         for g, members in enumerate(self.groups):
             if len(members) == 1:
@@ -111,6 +125,59 @@ class FeatureGroups:
                 col[nz] = self.offset_of[j] + feature_bins[j][nz]
             out[:, g] = col.astype(dtype)
         return out
+
+    def bundle_sparse(self, chunk, used, mappers, default_bins: np.ndarray,
+                      zero_bins: np.ndarray, pool=None):
+        """`bundle_rows` for a CSR row slice, from its stored entries.
+
+        chunk: scipy CSR [m, num_total_features]; used[u]: the column of
+        used feature u; zero_bins[u]: the bin `mappers` give 0.0, what
+        every row WITHOUT an entry holds. Returns ([m, G] group bins,
+        entries visited). The slice is turned to CSC once (O(entries)),
+        so each column's entries are one run; a group's members are
+        written in their order, the later winning, as `bundle_rows` does.
+        A member whose zero is not its default bin (a categorical column
+        without a 0 category) is non-default in EVERY row: it alone is
+        laid out as a whole column of the chunk."""
+        m = chunk.shape[0]
+        dtype = self.storage_dtype
+        csc = chunk.tocsc()
+        indptr, entry_rows, entry_vals = csc.indptr, csc.indices, csc.data
+        out = np.zeros((m, self.num_groups), dtype)
+
+        def entries(u):
+            lo, hi = indptr[used[u]], indptr[used[u] + 1]
+            rows = entry_rows[lo:hi]
+            return rows, mappers[used[u]].values_to_bins(entry_vals[lo:hi])
+
+        def fill(g):
+            members = self.groups[g]
+            if len(members) == 1:
+                u = members[0]
+                rows, bins = entries(u)
+                col = np.full(m, zero_bins[u], dtype)
+                col[rows] = bins
+                out[:, g] = col
+                return
+            col = np.zeros(m, np.int32)
+            for u in members:
+                rows, bins = entries(u)
+                if zero_bins[u] != default_bins[u]:
+                    whole = np.full(m, zero_bins[u], np.int32)
+                    whole[rows] = bins
+                    nz = whole != default_bins[u]
+                    col[nz] = self.offset_of[u] + whole[nz]
+                    continue
+                nz = bins != default_bins[u]
+                col[rows[nz]] = self.offset_of[u] + bins[nz]
+            out[:, g] = col
+
+        if pool is not None:
+            list(pool.map(fill, range(self.num_groups)))
+        else:
+            for g in range(self.num_groups):
+                fill(g)
+        return out, int(np.diff(indptr)[used].sum())
 
 
 EFB_SAMPLE_CNT = 50_000
@@ -235,6 +302,8 @@ def find_groups_sampled(sample_bins: List[np.ndarray],
 
     # demote 1-member "bundles" to plain groups (no reserved bin 0)
     fg = FeatureGroups(groups, num_bins)
+    fg.sample_conflicts = int(sum(
+        c for c, g in zip(gconflict, groups) if len(g) > 1))
     n_bundled = sum(1 for g in groups if len(g) > 1)
     if n_bundled:
         log.info("EFB bundled %d features into %d groups "

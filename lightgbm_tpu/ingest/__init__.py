@@ -5,7 +5,7 @@ The reproduction's analogue of the reference's `DatasetLoader` /
 `PipelineReader` split (PAPER.md layer 3). One import surface:
 
 - `sources` — re-iterable chunk streams (`ArraySource`, `FileSource`,
-  `ChunksSource`);
+  `ChunksSource`, `SparseSource`);
 - `sketch`  — pass 1: stream once, gather the deterministic bin-finding
   + EFB row samples, freeze per-feature quantile bin bounds (reusing
   binning.py's sampled bound-finding — the exact-small-data fast path);
@@ -33,13 +33,13 @@ from .cache import (CacheCorrupt, CacheMismatch,
 from .landing import HostLanding, ShardedLanding
 from .sketch import SketchResult, sketch_pass
 from .sources import (ArraySource, ChunkSource, ChunksSource,
-                      DEFAULT_CHUNK_ROWS, FileSource)
+                      DEFAULT_CHUNK_ROWS, FileSource, SparseSource)
 
 __all__ = [
     "ArraySource", "CacheCorrupt", "CacheMismatch",
     "CACHE_FORMAT_VERSION", "CACHE_MAGIC",
     "ChunkSource", "ChunksSource", "DEFAULT_CHUNK_ROWS", "FileSource",
-    "HostLanding", "ShardedLanding", "SketchResult",
+    "HostLanding", "ShardedLanding", "SketchResult", "SparseSource",
     "binning_params_fingerprint_fields", "build_from_numpy", "build_inner",
     "ingest_fingerprint", "load_cache", "save_cache",
     "sketch_pass",

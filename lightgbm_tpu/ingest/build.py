@@ -6,7 +6,9 @@ chunks, bins each against the frozen bounds, bundles it (EFB) and lands
 it into a preallocated buffer — a host matrix by default, per-device
 shards under a data mesh (`landing.ShardedLanding`) when asked. The full
 raw float matrix never exists: peak memory is
-O(samples + chunk + landed bins).
+O(samples + chunk + landed bins). A sparse source (`SparseSource`) hands
+CSR row slices: pass 2 then bins and bundles their stored entries alone
+(`efb.FeatureGroups.bundle_sparse`), at a cost per entry, not per value.
 
 Bit-identity contract: every decision that shapes the result (row
 samples, bin bounds, bundle layout, per-row bins) is computed by the SAME
@@ -21,7 +23,7 @@ from typing import Callable, List, Optional, Sequence
 import numpy as np
 
 from .. import log, telemetry
-from .sketch import bin_sample_columns, sketch_pass
+from .sketch import bin_sample_columns, chunk_bytes, sketch_pass, zero_bin
 from .sources import ArraySource, ChunkSource, DEFAULT_CHUNK_ROWS
 from .landing import HostLanding
 
@@ -116,7 +118,7 @@ def build_inner(source: ChunkSource, *,
     g_cnt = groups.num_groups if groups is not None else 0
     max_group_bin = int(groups.group_num_bin.max(initial=1)) \
         if groups is not None and g_cnt else 1
-    out_dtype = np.uint8 if max_group_bin <= 256 else np.uint16
+    out_dtype = groups.storage_dtype if groups is not None else np.uint8
     landing = (landing_factory(n, g_cnt, out_dtype, max_group_bin)
                if landing_factory else HostLanding(n, g_cnt, out_dtype))
 
@@ -131,12 +133,25 @@ def build_inner(source: ChunkSource, *,
     # would double peak memory for nothing
     collect_raw = keep_raw and not isinstance(source, ArraySource)
     raw_blocks: List[np.ndarray] = []
+    # what a row without a stored entry holds in each used column
+    zero_bins = np.asarray([zero_bin(ds.mappers[j]) for j in used],
+                           np.int32) if source.sparse else None
+    values = nonzeros = n * len(used)    # a dense source: every value
+    if source.sparse:
+        values = nonzeros = 0            # counted chunk by chunk
     try:
         with phase("lgbm/dataset/bin"):
             lo = 0
             for chunk, chunk_labels in source.chunks():
-                m = len(chunk)
-                if used:
+                m = chunk.shape[0]
+                if used and source.sparse:
+                    block, visited = groups.bundle_sparse(
+                        chunk, used, ds.mappers, default_bins, zero_bins,
+                        pool)
+                    landing.write(lo, block)
+                    values += int(chunk.nnz)
+                    nonzeros += visited
+                elif used:
                     def _bin_col(j):
                         return ds.mappers[j].values_to_bins(chunk[:, j])
                     if pool is not None:
@@ -147,10 +162,12 @@ def build_inner(source: ChunkSource, *,
                 if labels_out is not None and chunk_labels is not None:
                     labels_out[lo:lo + m] = chunk_labels
                 if collect_raw:
-                    raw_blocks.append(np.array(chunk, np.float64))
+                    raw_blocks.append(np.array(
+                        chunk.toarray() if source.sparse else chunk,
+                        np.float64))
                 lo += m
                 telemetry.counter_add("ingest/rows", m)
-                telemetry.counter_add("ingest/bytes", chunk.nbytes)
+                telemetry.counter_add("ingest/bytes", chunk_bytes(chunk))
                 telemetry.counter_add("ingest/chunks", 1)
             if lo != n:
                 log.fatal("Source reported %d rows but streamed %d"
@@ -188,7 +205,13 @@ def build_inner(source: ChunkSource, *,
         ds.metadata.set_init_score(init_score)
     ds.construct_record = telemetry.ConstructRecord(
         *(phase.seconds[name] for name in telemetry.DATASET_SPANS),
-        values=n * len(used))
+        values=values, nonzeros=nonzeros)
+    if groups is not None:
+        ds.efb_counters = telemetry.EfbCounters(
+            features=len(used), groups=g_cnt,
+            bundles=sum(1 for g in groups.groups if len(g) > 1),
+            widest_group_bins=max_group_bin,
+            sample_conflicts=groups.sample_conflicts)
     telemetry.record_construct(ds.construct_record)
     return ds
 

@@ -11,7 +11,11 @@ n <= bin_construct_sample_cnt the "sample" is every row, bounded by the
 sample cap, never by the dataset).
 
 Peak memory: O(bin_sample + efb_sample) rows of float64 — independent of
-the dataset row count.
+the dataset row count. A sparse source's chunks are CSR row slices: the
+sampled rows are picked from them and kept sparse (CSC, so a column's
+entries are one run); `binning.mappers_from_sample` and
+`bin_sample_columns` read a column's stored entries where the dense path
+reads the column, and give the same mappers and sample bins to the bit.
 """
 from __future__ import annotations
 
@@ -25,6 +29,17 @@ from ..efb import EFB_SAMPLE_CNT, efb_sample_indices
 from .sources import ChunkSource
 
 
+def _is_sparse(chunk) -> bool:
+    return hasattr(chunk, "indptr")
+
+
+def chunk_bytes(chunk) -> int:
+    """Bytes the chunk holds: a CSR slice's three arrays, or the block."""
+    if _is_sparse(chunk):
+        return chunk.data.nbytes + chunk.indices.nbytes + chunk.indptr.nbytes
+    return chunk.nbytes
+
+
 class _RowGatherer:
     """Collect the rows of a sorted global-index set from a chunk stream."""
 
@@ -33,21 +48,30 @@ class _RowGatherer:
         self._cursor = 0
         self.blocks: List[np.ndarray] = []
 
-    def feed(self, global_lo: int, chunk: np.ndarray) -> None:
+    @staticmethod
+    def _keep(rows):
+        return rows if _is_sparse(rows) else np.array(rows, np.float64)
+
+    def feed(self, global_lo: int, chunk) -> None:
         if self.indices is None:
-            self.blocks.append(np.array(chunk, np.float64))
+            self.blocks.append(self._keep(chunk))
             return
-        hi = global_lo + len(chunk)
+        hi = global_lo + chunk.shape[0]
         c = self._cursor
         e = c + np.searchsorted(self.indices[c:], hi, side="left")
         if e > c:
             local = self.indices[c:e] - global_lo
-            self.blocks.append(np.array(chunk[local], np.float64))
+            self.blocks.append(self._keep(chunk[local]))
             self._cursor = e
 
-    def rows(self, num_cols: int) -> np.ndarray:
+    def rows(self, num_cols: int):
+        """The gathered rows: [s, num_cols] float64, or a scipy CSC
+        matrix of that shape where the chunks were sparse."""
         if not self.blocks:
             return np.zeros((0, num_cols), np.float64)
+        if _is_sparse(self.blocks[0]):
+            import scipy.sparse as sp
+            return sp.vstack(self.blocks, format="csc")
         return np.concatenate(self.blocks, axis=0)
 
 
@@ -61,7 +85,7 @@ class SketchResult:
         self.num_rows = num_rows
         self.num_cols = num_cols
         self.mappers = mappers
-        self.efb_rows = efb_rows  # [s, num_cols] raw sampled rows
+        self.efb_rows = efb_rows  # [s, num_cols] raw sampled rows (or CSC)
         self.total_sample_cnt = total_sample_cnt
 
 
@@ -93,9 +117,9 @@ def sketch_pass(source: ChunkSource, *, max_bin: int,
         if bin_gather is not None:
             bin_gather.feed(global_lo, chunk)
         efb_gather.feed(global_lo, chunk)
-        global_lo += len(chunk)
-        telemetry.counter_add("ingest/pass1_rows", len(chunk))
-        telemetry.counter_add("ingest/bytes", chunk.nbytes)
+        global_lo += chunk.shape[0]
+        telemetry.counter_add("ingest/pass1_rows", chunk.shape[0])
+        telemetry.counter_add("ingest/bytes", chunk_bytes(chunk))
         telemetry.counter_add("ingest/chunks", 1)
     if global_lo != n:
         from .. import log
@@ -115,10 +139,24 @@ def sketch_pass(source: ChunkSource, *, max_bin: int,
     return SketchResult(n, f, mappers, efb_gather.rows(f), total_sample)
 
 
+def zero_bin(mapper: BinMapper) -> int:
+    """The bin a row without a stored entry holds: 0.0's."""
+    return int(mapper.values_to_bins(np.zeros(1))[0])
+
+
 def bin_sample_columns(sketch: SketchResult,
                        used: Sequence[int]) -> List[np.ndarray]:
     """Bin the gathered EFB sample rows for the used features — the
     columns `efb.find_groups_sampled` consumes. Row-wise binning
     commutes with row sampling, so these equal `bin(all)[sample]`."""
-    return [sketch.mappers[j].values_to_bins(sketch.efb_rows[:, j])
-            for j in used]
+    rows = sketch.efb_rows
+    if not _is_sparse(rows):
+        return [sketch.mappers[j].values_to_bins(rows[:, j]) for j in used]
+    cols = []
+    for j in used:
+        lo, hi = rows.indptr[j], rows.indptr[j + 1]
+        col = np.full(rows.shape[0], zero_bin(sketch.mappers[j]), np.int32)
+        col[rows.indices[lo:hi]] = \
+            sketch.mappers[j].values_to_bins(rows.data[lo:hi])
+        cols.append(col)
+    return cols

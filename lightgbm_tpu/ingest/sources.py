@@ -9,10 +9,15 @@ Pass 1 streams it to sketch bin bounds, pass 2 streams it again to bin
 rows into the landed matrix — neither pass ever holds the full raw
 matrix.
 
-Three concrete sources:
+Four concrete sources:
 - `ArraySource`  — an in-memory matrix served as zero-copy row views
   (the Python-API path; "streaming" it buys the shared code path and the
   bit-identity contract, not memory);
+- `SparseSource` — a scipy sparse matrix served as CSR row slices: the
+  one source whose chunks are NOT dense blocks (`sparse = True`). Pass 1
+  keeps its row samples sparse, pass 2 bins and bundles from the stored
+  entries (`efb.FeatureGroups.bundle_sparse`), so the dense
+  `[rows, features]` matrix never exists;
 - `FileSource`   — a delimited text file parsed chunk-by-chunk
   (CSV/TSV via the io.parser float rules; the CLI / billion-row path);
 - `ChunksSource` — a held list of row blocks, for callers whose data
@@ -46,6 +51,8 @@ class ChunkSource:
     """
 
     has_labels: bool = False
+    #: True where chunks() yields scipy CSR row slices, not dense blocks
+    sparse: bool = False
 
     def num_rows(self) -> int:  # pragma: no cover — interface
         raise NotImplementedError
@@ -88,6 +95,49 @@ class ArraySource(ChunkSource):
         n = self.data.shape[0]
         for lo in range(0, n, self.chunk_rows):
             yield self.data[lo:lo + self.chunk_rows], None
+
+
+#: rows a chunk of a sparse source holds unless the caller says: a row is
+#: a handful of stored entries, and pass 2 pays a few numpy calls a
+#: COLUMN a chunk, so a chunk must hold many entries of every column
+SPARSE_CHUNK_ROWS = 1 << 20
+
+
+class SparseSource(ChunkSource):
+    """Stream a scipy sparse matrix as CSR row slices (CSC and the other
+    formats are converted once). Duplicate entries are summed first, as
+    `toarray()` would; explicit zeros and NaNs stay stored entries and
+    are binned like any value."""
+
+    sparse = True
+
+    def __init__(self, data, chunk_rows: int = SPARSE_CHUNK_ROWS):
+        data = data.tocsr()
+        if not data.has_canonical_format:
+            data = data.copy()
+            data.sum_duplicates()
+        self.data = data
+        self.chunk_rows = max(1, int(chunk_rows))
+
+    def num_rows(self) -> int:
+        return self.data.shape[0]
+
+    def num_cols(self) -> int:
+        return self.data.shape[1]
+
+    def chunks(self) -> Iterator[Chunk]:
+        """Row slices that VIEW the matrix's entries (scipy's own `[lo:hi]`
+        copies them, 30 ns an entry a pass): only the row pointers are
+        made anew."""
+        import scipy.sparse as sp
+        whole = self.data
+        n, f = whole.shape
+        for lo in range(0, n, self.chunk_rows):
+            hi = min(n, lo + self.chunk_rows)
+            a, b = whole.indptr[lo], whole.indptr[hi]
+            yield sp.csr_matrix(
+                (whole.data[a:b], whole.indices[a:b],
+                 whole.indptr[lo:hi + 1] - a), shape=(hi - lo, f)), None
 
 
 class ChunksSource(ChunkSource):

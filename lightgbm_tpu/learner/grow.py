@@ -361,6 +361,7 @@ class _NodeTable(NamedTuple):
         )
 
 
+@scope("lgbm/split/extract")
 def _extract_feature_hist(group_hist, sum_g, sum_h, count, fmeta, cfg):
     """Per-feature histograms [F, Bf, 3] out of the stored-group histogram
     [G, Bg, 3] (EFB layout, efb.py): feature f's bins live at

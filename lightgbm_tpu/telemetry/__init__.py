@@ -39,7 +39,8 @@ import atexit
 from typing import Any, Dict, List, Optional, Tuple
 
 from .layers import (DATASET_SPANS, INIT_SPANS, ITER_SPANS, SCOPES,
-                     ConstructRecord, InitRecord, Phases, TreeRecord, scope)
+                     ConstructRecord, EfbCounters, InitRecord, Phases,
+                     TreeRecord, scope)
 from .metrics import (DEFAULT_TIME_BUCKETS, Counter, Gauge, Histogram,
                       Registry, counter_add, current_site, enable,
                       enabled, gauge_set, heartbeat, observe, registry,
@@ -53,7 +54,7 @@ __all__ = [
     "DEFAULT_TIME_BUCKETS", "Counter", "Gauge", "Histogram", "Registry",
     "RunLog", "TrainRecorder", "CompileObserver", "SCHEMA_VERSION",
     "CompileTotals", "DATASET_SPANS", "INIT_SPANS", "ITER_SPANS", "SCOPES",
-    "ConstructRecord", "InitRecord", "Phases", "TreeRecord", "scope",
+    "ConstructRecord", "EfbCounters", "InitRecord", "Phases", "TreeRecord", "scope",
     "last_construct", "record_construct", "last_run", "record_run",
     "active_recorder", "compile_path_since", "counter_add", "current_site",
     "enable", "enabled", "gauge_set", "heartbeat", "observe", "observer",

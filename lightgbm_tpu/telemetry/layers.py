@@ -59,6 +59,10 @@ SCOPES = (
     "lgbm/hist/merge",        # data-axis psum / psum_scatter of histograms
     "lgbm/grow/subtract",     # larger child = parent - smaller
     "lgbm/split/scan",        # ops/split.find_best_splits and its vmaps
+    "lgbm/split/extract",     # opened INSIDE lgbm/split/scan: per-feature
+                              # histograms gathered out of the stored
+                              # groups', a bundled feature's default bin
+                              # repaired (grow._extract_feature_hist)
     "lgbm/grow/table",        # node-table and histogram-cache writes
     "lgbm/grow/commit",       # the drain: frontier argmax -> tree node
     "lgbm/grow/finalize",     # slot-map hops, then rows -> committed leaf
@@ -204,7 +208,29 @@ class ConstructRecord(NamedTuple):
     sketch_s: float
     groups_s: float
     bin_s: float
-    values: int                 # rows x used columns, what pass 2 binned
+    values: int                 # what the source held for pass 2: rows x
+                                # used columns of a dense source, the
+                                # stored entries of a sparse one
+    nonzeros: int = 0           # what pass 2 visited of them: every value
+                                # of a dense source, the entries in used
+                                # columns of a sparse one
+
+
+class EfbCounters(NamedTuple):
+    """What one data set's bundle layout holds, counted once in
+    `ingest/build.build_inner` (the dataset's `efb_counters`;
+    `schedule_info["efb"]`)."""
+    features: int               # used (non-trivial) features
+    groups: int                 # stored columns
+    bundles: int                # groups of more than one feature
+    widest_group_bins: int
+    sample_conflicts: int       # conflicting rows of the EFB sample that
+                                # the grouping accepted; 0 at
+                                # max_conflict_rate 0 and for a layout
+                                # taken from a reference or a cache
+
+    def as_dict(self) -> dict:
+        return dict(self._asdict())
 
 
 class InitRecord(NamedTuple):

@@ -90,9 +90,13 @@ def main(argv=None) -> int:
         config = json.load(fh)
     rows = args.rows or int(config["rows"])
     config["params"].update(kv.split("=", 1) for kv in args.param)
-    # a ranking configuration's generator hands query sizes over too
+    # a ranking configuration's generator hands query sizes over too; the
+    # sparse one's (X a scipy CSR matrix) the codes and the column map,
+    # which are the reference's alone
     X, y, *group = datagen.generator(config["generator"])(
         rows, int(config["features"]), args.seed)
+    if "lambdarank" not in str(config["params"].get("objective")):
+        group = []
     # set-up, as the benchmark's `dataset.construct_s` splits it: the
     # host's seconds in `construct()` (its phases are the ConstructRecord)
     # and from there to the binned matrix being on the device (`Booster`:

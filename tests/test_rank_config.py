@@ -415,7 +415,7 @@ def test_benchmark_json_names_the_configuration_the_cell_and_the_readers():
     entry, config = loaded["entry"], loaded["config"]
     assert (entry["config"], entry["traffic"], entry["chips"]) == (
         "msltr-2m27x137", "train_steady_rank", 1)
-    assert [w["name"] for w in bench["workloads"]][-1] == CELL
+    assert [w["name"] for w in bench["workloads"]][3] == CELL
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     declared, = [c for c in bench["configs"] if c["name"] == entry["config"]]
     assert declared["reduced"] == config["reduced"] == ["rows"]
@@ -443,8 +443,12 @@ def test_benchmark_json_names_the_configuration_the_cell_and_the_readers():
         "gradients.roofline_share"] == [m["name"]
                                         for m in bench["per_layer"][14:17]]
     for m in mine:
+        # the sparse cell's mode hands scopes too: PR 38 appended it to
+        # the one reader that needs nothing but scopes
         assert (m["layer"], m["moves"], m["workloads"]) == (
-            "gradients", "train_mrow_iters_per_s", [CELL])
+            "gradients", "train_mrow_iters_per_s",
+            [CELL] + ["expo-train-1chip"] * (m["name"].endswith(
+                "device_share")))
         assert os.path.isfile(os.path.join(BENCH, "layer_metrics",
                                            m["name"] + ".py"))
     assert set(loaded["cell"]["limits"]) == set(reference_rank.COMPARED) | {

@@ -451,7 +451,9 @@ def test_construct_record_accounts_for_construct():
     rec = ds._lazy_init().construct_record
     assert isinstance(rec, telemetry.ConstructRecord)
     assert rec is telemetry.last_construct()
-    assert rec._fields == ("sketch_s", "groups_s", "bin_s", "values")
+    assert rec._fields == ("sketch_s", "groups_s", "bin_s", "values",
+                           "nonzeros")
+    assert rec.nonzeros == rec.values          # dense: every value visited
     assert len(telemetry.DATASET_SPANS) == 3
     assert rec.values == 200_000 * 47 == X.shape[0] * len(
         ds._lazy_init().used_features)
@@ -568,11 +570,15 @@ def test_benchmark_json_names_the_cell_and_its_readers():
                             "dataset.sketch_s", "dataset.bin_s"]
     for m in bench["per_layer"]:
         # every cell produces what this cell's readers read; the metrics
-        # with a list of cells came with higgs-train-dp4 (the merge) and
-        # msltr-rank-1chip (the ranking gradients)
+        # with a list of cells came with higgs-train-dp4 (the merge),
+        # msltr-rank-1chip (the ranking gradients) and expo-train-1chip
+        # (bundles and the sparse ingest)
         assert ("workloads" in m) == (
             m["name"].startswith("merge.")
-            or m["name"].startswith("gradients."))
+            or m["name"].startswith("gradients.")
+            or m["name"] in ("dataset.bin_ns_per_nonzero",
+                             "dataset.groups_per_feature",
+                             "split.device_share", "hist.roofline_share"))
         assert os.path.isfile(os.path.join(BENCH, "layer_metrics",
                                            m["name"] + ".py"))
     assert set(loaded["cell"]["limits"]) == set(
