@@ -30,6 +30,7 @@ from ..learner.schedule import (compact_capacity, pick_schedule,
                                 schedule_info)
 from ..metrics import Metric, create_metric, default_metric_for_objective
 from ..objectives import ObjectiveFunction
+from ..ops.histogram import contraction_counters
 from ..ops.lookup import row_lookup
 from ..ops.predict import predict_leaf_binned, predict_value_binned
 from ..tree import Tree
@@ -1034,6 +1035,16 @@ class GBDT:
                      "widest_group_bins=%d sample_conflicts=%d",
                      efb.features, efb.groups, efb.bundles,
                      efb.widest_group_bins, efb.sample_conflicts)
+
+        # the contraction's block plan as the kernels take it (the serial
+        # and data learners' widths; feature shards re-plan per position)
+        hist = contraction_counters(self._grower_cfg.group_widths,
+                                    layout.chunk, self._max_bins)
+        self._schedule_info["hist"] = hist
+        if hist["split_groups"]:
+            log.info("Schedule: hist split_groups=%d sub_width=%d "
+                     "onehot_columns=%d", hist["split_groups"],
+                     hist["sub_width"], hist["onehot_columns"])
 
         # boost from average (gbdt.cpp:358-378): the score bump happens at
         # init; the bias itself is folded into the first trained tree via

@@ -169,6 +169,24 @@ def quantize_gradients(grad, hess, row_weight, *, n: int, qmax: int,
 # in elements; bounds the materialized [chunk, Gb, Bb] operand
 _BLOCK_BUDGET = 1 << 26
 
+# the most bins a group reaches the matmul with: a wider group is
+# contracted as sub-groups of at most this many bins (_group_split)
+SUB_BINS = 64
+
+
+def _group_split(width: int):
+    """(k, s): a group presented at `width` bins is contracted as k
+    sub-groups of s bins, k * s >= width; (1, width) up to SUB_BINS.
+
+    XLA:TPU lays a one-hot of more than ~96 bins out bins-minor and its
+    matmul's output `{1,2,0}`, and runs it at a fifth of the rate the
+    same matmul gets at 63 bins (batched_leaves_histogram's fifth design
+    choice). s = ceil(width / k) rather than SUB_BINS keeps a 65-bin
+    group at 66 columns, not 128; at 255 bins both are 64."""
+    width = max(1, int(width))
+    k = -(-width // SUB_BINS)
+    return k, -(-width // k)
+
 
 def plan_group_blocks(group_widths, chunk: int,
                       budget: int = _BLOCK_BUDGET):
@@ -182,9 +200,15 @@ def plan_group_blocks(group_widths, chunk: int,
     block scans at bin width = max(group widths inside it), so narrow
     features (the reference's 4-bit path, src/io/dense_nbits_bin.hpp)
     pay a proportionally narrower one-hot, not the global max width.
+    A group wider than SUB_BINS is budgeted at the k * s columns its
+    sub-groups take (_group_split).
 
     Returns a tuple of (g_start, g_count, bin_width) covering all groups.
     """
+    def cols(bw):
+        k, s = _group_split(bw)
+        return k * s
+
     g = len(group_widths)
     if g == 0:
         return ()
@@ -195,7 +219,7 @@ def plan_group_blocks(group_widths, chunk: int,
         j = i + 1
         while j < g:
             nbw = max(bw, int(group_widths[j]))
-            if nbw * (j + 1 - i) * chunk > budget:
+            if cols(nbw) * (j + 1 - i) * chunk > budget:
                 break
             bw = nbw
             j += 1
@@ -204,7 +228,29 @@ def plan_group_blocks(group_widths, chunk: int,
     return tuple(blocks)
 
 
-def _contract_block_parts(get_block, blocks, num_bins, u, bf16):
+def plan_contraction(group_widths, chunk: int, num_bins: int):
+    """The kernels' block plan: (g_start, g_count, width, k, s) a block,
+    width = the block's bins as presented (min(bin_width, num_bins)),
+    contracted as k sub-groups of s bins a group (_group_split)."""
+    out = []
+    for gs, gc, bw in plan_group_blocks(group_widths, chunk):
+        w = min(bw, num_bins)
+        out.append((gs, gc, w) + _group_split(w))
+    return tuple(out)
+
+
+def contraction_counters(group_widths, chunk: int, num_bins: int) -> dict:
+    """`schedule_info["hist"]`, from the plan the kernels take:
+    `split_groups`, the stored groups contracted as sub-groups;
+    `sub_width`, the most bins a sub-group holds; `onehot_columns`, the
+    one-hot columns a row is contracted over, summed over blocks."""
+    plan = plan_contraction(group_widths, chunk, num_bins)
+    return {"split_groups": sum(gc for _, gc, _, k, _ in plan if k > 1),
+            "sub_width": SUB_BINS,
+            "onehot_columns": sum(gc * k * s for _, gc, _, k, s in plan)}
+
+
+def _contract_block_parts(get_block, blocks, u, bf16):
     """One row-chunk's histogram contribution, group-block tiled.
 
     get_block(gs, gc): returns the chunk's [chunk, gc] bin slice for the
@@ -212,17 +258,30 @@ def _contract_block_parts(get_block, blocks, num_bins, u, bf16):
     matrix for the full-pass kernels, a static slice of an already
     gathered chunk for the compacted kernel.
     u: [chunk, S] channel matrix (already masked/hi-lo-packed by the
-    caller). Each block materializes only a [chunk, Gb, Bb] one-hot
-    (Bb = the block's own width). Returns a TUPLE of per-block
-    [Gb, Bb, S] f32 parts at their OWN widths — the chunk loop
-    accumulates the ragged parts and only _assemble_blocks pads them to
-    the uniform output width once, after the loop. (Padding inside the
-    loop made the fori carry [G, Bmax, S]: on heavily-bundled data like
-    the Bosch shape that is ~3.5x the real bin mass, all of it read and
-    written every chunk step.)"""
+    caller). Each block materializes only a [chunk, k * Gb, s] one-hot
+    (plan_contraction). Returns a TUPLE of per-block [k * Gb, s, S] f32
+    parts at their OWN widths — the chunk loop accumulates the ragged
+    parts and only _assemble_blocks lays them out at the uniform output
+    width once, after the loop. (Padding inside the loop made the fori
+    carry [G, Bmax, S]: on heavily-bundled data like the Bosch shape
+    that is ~3.5x the real bin mass, all of it read and written every
+    chunk step.)
+
+    A block of k > 1 sub-groups a group: sub-group j of a group holds its
+    bins s*j .. s*j + s - 1, as the group's bins less s*j in their own
+    integer type; a bin below wraps to s or above (k * s <= 256 where the
+    bins are uint8) or goes negative, a bin above stays s or above, and
+    neither matches the sub-group's one-hot. The k shifted copies are
+    joined sub-group-major, [chunk, k * Gb]: XLA:TPU builds that in one
+    fusion, where the group-major [chunk, Gb, k] took a broadcast and a
+    reshape of their own besides."""
     parts = []
-    for gs, gc, bw in blocks:
-        oh = _onehot(get_block(gs, gc), min(bw, num_bins))
+    for gs, gc, _, k, s in blocks:
+        b = get_block(gs, gc)
+        if k > 1:
+            b = jnp.concatenate([b - jnp.asarray(s * j, b.dtype)
+                                 for j in range(k)], axis=1)
+        oh = _onehot(b, s)
         if bf16:
             p = jnp.einsum("cfb,cs->fbs", oh.astype(jnp.bfloat16),
                            u.astype(jnp.bfloat16),
@@ -243,16 +302,21 @@ def _chunk_blocks(binned, c, chunk):
                                                 (chunk, gc))
 
 
-def _blocks_zeros(blocks, num_bins, s, dtype=jnp.float32):
-    return tuple(jnp.zeros((gc, min(bw, num_bins), s), dtype)
-                 for _, gc, bw in blocks)
+def _blocks_zeros(blocks, s, dtype=jnp.float32):
+    return tuple(jnp.zeros((k * gc, sb, s), dtype)
+                 for _, gc, _, k, sb in blocks)
 
 
-def _assemble_blocks(parts, num_bins):
-    """Pad the ragged per-block accumulators to the uniform output width
-    and concatenate along the group axis: [G, num_bins, S]."""
+def _assemble_blocks(parts, blocks, num_bins):
+    """Lay the ragged per-block accumulators out at the uniform output
+    width and concatenate along the group axis: [G, num_bins, S]. A
+    split block's [k * Gb, s, S] (sub-group-major) becomes its groups'
+    [Gb, k * s, S] in bin order, cut to the block's width."""
     out = []
-    for p in parts:
+    for p, (_, gc, w, k, s) in zip(parts, blocks):
+        if k > 1:
+            p = p.reshape(k, gc, s, p.shape[-1]).transpose(1, 0, 2, 3)
+            p = p.reshape(gc, k * s, p.shape[-1])[:, :w]
         if p.shape[1] < num_bins:
             p = jnp.pad(p, ((0, 0), (0, num_bins - p.shape[1]), (0, 0)))
         out.append(p)
@@ -278,16 +342,16 @@ def _accumulate_chunks(one, n_chunks, blocks, num_bins, s, n_valid, chunk,
         return tuple(p.astype(dtype) for p in parts)
 
     if n_chunks == 1:
-        return _assemble_blocks(cast(one(jnp.int32(0))), num_bins)
+        return _assemble_blocks(cast(one(jnp.int32(0))), blocks, num_bins)
 
     def body(c, accs):
         return tuple(a + p for a, p in zip(accs, cast(one(c))))
 
     trip = n_chunks if n_valid is None else \
         jnp.minimum((n_valid + chunk - 1) // chunk, n_chunks)
-    init = _blocks_zeros(blocks, num_bins, s, dtype)
+    init = _blocks_zeros(blocks, s, dtype)
     return _assemble_blocks(
-        jax.lax.fori_loop(0, trip, body, init), num_bins)
+        jax.lax.fori_loop(0, trip, body, init), blocks, num_bins)
 
 
 def _channels(w_chunk, bf16, quantize):
@@ -411,14 +475,14 @@ def leaf_histogram(binned: jnp.ndarray, weights: jnp.ndarray,
     q = quantize != "none"
     n_chunks = n // chunk
     widths = group_widths if group_widths else (num_bins,) * f
-    blocks = plan_group_blocks(widths, chunk)
+    blocks = plan_contraction(widths, chunk, num_bins)
 
     def one(c):
         w_chunk = jax.lax.dynamic_slice(weights, (c * chunk, 0), (chunk, 3))
         base, lo = _channels(w_chunk, bf16, quantize)
         u = base if lo is None else jnp.concatenate([base, lo], axis=1)
         return _contract_block_parts(_chunk_blocks(binned, c, chunk),
-                                     blocks, num_bins, u, bf16 or q)
+                                     blocks, u, bf16 or q)
 
     hist = _accumulate_chunks(one, n_chunks, blocks, num_bins,
                               3 + _n_lo(bf16, quantize), n_valid, chunk,
@@ -437,14 +501,13 @@ def _leaves_histogram(rows_of, ids, n_chunks, f, num_bins, chunk, bf16,
     q = quantize != "none"
     c_ids = ids.shape[0]
     widths = group_widths if group_widths else (num_bins,) * f
-    blocks = plan_group_blocks(widths, chunk)
+    blocks = plan_contraction(widths, chunk, num_bins)
 
     def one(c):
         get_block, w_chunk, lid = rows_of(c)
         base, lo = _channels(w_chunk, bf16, quantize)
         u = _channel_operand(lid, ids, base, lo)
-        return _contract_block_parts(get_block, blocks, num_bins, u,
-                                     bf16 or q)
+        return _contract_block_parts(get_block, blocks, u, bf16 or q)
 
     hist = _accumulate_chunks(one, n_chunks, blocks, num_bins,
                               c_ids * (3 + _n_lo(bf16, quantize)),
@@ -469,7 +532,7 @@ def batched_leaves_histogram(binned: jnp.ndarray, weights: jnp.ndarray,
     ids BEFORE building their histograms, so membership is a direct
     `leaf_id == ids[k]` compare — no split bit. Returns [C, F, B, 3].
 
-    Four deliberate design choices, all but the third profiled on
+    Five deliberate design choices, all but the third profiled on
     hardware:
     - rows are walked with `lax.dynamic_slice` chunks instead of an
       upfront reshape to [n_chunks, chunk, F]: the reshape forced XLA to
@@ -501,7 +564,21 @@ def batched_leaves_histogram(binned: jnp.ndarray, weights: jnp.ndarray,
       columns (five [chunk, C] selects concatenated), the fastest alone
       (60.6 / 104.0 / 125.4) and cheapest to build, but in the grow
       program its [F][C][K][B] accumulators make XLA re-lay the whole
-      subtraction cache every pass (0.74 s a pair at 2000 features).
+      subtraction cache every pass (0.74 s a pair at 2000 features);
+    - no group reaches the matmul wider than SUB_BINS = 64 bins: a wider
+      one is contracted as k sub-groups of ceil(w / k) bins
+      (_group_split), sub-group j comparing the bins less s*j.
+      XLA:TPU lays a one-hot of more than ~96 bins out bins-minor
+      (`pred[65536,4,255]{2,0,1}`) and its matmul `{1,2,0}`: at 255
+      bins (the Expo bundles, LightGBM's default max_bin) the two
+      matmul fusions were 90% of the device time and ran at 21% of the
+      bf16 MXU peak, where HIGGS's `f32[16,63,120]{2,1,0}` runs at 93%
+      (0.382 against 0.087 ms a chunk for 1,020 against 1,008 one-hot
+      columns; TPU v5e, PR 38's ledger). Split, the same groups compile
+      to `f32[16,64,120]{2,1,0}` and `f32[12,64,120]{2,1,0}`, one
+      `u8[chunk, k*Gb]` fusion of shifted bins a block, and the
+      histograms to the bit on the CPU; a block of at most 64 bins
+      traces the program it traced before (PR 39, `PERF.md` section 6).
     """
     n, f = binned.shape
     if n % chunk != 0:

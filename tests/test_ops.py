@@ -255,3 +255,196 @@ def test_batched_leaves_histogram_bf16_single_pass():
         jnp.asarray(ids), B, chunk=128, bf16=True))
     np.testing.assert_allclose(fast, ref, rtol=2e-4, atol=2e-4)
     np.testing.assert_array_equal(fast[:, :, :, 2], ref[:, :, :, 2])
+
+
+# --- groups wider than SUB_BINS, contracted as sub-groups (PR 39) ---------
+
+def _today_blocks(widths, chunk, budget=1 << 26):
+    """plan_group_blocks before PR 39, for widths of at most SUB_BINS."""
+    blocks, i = [], 0
+    while i < len(widths):
+        bw, j = max(1, widths[i]), i + 1
+        while j < len(widths):
+            nbw = max(bw, widths[j])
+            if nbw * (j + 1 - i) * chunk > budget:
+                break
+            bw, j = nbw, j + 1
+        blocks.append((i, j - i, bw))
+        i = j
+    return tuple(blocks)
+
+
+@pytest.mark.parametrize("widths,chunk", [
+    ((63,) * 28, 65536), ((63,) * 137, 65536), ((15,) * 2000, 8192),
+    ((2,) * 700, 65536), ((64, 3, 17, 64, 1, 40) * 50, 16384)])
+def test_group_blocks_unchanged_up_to_sub_bins(widths, chunk):
+    """Nothing wider than SUB_BINS: the parent's blocks, none split."""
+    from lightgbm_tpu.ops import histogram as H
+    assert H.plan_group_blocks(widths, chunk) == _today_blocks(widths, chunk)
+    assert all(k == 1 and s == w for _, _, w, k, s in
+               H.plan_contraction(widths, chunk, max(widths)))
+
+
+def _kernel_case(kernel, precision, widths, chunk=256):
+    """One kernel's histograms of a table in which every group holds each
+    of its bins 0..w-1, with the channels `precision` contracts exactly
+    (float32: sixteenths; int8 / int16: integers in range); and the
+    float64 (int64) np.add.at histograms of the same rows."""
+    from lightgbm_tpu.ops import histogram as H
+    rng = np.random.RandomState(sum(widths))
+    n, f, nb = 2 * chunk, len(widths), max(widths)
+    binned = np.stack([np.concatenate(
+        [np.arange(w), rng.randint(0, w, n - w)]) for w in widths],
+        axis=1).astype(np.uint8)
+    q = precision in ("int8", "int16")
+    if q:
+        qm = 127 if precision == "int8" else 32767
+        g, h = rng.randint(-qm, qm + 1, n), rng.randint(0, qm + 1, n)
+    elif precision == "float32":
+        g, h = rng.randint(-64, 65, n) / 16, rng.randint(1, 65, n) / 16
+    else:
+        g, h = rng.randn(n), rng.rand(n)
+    w = np.stack([g, h, rng.rand(n) < 0.8], axis=1).astype(np.float32)
+    w[:, :2] *= w[:, 2:]
+    leaf_id = rng.randint(0, 4, n).astype(np.int32)
+    ids = np.asarray([0, 2, 3, -1], np.int32)
+    rows = np.zeros(n, np.int32)
+    rows[:300] = np.sort(rng.choice(n, 300, replace=False))
+    static = dict(num_bins=nb, chunk=chunk, bf16=precision == "bf16",
+                  group_widths=tuple(widths),
+                  quantize=precision if q else "none")
+    args = {"leaf": (binned, w),
+            "batched": (binned, w, leaf_id, ids),
+            "gathered": (binned, w, leaf_id, rows, ids)}[kernel]
+    fn = {"leaf": H.leaf_histogram, "batched": H.batched_leaves_histogram,
+          "gathered": H.gathered_leaves_histogram}[kernel]
+    extra = {"n_valid": 300} if kernel == "gathered" else {}
+
+    def run():
+        # a fresh program each call: plan_contraction is read at trace
+        import functools
+        import jax
+        return np.asarray(jax.jit(functools.partial(
+            fn.__wrapped__, **static))(*map(jnp.asarray, args), **extra))
+
+    dt = np.int64 if q else np.float64
+    member = rows[:300] if kernel == "gathered" else np.arange(n)
+    refs = []
+    for lab in ([None] if kernel == "leaf" else ids):
+        r = member if lab is None else member[leaf_id[member] == lab]
+        ref = np.zeros((f, nb, 3), dt)
+        for j in range(f):
+            np.add.at(ref[j], binned[r, j], w[r].astype(dt))
+        refs.append(ref)
+    return run, (refs[0] if kernel == "leaf" else np.stack(refs))
+
+
+@pytest.mark.parametrize("widths", [
+    (45, 63, 64, 65, 100, 127, 128, 255),
+    (255, 255, 255, 255, 45, 45, 45)])
+@pytest.mark.parametrize("precision", ["float32", "bf16", "int8", "int16"])
+@pytest.mark.parametrize("kernel", ["leaf", "batched", "gathered"])
+def test_groups_over_sub_bins_contract_exactly(kernel, precision, widths,
+                                               monkeypatch):
+    """A group wider than SUB_BINS reaches the matmul as sub-groups of at
+    most SUB_BINS bins: the histogram every caller receives is the one
+    np.add.at gives, exactly in float32 and the quantized modes, and in
+    bf16 hi+lo the unsplit form's to the bit. The small budget makes
+    blocks of several groups, split and not, at 256-row chunks."""
+    import functools
+    from lightgbm_tpu.ops import histogram as H
+    monkeypatch.setattr(H, "plan_group_blocks", functools.partial(
+        H.plan_group_blocks, budget=256 * 520))
+    plan = H.plan_contraction(widths, 256, max(widths))
+    assert any(k > 1 for *_, k, _ in plan) and all(
+        s <= H.SUB_BINS and k * s >= w for _, _, w, k, s in plan)
+    run, ref = _kernel_case(kernel, precision, widths)
+    got = run()
+    if precision == "bf16":
+        np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
+        monkeypatch.setattr(H, "SUB_BINS", 1 << 16)
+        assert all(k == 1 for *_, k, _ in
+                   H.plan_contraction(widths, 256, max(widths)))
+        np.testing.assert_array_equal(got, run())
+    else:
+        np.testing.assert_array_equal(got, ref)
+
+
+@pytest.mark.parametrize("widths,split,columns", [
+    ((45,) * 4 + (255,) * 7, 7, 1972),          # expo-11mx700's groups
+    ((63,) * 28, 0, 1764)])                      # higgs-10m5x28's
+def test_contraction_counters(widths, split, columns):
+    from lightgbm_tpu.ops.histogram import contraction_counters
+    assert contraction_counters(widths, 65536, max(widths)) == {
+        "split_groups": split, "sub_width": 64, "onehot_columns": columns}
+
+
+def test_schedule_info_carries_the_contraction_plan(capfd):
+    """Every run's `schedule_info["hist"]` is the kernels' plan; the
+    `Schedule: hist` line is written where a group is split."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.ops.histogram import contraction_counters
+    rng = np.random.default_rng(0)
+    X = rng.standard_normal((3000, 3)).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.float32)
+    inner = lgb.Booster({"objective": "binary", "verbose": 1,
+                         "max_bin": 255, "num_leaves": 7},
+                        lgb.Dataset(X, y))._inner
+    cfg, info = inner._grower_cfg, inner._schedule_info
+    assert info["hist"] == contraction_counters(
+        cfg.group_widths, info["chunk"], cfg.max_bins)
+    assert info["hist"]["split_groups"] == 3
+    assert "Schedule: hist split_groups=3 sub_width=64" in \
+        capfd.readouterr().err
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a described v5e: the chip's compiler, no chip. The
+    persistent cache is off meanwhile: a program compiled for a chip
+    that is not attached is written there but cannot be read back."""
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 - libtpu cannot describe one
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("widths", [(255,) * 4, (63,) * 16])
+def test_matmul_output_keeps_channels_minor_on_v5e(widths, v5e_chip):
+    """One chunk step of the contraction compiled for a described v5e:
+    every 120-channel matmul comes out `{2,1,0}` (channels on lanes). A
+    255-bin one-hot presented whole came out `{1,2,0}` and ran at a fifth
+    of the MXU's rate (PR 39); this guards against a compiler that lays
+    the sub-groups out that way again."""
+    import re
+    import jax
+    from lightgbm_tpu.ops import histogram as H
+    chunk = 65536
+    blocks = H.plan_contraction(widths, chunk, max(widths))
+
+    def step(b, u):
+        return H._contract_block_parts(
+            lambda gs, gc: jax.lax.slice_in_dim(b, gs, gs + gc, axis=1),
+            blocks, u, True)
+
+    text = jax.jit(step).lower(
+        jax.ShapeDtypeStruct((chunk, len(widths)), jnp.uint8,
+                             sharding=v5e_chip),
+        jax.ShapeDtypeStruct((chunk, 120), jnp.bfloat16, sharding=v5e_chip)
+    ).compile().as_text()
+    found = re.findall(r"= f32\[(\d+),(\d+),120\]\{([\d,]+)[:}][^\n]*"
+                       r"(?:fusion|convolution)\([^\n]*dot_general", text)
+    assert found, "no matmul in the listing"
+    assert {(int(b), lay) for _, b, lay in found} == {
+        (min(max(widths), H.SUB_BINS), "2,1,0")}
