@@ -233,7 +233,7 @@ class _HostState:
 
 
 def _class_state(host_state, cls: int) -> _HostState:
-    """Class tree `cls` of the class-vmapped program's host state."""
+    """Class tree `cls` of the multiclass program's host state."""
     return _HostState({key: v[cls] for key, v in vars(host_state).items()})
 
 
@@ -339,39 +339,29 @@ def _grow_and_update_multi_impl(score, binned, grads, hesses, row_weight,
                                 fmasks, shrinkage, n_valid, fmeta_args, cfg,
                                 qscales=None):
     """Grow ALL num_class trees of one boosting iteration in ONE device
-    program (vmap over the class axis) and update every score row.
+    program, one after another, and update every score row.
 
-    The reference grows class trees sequentially (gbdt.cpp:410-462,
-    one `tree_learner_->Train` per class). SURVEY.md §2.5 marks this the
-    EP-analogue free win on TPU: the class trees of an iteration are
-    independent given the gradients, so vmap fuses their histogram
-    passes into wider contractions and collapses k dispatches + k
-    compiled signatures into one."""
+    The class trees of an iteration are independent given the gradients,
+    and the reference grows them in sequence (gbdt.cpp:410-462, one
+    `tree_learner_->Train` per class). So does this program: a `lax.scan`
+    over the class axis whose body is the one-class grow+update
+    (`_grow_and_update_impl`, its class index traced), so each class tree
+    runs the program of one tree on the schedule `pick_schedule` gives
+    one tree, and the body's buffers serve every class in turn. One
+    dispatch and one fetch an iteration; the small state comes back with
+    a leading class axis."""
     import jax
     import jax.numpy as jnp
 
-    def one(g, h, m, qs=None):
-        return grow_tree(binned, g, h, row_weight, m, *fmeta_args,
-                         cfg, n_valid=n_valid, qscale=qs)
+    def one(score, xs):
+        cls, g, h, m, qs = xs
+        return _grow_and_update_impl(score, binned, g, h, row_weight, m,
+                                     shrinkage, n_valid, fmeta_args, cls,
+                                     cfg, qscale=qs)
 
-    if qscales is None:
-        state = jax.vmap(one)(grads, hesses, fmasks)
-    else:
-        # per-class dequant scales ride the class vmap with the grads
-        state = jax.vmap(one)(grads, hesses, fmasks, qscales)
-
-    def upd(lv, lid, grew):
-        vals = lv * shrinkage
-        return jnp.where(
-            grew,
-            row_lookup(vals, jnp.clip(lid, 0, cfg.num_leaves - 1)), 0.0)
-
-    with telemetry.scope("lgbm/score/update"):
-        delta = jax.vmap(upd)(state.leaf_value, state.leaf_id,
-                              state.num_leaves_used > 1)
-        score = score + delta
-    small = {k: getattr(state, k) for k in _SMALL_STATE_KEYS}
-    return score, small
+    classes = jnp.arange(grads.shape[0], dtype=jnp.int32)
+    return jax.lax.scan(one, score,
+                        (classes, grads, hesses, fmasks, qscales))
 
 
 def _grow_and_update_multi(score, binned, grads, hesses, row_weight, fmasks,
@@ -858,7 +848,8 @@ class GBDT:
         # --- the execution schedule (learner/schedule.py) ----------------
         # each data/voting shard compacts its own block, so the schedule
         # is asked about ONE shard's rows: what it models is what the
-        # grower's own guard sees
+        # grower's own guard sees; and about ONE tree, since every path
+        # grows the class trees of an iteration one after another
         g_cnt = max(1, int(train_data.num_groups))
         shards = layout.row_multiple // layout.chunk  # this process's
         # the memory the subtraction cache is judged against (a device
@@ -871,7 +862,6 @@ class GBDT:
         picked = pick_schedule(
             g_cnt, self._max_bins, n // shards, n_pad // shards,
             layout.chunk, num_leaves=self.config.tree.num_leaves,
-            classes=self.num_tree_per_iteration,
             learner=self._tree_learner_kind,
             bundled=g_cnt < 0.8 * max(1, train_data.num_features),
             quantize=quant_mode,
@@ -882,8 +872,7 @@ class GBDT:
             device_bytes=self._device_bytes, cache_groups=owned_groups)
         costs = picked.compact_model
         relabel = relabel_rows(g_cnt, self._max_bins, picked.batch_k,
-                               n_pad // shards,
-                               classes=self.num_tree_per_iteration)
+                               n_pad // shards)
         log.info("Schedule: groups=%d max_bin=%d wide=%s subtract=%s "
                  "compact=%s@%.3f (ns a row: full=%.1f index=%.1f "
                  "gather=%.1f) batch_k=%d table_mult=%d chunk=%d "
@@ -1022,6 +1011,10 @@ class GBDT:
                          if self._tree_learner_kind == "data" else None),
             owned_groups=owned_groups,
             device_bytes=self._device_bytes)
+        if self.num_tree_per_iteration > 1:
+            # the class trees of an iteration run the schedule above one
+            # after another (`_grow_and_update_multi_impl`)
+            self._schedule_info["class_trees"] = "sequential"
         rank = getattr(objective, "rank_counters", None)
         if rank is not None:
             # lambdarank's pair layout, counted once in its init()
@@ -1437,15 +1430,15 @@ class GBDT:
                                   probe, qscales, entered) -> bool:
         """One-class iteration of the serial learner, or of a data-parallel
         one whose rows live on this process's devices, or the K class trees
-        of a serial multiclass iteration (the class-vmapped program), with
-        the fetch pipelined one iteration behind the device dispatch (see
-        __init__). The stop/rollback decision therefore lags one
-        iteration: an iteration in which no tree split is detected when it
-        is materialized and rolled back whole (its score delta was already
-        zero on device, the `grew` guards of _grow_and_update_impl and
-        _grow_and_update_multi_impl; gbdt.cpp:466-472), and the one extra
-        dispatched iteration — which cannot split either — is discarded
-        by finalize_training()."""
+        of a serial multiclass iteration (one program, the class trees one
+        after another), with the fetch pipelined one iteration behind the
+        device dispatch (see __init__). The stop/rollback decision therefore
+        lags one iteration: an iteration in which no tree split is detected
+        when it is materialized and rolled back whole (its score delta was
+        already zero on device, the `grew` guard of _grow_and_update_impl,
+        the body of _grow_and_update_multi_impl; gbdt.cpp:466-472), and the
+        one extra dispatched iteration — which cannot split either — is
+        discarded by finalize_training()."""
         import jax.numpy as jnp
 
         from ..learner.grow import FMETA_KEYS
@@ -1509,7 +1502,7 @@ class GBDT:
         return False
 
     def _dispatch_multi(self, grad, hess, row_weight, qscales):
-        """Enqueue the class-vmapped grow+update program of one iteration
+        """Enqueue the multiclass grow+update program of one iteration
         (`_grow_and_update_multi`); the K trees' small state stays on the
         device."""
         import jax.numpy as jnp
@@ -1527,7 +1520,7 @@ class GBDT:
         return small
 
     def _fetch_class_trees(self, small, iteration, dispatch, shrink=None):
-        """`_fetch_tree` for the class-vmapped program: ONE `device_get`
+        """`_fetch_tree` for the multiclass program: ONE `device_get`
         of the K trees' small state (a leading class axis on every field),
         then the K trees, shrunk by the dispatch-time rate where given.
         Multiclass has no boost-from-average bias to fold (GBDT.init)."""
@@ -1551,7 +1544,7 @@ class GBDT:
     def _fetch_iteration(self, small, iteration, dispatch, shrink,
                          fold_bias=False):
         """The trees of one dispatched iteration program and the arguments
-        of `_log_pass_economics`: the K class trees of the class-vmapped
+        of `_log_pass_economics`: the K class trees of the multiclass
         program, or the one tree of the one-class program."""
         if self.num_tree_per_iteration > 1:
             return self._fetch_class_trees(small, iteration, dispatch, shrink)
@@ -1597,39 +1590,45 @@ class GBDT:
         full against compacted passes are told from `pass_rows`, which
         the fetch already carried. Called once the tree is appended:
         `build_tree_s` runs from the end of the fetch to here."""
-        # the class-vmapped program's state has a leading class axis: its
-        # loop ran as many passes as the class tree that needed the most,
-        # whose own counters therefore cover every pass
+        # the multiclass program's state has a leading class axis, one
+        # class tree after another: a count of the record is the mean over
+        # its class trees, so `classes` x it is what the program ran
         class_passes = tuple(int(p) for p in np.ravel(host_state.num_passes))
-        lane = host_state
-        if len(class_passes) > 1:
-            lane = _class_state(host_state, int(np.argmax(class_passes)))
-        num_passes = int(lane.num_passes)
+        trees = [host_state] if np.ndim(host_state.num_passes) == 0 else [
+            _class_state(host_state, c) for c in range(len(class_passes))]
+
+        def per_tree(values):
+            return values[0] if len(values) == 1 else sum(values) / len(values)
+
         comm_elems = float(np.sum(getattr(host_state, "comm_elems", 0.0)))
         cfg = self._grower_cfg if self._dist_grower is None \
             else self._dist_grower.cfg
         shards = max(1, cfg.num_data_shards)
         cap = shards * compact_capacity(cfg, self._n_pad // shards)
-        pass_rows = getattr(lane, "pass_rows", ())
-        full, compacted, gathered = telemetry.layers.split_passes(
-            pass_rows, num_passes, cap)
-        self._warn_count_cell(lane)
-        rec = telemetry.TreeRecord(
-            num_passes=num_passes,
-            table_high_water=int(np.max(host_state.next_free)),
+        counts = []
+        for tree in trees:
+            n, rows = int(tree.num_passes), getattr(tree, "pass_rows", ())
             # the per-pass int32 counts summed here: the device's own
             # float32 total rounds past 2^24 and an int32 one would pass
             # 2^31 (84M rows x 28 passes)
-            rows_contracted=float(np.sum(pass_rows[:num_passes],
-                                         dtype=np.int64)),
+            counts.append((float(np.sum(rows[:n], dtype=np.int64)),
+                           *telemetry.layers.split_passes(rows, n, cap)))
+        contracted, full, compacted, gathered = zip(*counts)
+        # every class tree's root counts the same rows
+        self._warn_count_cell(trees[0])
+        rec = telemetry.TreeRecord(
+            num_passes=per_tree(class_passes),
+            table_high_water=int(np.max(host_state.next_free)),
+            rows_contracted=per_tree(contracted),
             comm_elems=comm_elems,
             # element count -> wire bytes: every exchanged histogram
             # element is 4 bytes (f32, or the exact int32 domain under
             # tpu_hist_quantize — where the constant-hessian channel
             # elision already shrank comm_elems itself by red_ch/3)
             comm_bytes=comm_elems * 4.0,
-            full_passes=full, compact_passes=compacted,
-            rows_indexed=compacted * self._n_pad, rows_gathered=gathered,
+            full_passes=per_tree(full), compact_passes=per_tree(compacted),
+            rows_indexed=per_tree(compacted) * self._n_pad,
+            rows_gathered=per_tree(gathered),
             dispatch_s=dispatch[0], fetch_wait_s=fetch_wait_s,
             build_tree_s=0.0 if t_fetched is None
             else time.perf_counter() - t_fetched,

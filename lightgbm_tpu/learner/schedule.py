@@ -230,16 +230,16 @@ RELABEL_BLOCK = 262144
 
 
 def relabel_rows(groups: int, max_bins: int, batch_k: int,
-                 rows_padded: int, classes: int = 1) -> int:
+                 rows_padded: int) -> int:
     """Rows a step of the blocked relabel routes on a shard of
     `rows_padded` rows of `groups` stored groups at `batch_k` nodes a
     pass; 0 means the column form (`grow.GrowerConfig.relabel_rows`).
     Blocked where the readings above say it is the cheaper: linear
     between the measured widths and node counts, flat past the node
     counts. No reading is trusted outside the widths or under 8 nodes,
-    nor for bins past uint8 (the one-hot product is exact to 255) or the
-    vmapped class trees, which were not run: those keep the column form."""
-    if (max_bins > 256 or classes > 1 or batch_k < _RELABEL_NODES[0]
+    nor for bins past uint8 (the one-hot product is exact to 255): those
+    keep the column form."""
+    if (max_bins > 256 or batch_k < _RELABEL_NODES[0]
             or groups > _RELABEL_GROUPS[-1]):
         return 0
     column = np.interp(batch_k, _RELABEL_NODES, _RELABEL_COLUMN_NS)
@@ -269,17 +269,19 @@ class Schedule(NamedTuple):
 
 
 def pick_schedule(groups: int, max_bins: int, rows: int, rows_padded: int,
-                  chunk: int, *, num_leaves: int, classes: int = 1,
+                  chunk: int, *, num_leaves: int,
                   learner: str = "serial", bundled: bool = False,
                   quantize: str = "none",
                   compact_fraction: Optional[float] = None,
                   device_bytes: int = 0,
                   cache_groups: Optional[int] = None) -> Schedule:
-    """The execution schedule as a function of the shape: stored groups,
-    bins of the widest group, rows and padded rows of ONE shard, and the
-    histogram chunk (`plan_row_layout` gives the last two); of the
-    learner kind; and of the device: `device_bytes` is its memory, 0
-    where the backend reports none (`subtract_cache_budget`).
+    """The execution schedule of ONE tree's program as a function of the
+    shape: stored groups, bins of the widest group, rows and padded rows
+    of ONE shard, and the histogram chunk (`plan_row_layout` gives the
+    last two); of the learner kind; and of the device: `device_bytes` is
+    its memory, 0 where the backend reports none (`subtract_cache_budget`).
+    A multiclass iteration runs it once a class tree, one after another
+    (`gbdt._grow_and_update_multi_impl`).
     `cache_groups` is the width the subtraction cache has on one device
     where that is not the shape's: the data-parallel learner under the
     scatter merge keeps its owned slice, ceil(groups / shards) of the
@@ -295,20 +297,19 @@ def pick_schedule(groups: int, max_bins: int, rows: int, rows_padded: int,
     ones."""
     wide = groups * max_bins > 8192
     # sibling subtraction: the per-node [M, G, B, 3] histogram cache must
-    # fit the budget (vmap'd class trees each carry their own cache).
-    # Node-table size rides on it: generous tables keep late-boosting
-    # speculation wide (grow.py table notes), so where slots are cheap
-    # take the largest table_mult in [6, 12] whose cache stays inside the
-    # fixed 256 MB; a cache past that, which only a device that reports
-    # room allows, takes 6: on the run above trees 3-4 filled 509-527 of
-    # its 1,548 slots, and 12 read the same passes for 2.8 GB more.
-    # Without the cache the table is [M]-scalar cheap: take the max.
+    # fit the budget. Node-table size rides on it: generous tables keep
+    # late-boosting speculation wide (grow.py table notes), so where slots
+    # are cheap take the largest table_mult in [6, 12] whose cache stays
+    # inside the fixed 256 MB; a cache past that, which only a device that
+    # reports room allows, takes 6: on the run above trees 3-4 filled
+    # 509-527 of its 1,548 slots, and 12 read the same passes for 2.8 GB
+    # more. Without the cache the table is [M]-scalar cheap: take the max.
     if cache_groups is None:
         cache_groups = groups
 
     def cache_fits(mult: int, device: int) -> bool:
         return subtract_cache_fits(cache_groups, max_bins, num_leaves, mult,
-                                   classes=classes, rows_padded=rows_padded,
+                                   rows_padded=rows_padded,
                                    device_bytes=device)
 
     mult_fit = next((m for m in range(12, 5, -1)
@@ -323,16 +324,8 @@ def pick_schedule(groups: int, max_bins: int, rows: int, rows_padded: int,
     # (grow.py), and the feature-parallel learner has never run with it:
     # both keep the direct path, and consent here would only widen
     # `batch_k` for a cache that is not built.
-    subtract = (learner in ("serial", "data")
-                # vmap'd class trees would each carry a cache: x classes
-                # its memory and its scatter traffic. Never run on the
-                # chip with it; `covtype-multiclass-1chip` measures the
-                # direct path (PERF.md section 5, PR 40)
-                and classes == 1
-                and mult_fit > 0)
-    # vmap'd class trees multiply every [M]-sized table op by the
-    # classes, so they take the smaller table (12 was not run there)
-    table_mult = mult_fit if subtract else (6 if classes > 1 else 12)
+    subtract = learner in ("serial", "data") and mult_fit > 0
+    table_mult = mult_fit if subtract else 12
     # gather-compacted small-node contraction: wherever the grower would
     # hold a buffer for it (`compact_capacity`'s guards: rows locally
     # resident, more than one chunk of them) and the shape's pass costs
@@ -342,16 +335,12 @@ def pick_schedule(groups: int, max_bins: int, rows: int, rows_padded: int,
     # break-even of a full pass against an index build plus gathers
     # (`compact_threshold`). A narrow table never reaches it: at 28
     # groups x 63 bins the index build alone costs 2.7 full passes, and
-    # the fraction comes out 0. Multiclass is excluded like subtraction:
-    # the vmap over class trees batches the per-pass cond predicate,
-    # which under jax's cond batching rule executes BOTH histogram
-    # kernels every pass.
+    # the fraction comes out 0.
     model = compact_threshold(groups, max_bins, rows, rows_padded)
     if compact_fraction is None:
         compact_fraction = model.fraction
-    compact = (classes == 1
-               and _compact_rows(rows_padded, chunk, compact_fraction,
-                                 learner == "feature") > 0)
+    compact = _compact_rows(rows_padded, chunk, compact_fraction,
+                            learner == "feature") > 0
     if subtract:
         # one smaller-child channel set per node: 24 x (3 hi + 2 lo)
         # fills the 128-lane tile. Under the data axis as on one chip:

@@ -201,10 +201,10 @@ class TreeRecord(NamedTuple):
     backend_s: float = 0.0      # XLA compile, or the persistent cache's load
     cache_misses: int = 0       # programs compiled and written to the cache
     # the class trees of one multiclass iteration are ONE record: the
-    # vmapped program grows them together, its `while` loop running until
-    # the last of them has finished (`num_passes` is that loop's passes,
-    # the largest of `class_passes`; the row counts are the rows one class
-    # tree contracted over those passes)
+    # program grows them one after another, and every count above is the
+    # MEAN over its class trees, so `classes` x a count is the program's
+    # (x `num_passes` = the sum of `class_passes`, x `rows_contracted` =
+    # the rows it contracted); a count of one class tree is an int
     classes: int = 1            # trees the record's program grew
     class_passes: Tuple[int, ...] = ()  # the passes each of them needed
 

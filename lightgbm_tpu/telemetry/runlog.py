@@ -274,7 +274,7 @@ class TrainRecorder:
             return {}
         return {
             "trees": len(new),
-            "num_passes": sum(int(p[0]) for p in new),
+            "num_passes": sum(p[0] for p in new),
             "table_high_water": max(int(p[1]) for p in new),
             "rows_contracted": sum(float(p[2]) for p in new if len(p) > 2),
             "comm_elems": sum(float(p[3]) for p in new if len(p) > 3),

@@ -18,10 +18,11 @@ before a chip call is spent, in `<out>/<name>.hlo.txt` too; times are
 not. PR 37 found three faults this way that a kernel timed alone on the
 chip had not shown (`PERF.md` section 6). Serial learner only; about a
 minute for the HIGGS configuration, two for Epsilon's (`--small 131072`).
-A configuration with `num_class` > 1 compiles the class-vmapped program
-of a multiclass iteration (`_grow_and_update_multi_impl`) instead. The
-program's bytes on the chip (`memory_analysis()`: arguments, outputs,
-temporaries) are printed last.
+A configuration with `num_class` > 1 compiles the program of a
+multiclass iteration (`_grow_and_update_multi_impl`: the class trees one
+after another, each on the one-tree schedule) instead. The program's
+bytes on the chip (`memory_analysis()`: arguments, outputs, temporaries)
+are printed last.
 """
 from __future__ import annotations
 
@@ -74,12 +75,11 @@ def main(argv=None) -> int:
     classes = int(params.get("num_class", 1))
     picked = pick_schedule(groups, cfg.max_bins, rows, layout.n_pad,
                            layout.chunk, num_leaves=cfg.num_leaves,
-                           classes=classes, device_bytes=V5E_BYTES,
-                           cache_groups=groups)
+                           device_bytes=V5E_BYTES, cache_groups=groups)
     cfg = cfg._replace(
         **picked.grower_fields(layout.chunk),
         relabel_rows=relabel_rows(groups, cfg.max_bins, picked.batch_k,
-                                  layout.n_pad, classes=classes))
+                                  layout.n_pad))
     print(f"{rows} rows padded to {layout.n_pad}; {cfg}", flush=True)
 
     topo = topologies.get_topology_desc(platform="tpu",

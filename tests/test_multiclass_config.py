@@ -17,8 +17,9 @@ import jax
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu import telemetry
-from lightgbm_tpu.learner.schedule import (pick_schedule, plan_row_layout,
-                                           relabel_rows)
+from lightgbm_tpu.learner.schedule import (RELABEL_BLOCK, pick_schedule,
+                                           plan_row_layout, relabel_rows,
+                                           subtract_cache_bytes)
 from lightgbm_tpu.telemetry import devtrace, layers
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -202,7 +203,10 @@ def test_pipelined_multiclass_equals_the_synchronous_loop(table):
         assert piped.classes == synced.classes == 7
         assert piped.class_passes == synced.class_passes
         assert len(piped.class_passes) == 7
-        assert piped.num_passes == max(piped.class_passes)
+        # a count of the record is the mean over the class trees grown one
+        # after another: x classes it is what the program ran
+        assert piped.classes * piped.num_passes == pytest.approx(
+            sum(piped.class_passes), rel=1e-15)
         assert piped.rows_contracted == synced.rows_contracted
         assert piped.full_passes == piped.num_passes
         assert piped.compact_passes == piped.rows_gathered == 0
@@ -251,28 +255,73 @@ def test_a_validation_set_keeps_multiclass_synchronous(table):
     assert [r.classes for r in inner.pass_log] == [7, 7]
 
 
+def test_the_class_trees_are_k_calls_of_the_one_class_program(table):
+    """The multiclass program grows class tree c as the one-class
+    grow+update does on class c's gradients under the same
+    `GrowerConfig`, one class after another: the same trees to the bit
+    (model text), the same small state, the same score."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.boosting import gbdt
+    from lightgbm_tpu.learner.grow import FMETA_KEYS
+    from lightgbm_tpu.tree import Tree
+
+    booster = _booster(table, True)
+    inner = booster._inner
+    booster.update()                 # a score at which the classes differ
+    k, cfg = 7, inner._grower_cfg
+    assert cfg.hist_subtract and cfg.relabel_rows > 0
+    grad, hess = inner._compute_gradients(inner._score)
+    grad, hess = grad.reshape(k, -1), hess.reshape(k, -1)
+    rng = np.random.default_rng(41)
+    masks = rng.random((k, inner._num_features_padded)) < 0.8
+    fmeta = [inner._fmeta[key] for key in FMETA_KEYS]
+    common = (inner._binned, grad, hess, inner._base_weight,
+              jnp.asarray(masks), 0.1, inner._n, fmeta)
+    score, small = gbdt._grow_and_update_multi(inner._score, *common, cfg)
+    multi = gbdt._HostState(jax.device_get(small))
+
+    one_score = inner._score
+    for c in range(k):
+        one_score, one = gbdt._grow_and_update(
+            one_score, inner._binned, grad[c], hess[c], inner._base_weight,
+            jnp.asarray(masks[c]), 0.1, inner._n, fmeta, c, cfg)
+        one = jax.device_get(one)
+        mine = gbdt._class_state(multi, c)
+        for key, value in one.items():
+            np.testing.assert_array_equal(getattr(mine, key), value,
+                                          err_msg=f"class {c}: {key}")
+        text = Tree.from_grower_state(mine, inner.train_data).to_string()
+        assert text == Tree.from_grower_state(
+            gbdt._HostState(one), inner.train_data).to_string()
+        assert int(mine.num_leaves_used) > 1
+    np.testing.assert_array_equal(np.asarray(score), np.asarray(one_score))
+
+
 # ---------------------------------------------------------------------------
 # (c) the schedule of the configuration's own shape
 # ---------------------------------------------------------------------------
 def test_the_cells_shape_takes_the_direct_path():
+    """A schedule is one tree's: the cell's 7 class trees run one after
+    another, each on the one-class schedule of 14 groups x 81 bins, so
+    each takes the subtraction cache at 24 nodes a pass and the blocked
+    relabel, as the one-class cells do; the direct path is gone."""
     config = harness.load_cell(CELL)["config"]
     rows = int(config["rows"])
     layout = plan_row_layout(rows, 14, 81)
     assert (layout.n_pad, layout.chunk) == (rows, 65536)   # no padded row
     picked = pick_schedule(14, 81, rows, rows, 65536, num_leaves=255,
-                           classes=7, learner="serial", bundled=True,
+                           learner="serial", bundled=True,
                            device_bytes=V5E_BYTES, cache_groups=14)
-    # no subtraction cache, no compaction, the smaller node table
+    # the cache with the larger node table, no compaction
     assert (picked.subtract, picked.compact, picked.table_mult) == (
-        False, False, 6)
-    assert picked.batch_k == 12
-    # the column relabel: the blocked one was never run under the vmap
-    assert relabel_rows(14, 81, picked.batch_k, rows, classes=7) == 0
-    assert relabel_rows(14, 81, picked.batch_k, rows, classes=1) > 0
-    one = pick_schedule(14, 81, rows, rows, 65536, num_leaves=255,
-                        classes=1, learner="serial", bundled=True,
-                        device_bytes=V5E_BYTES, cache_groups=14)
-    assert one.subtract
+        True, False, 12)
+    assert picked.batch_k == 24
+    assert picked.compact_model.fraction == 0.0
+    # (12 x 255 + 52) slots x 14 groups x 81 bins x 3 float32: 42 MB, one
+    # class tree's, which the program holds once for the seven
+    assert subtract_cache_bytes(14, 81, 255, picked.table_mult) \
+        == 42_348_096
+    assert relabel_rows(14, 81, picked.batch_k, rows) == RELABEL_BLOCK
 
 
 # ---------------------------------------------------------------------------
@@ -301,6 +350,42 @@ def _reader(name):
     return datagen.load_file_module(
         os.path.join(BENCH, "layer_metrics", name + ".py"),
         "reader_" + name.replace(".", "_")).read
+
+
+def test_a_sequential_iterations_record_counts_what_the_program_ran(table):
+    """One record of K class trees grown one after another: `classes` x
+    `num_passes` is the sum of `class_passes` and `classes` x
+    `rows_contracted` the rows the K trees contracted, so no pass is
+    counted for a finished tree and `grower.class_pass_waste` reads 0."""
+    from lightgbm_tpu.boosting import gbdt
+    booster = _booster(table, True)
+    inner = booster._inner
+    width = 8
+    rows = np.zeros((3, width), np.int32)
+    rows[0, :2] = (3000, 1200)
+    rows[1, :4] = (3000, 1700, 900, 250)
+    rows[2, :3] = (3000, 800, 333)
+    ones = np.ones(3)
+    inner._log_pass_economics(gbdt._HostState({
+        "num_passes": np.asarray([2, 4, 3], np.int32), "pass_rows": rows,
+        "next_free": np.asarray([5, 11, 7], np.int32),
+        "comm_elems": 0 * ones, "root_cell_max": 0 * ones,
+        "root_cell_at": np.zeros(3, np.int32)}))
+    rec = inner.pass_log[-1]
+    assert (rec.classes, rec.class_passes) == (3, (2, 4, 3))
+    assert rec.num_passes == 3.0 and rec.full_passes == 3.0
+    assert 3 * rec.rows_contracted == pytest.approx(
+        4200 + 5850 + 4133, rel=1e-15)
+    assert rec.table_high_water == 11
+    assert rec.compact_passes == rec.rows_gathered == 0
+
+    for _ in range(3):
+        booster.update()
+    window = [list(r) for r in inner.pass_log[1:]]
+    assert all(r[15] == 7 for r in window)
+    read = _reader("grower.class_pass_waste")
+    assert read({"pass_log_window": window,
+                 "traced_iterations": [0, 3]}) == pytest.approx(0.0, abs=1e-12)
 
 
 def _record(**values):

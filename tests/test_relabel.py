@@ -208,8 +208,9 @@ def test_zero_weight_padded_rows_keep_to_the_rule(block):
 
 @pytest.mark.parametrize("K,block", [(8, 0), (12, 0), (24, 256)])
 def test_route_under_vmap_as_the_class_trees(K, block):
-    """A multiclass iteration grows its class trees under `jax.vmap`:
-    labels, splits and slots carry a class axis, the bins do not."""
+    """A sweep grows its models' class trees under `jax.vmap`
+    (`learner/sweep.py`): labels, splits and slots carry a class axis,
+    the bins do not."""
     classes, n = 3, 900
     binned, fm, (lid, table, sel, valid, cl, cr) = _case(
         31 + K, n, K, classes=classes)
@@ -338,7 +339,6 @@ def test_where_the_relabel_form_turns_on_the_cells(groups, rows, want_k,
 ])
 def test_where_the_relabel_form_turns(groups, max_bins, batch_k, rows, want):
     assert relabel_rows(groups, max_bins, batch_k, rows) == want
-    assert relabel_rows(groups, max_bins, batch_k, rows, classes=3) == 0
 
 
 def test_the_blocked_form_refuses_bins_past_uint8():
@@ -351,7 +351,8 @@ def test_the_blocked_form_refuses_bins_past_uint8():
 
 @pytest.mark.parametrize("params,form", [
     ({}, "blocked"),                                  # the cache: 24 a pass
-    ({"num_class": 3, "objective": "multiclass"}, "columns"),   # 12 a pass
+    # the class trees one after another, each with the cache: 24 a pass
+    ({"num_class": 3, "objective": "multiclass"}, "blocked"),
 ])
 def test_schedule_info_names_the_relabel_form(params, form):
     """`schedule_info["relabel"]`: the form a run took and, blocked, its

@@ -124,34 +124,38 @@ NARROW, WIDE = (28, 63, 25_165_824), (2000, 63, 1 << 20)
         (WIDE, "serial", 1, True, 8, 6, True),
         (WIDE, "data", 1, True, 8, 6, True),
         # voting drops the cache itself (grow.py), the feature-parallel
-        # learner never ran with it, class trees lose by it: the direct
-        # path, as the parent of PR 33 gave every one of these
+        # learner never ran with it: the direct path, as the parent of PR
+        # 33 gave every one of these
         (NARROW, "voting", 1, False, 12, 12, False),
         (NARROW, "feature", 1, False, 12, 12, False),
         (WIDE, "voting", 1, False, 12, 12, True),
         (WIDE, "feature", 1, False, 12, 12, False),
-        (NARROW, "serial", 3, False, 12, 6, False),
-        (NARROW, "data", 3, False, 12, 6, False),
-        (NARROW, "voting", 3, False, 12, 6, False),
-        (NARROW, "feature", 3, False, 12, 6, False),
-        (WIDE, "serial", 3, False, 12, 6, False),
-        (WIDE, "data", 3, False, 12, 6, False),
-        (WIDE, "voting", 3, False, 12, 6, False),
-        (WIDE, "feature", 3, False, 12, 6, False)])
+        # class trees run one after another, each on one tree's schedule:
+        # the one-class answer of their learner
+        (NARROW, "serial", 3, True, 24, 12, False),
+        (NARROW, "data", 3, True, 24, 12, False),
+        (NARROW, "voting", 3, False, 12, 12, False),
+        (NARROW, "feature", 3, False, 12, 12, False),
+        (WIDE, "serial", 3, True, 8, 6, True),
+        (WIDE, "data", 3, True, 8, 6, True),
+        (WIDE, "voting", 3, False, 12, 12, True),
+        (WIDE, "feature", 3, False, 12, 12, False)])
 def test_which_learner_takes_the_cache(shape, learner, classes, subtract,
                                        batch_k, table_mult, compact):
     """Learner kind x class trees x shape on the v5e's memory, at 255
-    leaves: one shard's shape as `GBDT.init` hands it over."""
+    leaves: one shard's shape as `GBDT.init` hands it over. A job of
+    `classes` trees an iteration asks the same question: the schedule is
+    one tree's, and its class trees take it one after another."""
     groups, bins, rows = shape
     layout = plan_row_layout(rows, groups, bins)
     picked = pick_schedule(groups, bins, rows, layout.n_pad, layout.chunk,
-                           num_leaves=255, classes=classes, learner=learner,
+                           num_leaves=255, learner=learner,
                            device_bytes=V5E_BYTES)
     assert (picked.subtract, picked.batch_k, picked.table_mult,
             picked.compact) == (subtract, batch_k, table_mult, compact)
     assert picked.wide == (shape is WIDE)
     assert picked.compact_fraction == (0.25 if shape is WIDE else 0.0)
-    if learner == "data" and classes == 1:
+    if learner == "data":
         # the data learner's answer is the serial learner's for the shape
         assert picked == pick_schedule(
             groups, bins, rows, layout.n_pad, layout.chunk, num_leaves=255,
