@@ -19,7 +19,7 @@ import numpy as np
 
 class RowLayout(NamedTuple):
     chunk: int          # histogram row-chunk the grower will use
-    row_multiple: int   # rows per padding granule (chunk x device factor)
+    row_multiple: int   # chunk x the devices this process shards rows over
     n_pad: int          # padded row count (this process)
     ndev: int           # device count the plan assumed
     local_dev: int      # local devices per process
@@ -31,11 +31,18 @@ def plan_row_layout(n: int, num_groups: int, max_num_bin: int, *,
                     ndev: int = 1, nproc: int = 1) -> RowLayout:
     """The padded-row plan of GBDT.init (boosting/gbdt.py): histogram
     chunk capped by the group-block budget, rows padded to a chunk (x
-    shard) multiple, then bucketed into coarse power-of-two granules so
-    nearby row counts share one compiled signature. A landing padded by
-    this plan is byte-compatible with what the trainer would have padded
-    itself. Multi-process callers must still allgather-max the result
-    across ranks."""
+    shard) multiple. A layout on one shard (serial, feature-parallel, or
+    one device) is then bucketed into coarse power-of-two granules so
+    nearby row counts share one compiled signature. A row-sharded layout
+    (`data` or `voting` over more than one device) is not: its padding is
+    one suffix of a process's rows, so a granule's padding would all sit
+    on the last shards while every shard is stepped at the pace of a full
+    one. It pads to one row multiple (chunk x the process's devices), so
+    the shards hold real rows to within that many chunks of each other,
+    and each such row count compiles a program of its own. A landing
+    padded by this plan is byte-compatible with what the trainer would
+    have padded itself. Multi-process callers must still allgather-max
+    the result across ranks."""
     kind = tree_learner if tree_learner in ("data", "feature", "voting") \
         else "serial"
     if kind == "serial":
@@ -46,10 +53,11 @@ def plan_row_layout(n: int, num_groups: int, max_num_bin: int, *,
     target = max(1, (16 << 26) // gb)
     chunk = min(chunk, max(8192, 1 << int(np.floor(np.log2(target)))))
     chunk = int(min(chunk, max(256, 1 << int(np.ceil(np.log2(max(n, 1)))))))
+    row_sharded = kind in ("data", "voting") and ndev > 1
     row_multiple = chunk * (local_dev if nproc > 1 else ndev) \
         if kind in ("data", "voting") else chunk
     m_count = (n + row_multiple - 1) // row_multiple
-    if m_count > 1:
+    if m_count > 1 and not row_sharded:
         p2 = 1 << (m_count - 1).bit_length()
         g = max(1, p2 // 8)
         m_count = ((m_count + g - 1) // g) * g

@@ -284,9 +284,12 @@ def test_one_shards_shape_takes_the_cache():
     bins = int(config["params"]["max_bin"])
     leaves = int(config["params"]["num_leaves"])
     layout = plan_row_layout(rows, groups, bins, tree_learner="data", ndev=4)
-    assert (layout.chunk, layout.n_pad) == (65536, 100_663_296)
-    # each device holds the padded shard higgs-train-1chip holds
-    assert layout.n_pad // 4 == plan_row_layout(21_000_000, 28, 63).n_pad
+    # one row multiple of padding, not the one-shard layout's granule:
+    # a shard of 321 chunks, where higgs-train-1chip pads to 384
+    assert (layout.chunk, layout.n_pad) == (65536, 84_148_224)
+    assert layout.n_pad - rows < layout.row_multiple == 4 * 65536
+    assert layout.n_pad // 4 == 21_037_056 == 321 * 65536
+    assert plan_row_layout(21_000_000, 28, 63).n_pad == 384 * 65536
     shards = layout.row_multiple // layout.chunk
     assert shards == 4
     shape = (groups, bins, rows // shards, layout.n_pad // shards,
@@ -308,11 +311,64 @@ def test_one_shards_shape_takes_the_cache():
     at_every_group = subtract_cache_bytes(groups, bins, leaves, 12)
     assert (owned_groups, at_owned_slice) == (7, 16_468_704)
     assert at_every_group == 65_874_816 == 4 * at_owned_slice
-    # padding is a global suffix: the real rows each device holds
+    # padding is a global suffix: the real rows each device holds, the
+    # last short by under a chunk (the configuration's `assumed` block
+    # tells of the granule-padded layout: 25,165,824 x 3 + 8,502,528)
     per = layout.n_pad // 4
     real = [min(per, max(0, rows - d * per)) for d in range(4)]
-    assert real == [25_165_824] * 3 + [8_502_528]
-    assert config["assumed"]["rows_per_device"] == real
+    assert real == [21_037_056] * 3 + [20_888_832]
+    assert sum(real) == rows
+
+
+# (rows, stored groups, widest bins) of each cell's shape and a few
+# thousand rows; `CELL_PADS` pins what the one-shard layouts pad them to
+LAYOUT_SHAPES = [(84_000_000, 28, 63), (21_000_000, 28, 63),
+                 (14_680_064, 14, 81), (29_360_128, 11, 255),
+                 (12_582_912, 137, 63), (1_048_576, 2000, 63),
+                 (6_000, 12, 63)]
+CELL_PADS = {21_000_000: 25_165_824, 14_680_064: 14_680_064,
+             29_360_128: 29_360_128, 12_582_912: 12_582_912,
+             1_048_576: 1_048_576}
+
+
+def _granule_pad(rows, row_multiple):
+    """Rows padded to a row multiple, then up to a coarse power-of-two
+    granule of them: the one-shard layouts' plan."""
+    m = -(-rows // row_multiple)
+    if m > 1:
+        g = max(1, (1 << (m - 1).bit_length()) // 8)
+        m = -(-m // g) * g
+    return m * row_multiple
+
+
+@pytest.mark.parametrize("rows, groups, bins", LAYOUT_SHAPES)
+@pytest.mark.parametrize("ndev", [1, 2, 4, 8])
+@pytest.mark.parametrize("learner", ["serial", "data", "voting", "feature"])
+def test_a_row_sharded_layout_pads_one_row_multiple(learner, ndev, rows,
+                                                    groups, bins):
+    """Rows sharded over devices pad to one row multiple, so the last
+    shard is short by under `ndev` chunks; every other layout keeps the
+    granule, and the one-chip cells their padded rows."""
+    from lightgbm_tpu.telemetry import layers
+    layout = plan_row_layout(rows, groups, bins, tree_learner=learner,
+                             ndev=ndev)
+    assert layout.n_pad % layout.row_multiple == 0
+    if learner in ("data", "voting") and ndev > 1:
+        assert layout.row_multiple == ndev * layout.chunk
+        assert 0 <= layout.n_pad - rows < layout.row_multiple
+        real = layers.shard_rows(rows, layout.n_pad, ndev)
+        assert sum(real) == rows
+        shard = layout.n_pad // ndev
+        if shard > ndev * layout.chunk:
+            assert min(real) / max(real) >= 1 - ndev * layout.chunk / shard
+        if (rows, ndev) == (84_000_000, 4):          # higgs-train-dp4
+            assert layout.n_pad == 84_148_224
+            assert min(real) / max(real) > 0.99
+    else:
+        assert layout.row_multiple == layout.chunk
+        assert layout.n_pad == _granule_pad(rows, layout.chunk)
+        if rows in CELL_PADS and learner == "serial":
+            assert layout.n_pad == CELL_PADS[rows]
 
 
 def test_the_merge_reader_reads_the_record_and_nothing_on_one_device():
@@ -546,6 +602,79 @@ def test_the_trained_data_learner_keeps_the_cache_and_the_serial_trees(
             == want.rows_contracted
         assert (rec.full_passes, rec.compact_passes) == (rec.num_passes, 0)
         assert rec.comm_bytes > 0 == want.comm_bytes
+
+
+# One row past 16 row multiples of 4 x 256: the power-of-two granule
+# padded this to 20 of them and left the last shard 1,025 real rows of
+# 5,120; one row multiple of padding leaves it 3,329 of 4,352
+EVEN_ROWS, EVEN_CHUNK, EVEN_SHARDS = 16_385, 256, (4_352,) * 3 + (3_329,)
+
+
+def _even_data():
+    rng = np.random.RandomState(9)
+    X = rng.randn(EVEN_ROWS, 12).astype(np.float32)
+    y = (X[:, 0] - 0.7 * X[:, 1] * X[:, 4] + 0.5 * rng.randn(EVEN_ROWS)
+         > 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 31, "max_bin": 63,
+              "min_data_in_leaf": 5, "learning_rate": 0.1,
+              "tpu_hist_chunk": EVEN_CHUNK, "verbose": 0}
+    return X, y, params
+
+
+def test_the_data_learner_evens_its_shards_and_keeps_the_serial_trees(
+        sharded):
+    """`tree_learner=data` on four devices at a row count whose granule
+    left the last shard mostly padding: the shards hold real rows to
+    within four chunks, the serial learner keeps its granule, and the
+    trees are the serial learner's."""
+    import lightgbm_tpu as lgb
+    X, y, params = _even_data()
+    inners = {}
+    for learner in ("data", "serial"):
+        p = dict(params, tree_learner=learner)
+        inners[learner] = lgb.train(p, lgb.Dataset(X, y, params=p),
+                                    num_boost_round=3,
+                                    verbose_eval=False)._inner
+    dp, serial = inners["data"], inners["serial"]
+    assert dp._n_pad == 17 * 4 * EVEN_CHUNK
+    assert serial._n_pad == 80 * EVEN_CHUNK
+    shards = dp.init_record.shard_rows
+    assert shards == EVEN_SHARDS and sum(shards) == EVEN_ROWS
+    assert max(shards) - min(shards) < 4 * EVEN_CHUNK
+    assert serial.init_record.shard_rows == (EVEN_ROWS,)
+    assert len(dp.models) == len(serial.models) == 3
+    for a, b in zip(dp.models, serial.models):
+        a, b = (sharded.mode.tree_arrays(t) for t in (a, b))
+        for key in ("split_feature", "threshold", "left_child",
+                    "right_child", "internal_count", "leaf_count"):
+            np.testing.assert_array_equal(a[key], b[key])
+        assert int(a["leaf_count"].sum()) == EVEN_ROWS
+        np.testing.assert_allclose(a["leaf_value"], b["leaf_value"],
+                                   rtol=1e-4, atol=1e-7)
+
+
+def test_a_sharded_landing_of_the_even_layout_is_reused(sharded):
+    """`tpu_ingest_device_shards` plans its landing with the trainer's
+    row plan, so the landed shards are the trainer's matrix as they
+    stand: no gather to the host, no re-pad."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu import log
+    X, y, params = _even_data()
+    params = dict(params, tree_learner="data")
+    lines = []
+    log.register_callback(lines.append)
+    try:
+        ds = lgb.Dataset(X, y, params=dict(
+            params, tpu_ingest_device_shards=True))
+        landed = ds.construct()._inner.device_binned
+        assert landed is not None and landed.shape[0] == 17 * 4 * EVEN_CHUNK
+        inner = lgb.Booster(params, ds)._inner
+    finally:
+        log.register_callback(None)
+    assert not [line for line in lines if "re-padding" in line]
+    assert inner._binned is landed
+    assert inner.init_record.shard_rows == EVEN_SHARDS
+    assert len(inner._binned.addressable_shards) == 4
 
 
 def test_the_landing_span_is_in_the_profilers_trace(sharded, tmp_path):

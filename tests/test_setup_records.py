@@ -109,6 +109,7 @@ def test_shard_rows_under_the_data_learner_are_uneven():
 @pytest.mark.parametrize("rows, padded, shards, want", [
     (21_000_000, 25_165_824, 1, (21_000_000,)),
     (84_000_000, 100_663_296, 4, (25_165_824,) * 3 + (8_502_528,)),
+    (84_000_000, 84_148_224, 4, (21_037_056,) * 3 + (20_888_832,)),
     (8, 8, 4, (2, 2, 2, 2)),
     (3, 8, 4, (2, 1, 0, 0)),
 ])
