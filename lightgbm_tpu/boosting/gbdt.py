@@ -4,9 +4,11 @@ TPU-native re-implementation of the reference GBDT engine
 (`src/boosting/gbdt.{h,cpp}` — TrainOneIter at gbdt.cpp:380-474): owns the
 tree learner, per-class scores, gradients, bagging, early stopping, model
 (de)serialization and prediction. The training set lives on device as a
-padded binned matrix; one `TrainOneIter` runs gradients (objective kernel),
-bagging weight sampling, and `num_class` jitted tree growths, then updates
-train/valid scores with vectorized leaf lookups.
+padded binned matrix; one `TrainOneIter` runs gradients (objective kernel)
+and bagging weight sampling, dispatches the iteration's tree growths with
+the training score's update on the device, and flushes them into host
+trees and the validation scores, pipelined one iteration behind where it
+can be.
 """
 from __future__ import annotations
 
@@ -194,7 +196,7 @@ def _device_memory_bytes(device) -> int:
 
 
 def _dispatched(t_enter: float, compiled):
-    """What one tree's enqueue took, for its `telemetry.TreeRecord`:
+    """What one iteration's enqueue took, for its `telemetry.TreeRecord`:
     (`dispatch_s`, `trace_lower_s`, `backend_s`, `cache_misses`) since
     `t_enter`, a `perf_counter`, and `compiled`, the observer's `totals()`
     read at that moment. The gradient program, bagging and the grow
@@ -237,6 +239,42 @@ def _class_state(host_state, cls: int) -> _HostState:
     return _HostState({key: v[cls] for key, v in vars(host_state).items()})
 
 
+def _score_update(score, leaf_value, leaf_id, num_leaves_used, shrinkage,
+                  cls, num_leaves):
+    """Class `cls` of the training score plus one tree's shrunk leaf
+    values, looked up by each row's leaf id; nothing where the tree did
+    not split. The one score update of every learner: traced into the
+    fused grow programs, and run alone (`_score_update_device`) where
+    the learner grows without one."""
+    import jax.numpy as jnp
+    with telemetry.scope("lgbm/score/update"):
+        grew = num_leaves_used > 1
+        leaf_vals = leaf_value * shrinkage
+        delta = jnp.where(
+            grew,
+            row_lookup(leaf_vals, jnp.clip(leaf_id, 0, num_leaves - 1)), 0.0)
+        return score.at[cls].add(delta)
+
+
+_score_update_jit = None
+
+
+def _score_update_device(score, leaf_value, leaf_id, num_leaves_used,
+                         shrinkage, cls, num_leaves):
+    """`_score_update` as a device program of its own, the class index
+    traced: the score update of a learner that grows without a fused
+    program."""
+    import jax
+    import jax.numpy as jnp
+    global _score_update_jit
+    if _score_update_jit is None:
+        _score_update_jit = jax.jit(_score_update,
+                                    static_argnames=("num_leaves",))
+    return _score_update_jit(score, leaf_value, leaf_id, num_leaves_used,
+                             jnp.float32(shrinkage), jnp.int32(cls),
+                             num_leaves=num_leaves)
+
+
 def _grow_and_update_impl(score, binned, grad, hess, row_weight, fmask,
                           shrinkage, n_valid, fmeta_args, cls, cfg,
                           qscale=None, owned_feats=None):
@@ -247,19 +285,12 @@ def _grow_and_update_impl(score, binned, grad, hess, row_weight, fmask,
     dispatch + one device_get per tree instead of an eager op chain.
     Also the body of the data-parallel learners' sharded program
     (parallel/learners.py): there the rows are one shard's."""
-    import jax.numpy as jnp
-
     state = grow_tree(binned, grad, hess, row_weight, fmask, *fmeta_args,
                       cfg, n_valid=n_valid, qscale=qscale,
                       owned_feats=owned_feats)
-    with telemetry.scope("lgbm/score/update"):
-        grew = state.num_leaves_used > 1
-        leaf_vals = state.leaf_value * shrinkage
-        delta = jnp.where(
-            grew,
-            row_lookup(leaf_vals,
-                       jnp.clip(state.leaf_id, 0, cfg.num_leaves - 1)), 0.0)
-        score = score.at[cls].add(delta)
+    score = _score_update(score, state.leaf_value, state.leaf_id,
+                          state.num_leaves_used, shrinkage, cls,
+                          cfg.num_leaves)
     small = {k: getattr(state, k) for k in _SMALL_STATE_KEYS}
     return score, small
 
@@ -528,11 +559,11 @@ class GBDT:
         self.feature_names: List[str] = []
         self._eval_history: List[dict] = []
         self._stopped = False
-        # 1-deep async pipeline (serial learner, no valid sets): the
-        # grower's small tree arrays stay on device until the NEXT
-        # iteration has been dispatched, so the blocking fetch + host
-        # Tree build overlap device compute instead of serializing with
-        # it
+        # the dispatched iteration not yet flushed (train_one_iter): a
+        # pipelined call leaves its small tree arrays on the device until
+        # the NEXT iteration has been dispatched, so the blocking fetch +
+        # host Tree build overlap device compute instead of serializing
+        # with it
         self._pending_small = None
         # device-resident stacked-forest cache (serving/forest.py):
         # every ensemble mutation must go through _bump_model_version()
@@ -781,9 +812,8 @@ class GBDT:
             raw_inner = np.asarray(train_data.raw, np.float32)[
                 :, train_data.used_features]
             self._raw = jnp.asarray(_pad_to(raw_inner, n_pad))
-            # the async tree pipeline fuses grow+update into one program
-            # keyed on constant leaf outputs; the linear fit is a second
-            # program with its own score update, so run synchronous
+            # every iteration drained at once (train_one_iter), as under
+            # a validation set or explicit gradients
             self._supports_pipeline = False
 
         # scores: [num_tree_per_iteration, n_pad]
@@ -1265,7 +1295,11 @@ class GBDT:
     def train_one_iter(self, gradients: Optional[np.ndarray] = None,
                        hessians: Optional[np.ndarray] = None) -> bool:
         """One boosting iteration (reference: GBDT::TrainOneIter,
-        gbdt.cpp:380-474). Returns True when no further splits are possible
+        gbdt.cpp:380-474): prepare the gradients and row weights,
+        `_dispatch` the iteration's device work, `_flush_pending` it into
+        host trees. A pipelined call flushes the PREVIOUS iteration while
+        its own runs on the device (__init__); any other call flushes its
+        own at once. Returns True when no further splits are possible
         (training should stop)."""
         # injection point: a dying TPU worker surfaces as a failed grow
         # dispatch (testing/faults.py)
@@ -1303,7 +1337,7 @@ class GBDT:
         # integer codes + the 0/1 row weight for the grower; the RAW f32
         # moments are kept for consumers whose math stays full-precision
         # (the piecewise-linear leaf fit)
-        grad_f32, hess_f32, row_weight_f32 = grad, hess, row_weight
+        moments_f32 = (grad, hess, row_weight)
         qscales = None
         if getattr(self, "_quant_mode", "none") != "none":
             with telemetry.span("boosting/quantize"):
@@ -1313,282 +1347,156 @@ class GBDT:
                     qmax=self._quant_qmax,
                     hess_const=self._quant_hess_const)
 
-        import jax
-
-        from ..learner.grow import FMETA_KEYS
-
-        if k > 1 and self._dist_grower is None:
-            if (not self.valid_sets and gradients is None
-                    and getattr(self, "_supports_pipeline", True)):
-                return self._train_one_iter_pipelined(
-                    grad, hess, row_weight, probe, qscales,
-                    (t_enter, compiled))
-            self._raise_if_nonfinite(probe, self.iter_)
-            return self._train_one_iter_multi(grad, hess, row_weight,
-                                              qscales, (t_enter, compiled))
-
-        if ((self._dist_grower is None or self._row_sharded) and k == 1
-                and not self.valid_sets and gradients is None
-                and getattr(self, "_supports_pipeline", True)):
-            return self._train_one_iter_pipelined(
-                grad, hess, row_weight, probe, qscales, (t_enter, compiled))
-        self._raise_if_nonfinite(probe, self.iter_)
-
-        # leaving the pipelined path (explicit gradients, a valid set
-        # added mid-training, ...): drain the pending tree FIRST so
-        # models stay in iteration order
-        self._flush_pending()
-
-        could_split_any = False
-        for cls in range(k):
-            entered = (t_enter, compiled) if cls == 0 else (
-                time.perf_counter(), telemetry.observer().totals())
-            mask = self._feature_mask()
-            qs = None if qscales is None else qscales[cls]
-            if getattr(self, "_linear", False):
-                # piecewise-linear leaves: plain grow (serial OR
-                # distributed), then the shared post-growth fit program
-                # replaces the constant leaf outputs with fitted
-                # intercept+slopes and returns the per-row training
-                # values (pre-shrinkage) for the score update
-                with telemetry.span("lgbm/iter/dispatch", iteration=it):
-                    state = self._grow(grad[cls], hess[cls], row_weight,
-                                       mask, qscale=qs)
-                    # the leaf regression consumes the RAW f32 moments:
-                    # quantization narrows the HISTOGRAM path only, the
-                    # fitted intercept/slope normal equations stay exact
-                    leaf_value, leaf_coeff, feats, vals = _fit_linear_post(
-                        self._raw, grad_f32[cls], hess_f32[cls],
-                        row_weight_f32, state,
-                        self.config.tree.linear_lambda,
-                        self._grower_cfg, self._linear_k)
-                small = {key: getattr(state, key)
-                         for key in _SMALL_STATE_KEYS}
-                small["leaf_value"] = leaf_value
-                small["leaf_coeff"] = leaf_coeff
-                small["leaf_features_inner"] = feats
-                tree, timing = self._fetch_tree(
-                    small, it, _dispatched(*entered))
-                if tree.num_leaves > 1:
-                    self._score = self._score.at[cls].add(
-                        jnp.float32(self.shrinkage_rate) * vals)
-            elif self._dist_grower is None:
-                # serial learner: grow + score update as ONE device
-                # program, then ONE host fetch of the small tree arrays
-                with telemetry.span("lgbm/iter/dispatch", iteration=it):
-                    self._score, small = _grow_and_update(
-                        self._score, self._binned, grad[cls], hess[cls],
-                        row_weight, jnp.asarray(mask), self.shrinkage_rate,
-                        self._n,
-                        [self._fmeta[key] for key in FMETA_KEYS], cls,
-                        self._grower_cfg, qscale=qs)
-                tree, timing = self._fetch_tree(
-                    small, it, _dispatched(*entered))
-            else:
-                with telemetry.span("lgbm/iter/dispatch", iteration=it):
-                    state = self._grow(grad[cls], hess[cls], row_weight,
-                                       mask, qscale=qs)
-                small = {key: getattr(state, key)
-                         for key in _SMALL_STATE_KEYS}
-                tree, timing = self._fetch_tree(
-                    small, it, _dispatched(*entered))
-                if tree.num_leaves > 1:
-                    # train score update via leaf ids (UpdateScore,
-                    # gbdt.cpp:521)
-                    with telemetry.span("boosting/update_score"):
-                        # padded to the configured leaf count: a table
-                        # sized by THIS tree's leaves would compile a new
-                        # gather for every distinct tree size
-                        leaf_vals = jnp.asarray(_pad_to(
-                            np.asarray(tree.leaf_value, np.float32),
-                            self._grower_cfg.num_leaves))
-                        lid = state.leaf_id
-                        if self._num_processes > 1:
-                            # scores are per-process row shards; pull this
-                            # process's block of the global leaf ids
-                            from ..parallel.multihost import local_rows
-                            lid = jnp.asarray(local_rows(state.leaf_id))
-                        self._score = self._score.at[cls].add(
-                            leaf_vals[jnp.clip(lid, 0,
-                                               tree.num_leaves - 1)])
-
-            if tree.num_leaves > 1:
-                could_split_any = True
-                self._update_valid_scores(cls, tree)
-                # fold boost-from-average into the tree AFTER the score
-                # update (scores were bumped at init): gbdt.cpp:445-447
-                if abs(getattr(self, "_pending_bias", 0.0)) > _K_EPSILON:
-                    tree.add_bias(self._pending_bias)
-                    self._pending_bias = 0.0
-                    self.init_score_bias = 0.0
-            self.models.append(tree)
-            self._log_pass_economics(*timing)
-
-        return self._finish_iter(could_split_any)
-
-    def _train_one_iter_pipelined(self, grad, hess, row_weight,
-                                  probe, qscales, entered) -> bool:
-        """One-class iteration of the serial learner, or of a data-parallel
-        one whose rows live on this process's devices, or the K class trees
-        of a serial multiclass iteration (one program, the class trees one
-        after another), with the fetch pipelined one iteration behind the
-        device dispatch (see __init__). The stop/rollback decision therefore
-        lags one iteration: an iteration in which no tree split is detected
-        when it is materialized and rolled back whole (its score delta was
-        already zero on device, the `grew` guard of _grow_and_update_impl,
-        the body of _grow_and_update_multi_impl; gbdt.cpp:466-472), and the
-        one extra dispatched iteration — which cannot split either — is
-        discarded by finalize_training()."""
-        import jax.numpy as jnp
-
-        from ..learner.grow import FMETA_KEYS
-
-        if getattr(self, "_stopped", False):
-            # report the pending stop ONCE, then drop the latch: the
-            # reference retries every TrainOneIter call (a fresh bag can
-            # open splits the previous one closed), so a later call must
-            # be allowed to train again (ADVICE.md round 5 #1)
+        # pipelined: the serial learner, or the one-class iteration of a
+        # learner whose rows live on this process's devices; every other
+        # call is the same path drained at once
+        pipelined = (not self.valid_sets and gradients is None
+                     and getattr(self, "_supports_pipeline", True)
+                     and (self._dist_grower is None
+                          or (self._row_sharded and k == 1)))
+        if pipelined and self._stopped:
+            # report the stop a drain latched ONCE, then drop the latch:
+            # the reference retries every TrainOneIter call, and a fresh
+            # bag can open splits the previous one closed
             self._stopped = False
             return True
-        if self.num_tree_per_iteration > 1:
-            small = self._dispatch_multi(grad, hess, row_weight, qscales)
-        else:
-            mask = self._feature_mask()
-            with telemetry.span("lgbm/iter/dispatch", iteration=self.iter_):
-                self._score, small = _grow_and_update(
-                    self._score, self._binned, grad[0], hess[0],
-                    row_weight, jnp.asarray(mask), self.shrinkage_rate,
-                    self._n, [self._fmeta[key] for key in FMETA_KEYS], 0,
-                    self._grower_cfg,
-                    qscale=None if qscales is None else qscales[0],
-                    grower=self._dist_grower)
-        dispatch = _dispatched(*entered)
-        # fetch + build the PREVIOUS tree while this one runs on device
-        ok_prev = self._flush_pending()
+        small = self._dispatch(grad, hess, row_weight, qscales, moments_f32)
+        dispatch = _dispatched(t_enter, compiled)
+        # the PREVIOUS iteration's trees, built while this one runs
+        grew_prev = self._flush_pending()
         # stash the DISPATCH-TIME shrinkage (a learning-rate schedule
-        # changes self.shrinkage_rate before the flush happens one
+        # changes self.shrinkage_rate before a pipelined flush happens one
         # iteration later), the dispatch-time non-finite probe and
-        # iteration index, fetched together with the small tree arrays,
-        # and what this call took to enqueue the tree (_dispatched)
+        # iteration index, and what this call took to enqueue the
+        # iteration (_dispatched)
         self._pending_small = (small, self.shrinkage_rate, probe, self.iter_,
                                dispatch)
         self.iter_ += 1
-        if not ok_prev:
-            # previous iteration produced no split: unwind the
-            # speculative iteration just dispatched. Under bagging it may
-            # HAVE split (a fresh bag can open splits the previous one
-            # closed) and its leaf values are already in the device
-            # score, so roll it back the way rollback_one_iter does —
-            # materialize and subtract its traversal values — instead of
-            # assuming the delta was zero.
-            small, shrink, probe, it, dispatch = self._pending_small
-            self._pending_small = None
-            self._raise_if_nonfinite(probe, it)
-            self.iter_ -= 1
-            trees, timing = self._fetch_iteration(small, it, dispatch, shrink)
-            self._log_pass_economics(*timing)
-            for cls, tree in enumerate(trees):
-                if tree.num_leaves > 1:
-                    neg = copy.deepcopy(tree)
-                    neg.leaf_value = -neg.leaf_value
-                    self._score = self._score.at[cls].add(
-                        predict_value_binned(neg.to_device(), self._binned))
-            # the stop is reported by THIS return — disarm the latch so
-            # the next call trains again (the latch only needs to carry
-            # a stop detected by an out-of-band drain, e.g. an eval's
-            # finalize_training, to the next train_one_iter)
-            self._stopped = False
-            return True
-        return False
+        if pipelined and grew_prev:
+            return False
+        # drained: this iteration's trees now. Pipelined behind a stop: the
+        # speculative iteration just dispatched is discarded
+        grew = self._flush_pending(discard=pipelined)
+        # this return reports the stop: nothing is left to latch
+        self._stopped = False
+        return not grew
 
-    def _dispatch_multi(self, grad, hess, row_weight, qscales):
-        """Enqueue the multiclass grow+update program of one iteration
-        (`_grow_and_update_multi`); the K trees' small state stays on the
-        device."""
+    def _dispatch(self, grad, hess, row_weight, qscales, moments_f32):
+        """Enqueue the device work of one iteration, the training score's
+        update included, and return its small tree state; nothing here
+        waits for the device or builds a host tree. The serial learner's
+        K > 1 class trees are one program (`_grow_and_update_multi`, a
+        leading class axis on the state). Otherwise each class is
+        dispatched in turn and the state is a list of K dicts: the fused
+        grow+update program where the learner has one in this process
+        (serial, data, voting), else `_grow_then_update`. The fused
+        programs are looked up in this module at every call."""
         import jax.numpy as jnp
 
         from ..learner.grow import FMETA_KEYS
 
         k = self.num_tree_per_iteration
-        masks = np.stack([self._feature_mask() for _ in range(k)])
+        fmeta = [self._fmeta[key] for key in FMETA_KEYS]
+        masks = [self._feature_mask() for _ in range(k)]
+        fused = not self._linear and (
+            self._dist_grower is None or (self._num_processes == 1
+                                          and self._tree_learner_kind
+                                          in ("data", "voting")))
         with telemetry.span("lgbm/iter/dispatch", iteration=self.iter_):
-            self._score, small = _grow_and_update_multi(
-                self._score, self._binned, grad, hess, row_weight,
-                jnp.asarray(masks), self.shrinkage_rate, self._n,
-                [self._fmeta[key] for key in FMETA_KEYS],
-                self._grower_cfg, qscales=qscales)
+            if k > 1 and self._dist_grower is None:
+                self._score, small = _grow_and_update_multi(
+                    self._score, self._binned, grad, hess, row_weight,
+                    jnp.asarray(np.stack(masks)), self.shrinkage_rate,
+                    self._n, fmeta, self._grower_cfg, qscales=qscales)
+                return small
+            smalls = []
+            for cls, mask in enumerate(masks):
+                qs = None if qscales is None else qscales[cls]
+                if fused:
+                    self._score, small = _grow_and_update(
+                        self._score, self._binned, grad[cls], hess[cls],
+                        row_weight, jnp.asarray(mask), self.shrinkage_rate,
+                        self._n, fmeta, cls, self._grower_cfg, qscale=qs,
+                        grower=self._dist_grower)
+                else:
+                    small = self._grow_then_update(
+                        cls, grad[cls], hess[cls], row_weight, mask, qs,
+                        moments_f32)
+                smalls.append(small)
+        return smalls
+
+    def _grow_then_update(self, cls, grad, hess, row_weight, mask, qscale,
+                          moments_f32):
+        """One class tree where the learner has no fused program in this
+        process, or the leaves are linear: `_grow`, then the training
+        score's update on the device. Returns the small tree state."""
+        import jax.numpy as jnp
+
+        state = self._grow(grad, hess, row_weight, mask, qscale=qscale)
+        small = {key: getattr(state, key) for key in _SMALL_STATE_KEYS}
+        if self._linear:
+            # the leaf regression consumes the RAW f32 moments:
+            # quantization narrows the HISTOGRAM path only, the fitted
+            # intercept/slope normal equations stay exact
+            grad_f32, hess_f32, row_weight_f32 = moments_f32
+            small["leaf_value"], small["leaf_coeff"], \
+                small["leaf_features_inner"], vals = _fit_linear_post(
+                    self._raw, grad_f32[cls], hess_f32[cls], row_weight_f32,
+                    state, self.config.tree.linear_lambda, self._grower_cfg,
+                    self._linear_k)
+            self._score = self._score.at[cls].add(jnp.where(
+                state.num_leaves_used > 1,
+                jnp.float32(self.shrinkage_rate) * vals, 0.0))
+            return small
+        lid, used, values = (state.leaf_id, state.num_leaves_used,
+                             state.leaf_value)
+        if self._num_processes > 1:
+            # scores are per-process row shards: this process's block of
+            # the global leaf ids
+            from ..parallel.multihost import local_rows
+            lid, used, values = (local_rows(lid), np.asarray(used),
+                                 np.asarray(values))
+        self._score = _score_update_device(self._score, values, lid, used,
+                                           self.shrinkage_rate, cls,
+                                           self._grower_cfg.num_leaves)
         return small
 
-    def _fetch_class_trees(self, small, iteration, dispatch, shrink=None):
-        """`_fetch_tree` for the multiclass program: ONE `device_get`
-        of the K trees' small state (a leading class axis on every field),
-        then the K trees, shrunk by the dispatch-time rate where given.
-        Multiclass has no boost-from-average bias to fold (GBDT.init)."""
+    def _fetch(self, small, iteration, shrink):
+        """ONE `device_get` of a dispatched iteration's small state (the
+        host's wait for the device, a span of its own), then its K host
+        trees, each that grew shrunk by the dispatch-time rate. Returns
+        the trees, the host state (a leading class axis where K > 1, the
+        per-class dispatches' states stacked so) and the wait and its end
+        for `_log_pass_economics`."""
         import jax
 
+        k = self.num_tree_per_iteration
         t0 = time.perf_counter()
         with telemetry.span("lgbm/iter/fetch", iteration=iteration):
-            host_state = _HostState(jax.device_get(small))
+            host = jax.device_get(small)
         t1 = time.perf_counter()
-        trees = []
         with telemetry.span("lgbm/iter/build_tree", iteration=iteration):
-            for cls in range(self.num_tree_per_iteration):
-                tree = Tree.from_grower_state(
-                    _class_state(host_state, cls), self.train_data)
+            if isinstance(host, list):
+                host = host[0] if k == 1 else {
+                    key: np.stack([h[key] for h in host]) for key in host[0]}
+            host_state = _HostState(host)
+            trees = [Tree.from_grower_state(
+                host_state if k == 1 else _class_state(host_state, cls),
+                self.train_data) for cls in range(k)]
+            for tree in trees:
                 if tree.num_leaves > 1:
-                    tree.apply_shrinkage(
-                        self.shrinkage_rate if shrink is None else shrink)
-                trees.append(tree)
-        return trees, (host_state, dispatch, t1 - t0, t1)
-
-    def _fetch_iteration(self, small, iteration, dispatch, shrink,
-                         fold_bias=False):
-        """The trees of one dispatched iteration program and the arguments
-        of `_log_pass_economics`: the K class trees of the multiclass
-        program, or the one tree of the one-class program."""
-        if self.num_tree_per_iteration > 1:
-            return self._fetch_class_trees(small, iteration, dispatch, shrink)
-        tree, timing = self._fetch_tree(small, iteration, dispatch, shrink,
-                                        fold_bias)
-        return [tree], timing
-
-    def _fetch_tree(self, small, iteration, dispatch, shrink=None,
-                    fold_bias=False):
-        """Device small-state -> host Tree with its shrinkage (the
-        dispatch-time one where the pipelined path stashed it) and,
-        where asked, the one-time boost-from-average bias fold — the
-        single copy every training path uses. The `device_get` is the
-        host's wait for the device and has a span of its own. Returns
-        the tree and the arguments of `_log_pass_economics`, a call the
-        caller makes once the tree is appended; `dispatch` is what the
-        tree's enqueue took (`_dispatched`) and is only passed through."""
-        import jax
-
-        t0 = time.perf_counter()
-        with telemetry.span("lgbm/iter/fetch", iteration=iteration):
-            host_state = _HostState(jax.device_get(small))
-        t1 = time.perf_counter()
-        with telemetry.span("lgbm/iter/build_tree", iteration=iteration):
-            tree = Tree.from_grower_state(host_state, self.train_data)
-            if tree.num_leaves > 1:
-                tree.apply_shrinkage(
-                    self.shrinkage_rate if shrink is None else shrink)
-                if fold_bias and \
-                        abs(getattr(self, "_pending_bias", 0.0)) > _K_EPSILON:
-                    tree.add_bias(self._pending_bias)
-                    self._pending_bias = 0.0
-                    self.init_score_bias = 0.0
-        return tree, (host_state, dispatch, t1 - t0, t1)
+                    tree.apply_shrinkage(shrink)
+        return trees, host_state, t1 - t0, t1
 
     def _log_pass_economics(self, host_state, dispatch=(0.0, 0.0, 0.0, 0),
                             fetch_wait_s=0.0, t_fetched=None) -> None:
-        """Append this tree's `telemetry.TreeRecord` to `pass_log` (read
+        """Append this iteration's `telemetry.TreeRecord` to `pass_log` (read
         by the run log and by benchmarks/layer_metrics/) and feed the
         `tree/*` counters from it. rows_contracted is the compaction
         economics headline (full passes report ~passes * N), comm_elems
         the histogram-merge volume the scatter schedule exists to shrink;
         full against compacted passes are told from `pass_rows`, which
-        the fetch already carried. Called once the tree is appended:
+        the fetch already carried. Called once the trees are appended:
         `build_tree_s` runs from the end of the fetch to here."""
         # the multiclass program's state has a leading class axis, one
         # class tree after another: a count of the record is the mean over
@@ -1665,36 +1573,55 @@ class GBDT:
             "internal_count) may be off by a few rows"
             % (", ".join(names), bin_, top))
 
-    def _flush_pending(self) -> bool:
-        """Materialize the pipelined tree, if any. Returns False when the
-        tree could not split (its iteration is rolled back here)."""
+    def _flush_pending(self, discard: bool = False) -> bool:
+        """The one place a dispatched iteration becomes host trees, if one
+        is pending: `_fetch` them, add each tree that grew to the
+        validation scores, THEN fold the boost-from-average bias into the
+        first (gbdt.cpp:445-447; the validation scores already start from
+        it, add_valid), append the trees and write the iteration's
+        `TreeRecord`. Returns False when the iteration is rolled back
+        (gbdt.cpp:466-472): no class tree grew, a stop latched for the
+        next pipelined call; or `discard`, the speculative iteration
+        dispatched behind a stop."""
         if self._pending_small is None:
             return True
         small, shrink, probe, it, dispatch = self._pending_small
         self._pending_small = None
         self._raise_if_nonfinite(probe, it)
-        trees, timing = self._fetch_iteration(small, it, dispatch, shrink,
-                                              fold_bias=True)
-        grew = any(tree.num_leaves > 1 for tree in trees)
-        if grew:
+        trees, host_state, fetch_wait_s, t_fetched = self._fetch(
+            small, it, shrink)
+        grew = [cls for cls, tree in enumerate(trees) if tree.num_leaves > 1]
+        for cls in grew:
+            if discard:
+                # a fresh bag can split where the previous one stopped, and
+                # the leaf values are already in the device score: subtract
+                # them as rollback_one_iter does
+                neg = copy.deepcopy(trees[cls])
+                neg.leaf_value = -neg.leaf_value
+                self._score = self._score.at[cls].add(
+                    predict_value_binned(neg.to_device(), self._binned))
+            else:
+                self._update_valid_scores(cls, trees[cls])
+        kept = bool(grew) and not discard
+        if kept:
+            if abs(getattr(self, "_pending_bias", 0.0)) > _K_EPSILON:
+                trees[grew[0]].add_bias(self._pending_bias)
+                self._pending_bias = 0.0
+                self.init_score_bias = 0.0
             self.models.extend(trees)
             self._bump_model_version()
-        self._log_pass_economics(*timing)
-        if grew:
-            # a splitting tree clears any stale stop latch: the latch
-            # exists to carry a pending stop across a drain, not to
-            # poison later successful iterations (a fresh bag can open
-            # splits a previous bag closed — ADVICE.md round 5 #1)
-            self._stopped = False
-            return True
-        self.iter_ -= 1
-        # latch the stop so a drain from finalize_training (e.g. a
-        # training-metric eval mid-loop) cannot swallow it — the next
-        # train_one_iter must still report termination
-        self._stopped = True
-        log.warning("Stopped training because there are no more leaves "
-                    "that meet the split requirements")
-        return False
+        self._log_pass_economics(host_state, dispatch, fetch_wait_s,
+                                 t_fetched)
+        # a latched stop is cleared by a tree that grows: the latch carries
+        # a stop across a drain (finalize_training, e.g. a training-metric
+        # eval mid-loop), it must not poison later iterations
+        self._stopped = not (kept or discard)
+        if not kept:
+            self.iter_ -= 1
+            if not discard:
+                log.warning("Stopped training because there are no more "
+                            "leaves that meet the split requirements")
+        return kept
 
     def finalize_training(self) -> None:
         """Drain the async pipeline (engine.train calls this after the
@@ -1795,45 +1722,6 @@ class GBDT:
                             dtree, self._valid_binned[vi],
                             vraws[vi] if vraws else None))
 
-    def _finish_iter(self, could_split_any: bool) -> bool:
-        """Advance the iteration counter, rolling the whole iteration
-        back when no class tree could split (gbdt.cpp:466-472)."""
-        # trees were appended (or are about to be popped) either way
-        self._bump_model_version()
-        self.iter_ += 1
-        if not could_split_any:
-            for _ in range(self.num_tree_per_iteration):
-                self.models.pop()
-            self.iter_ -= 1
-            log.warning("Stopped training because there are no more leaves "
-                        "that meet the split requirements")
-            return True
-        # sync-path iterations that split clear the pipelined stop latch
-        # (same rationale as in _flush_pending)
-        self._stopped = False
-        return False
-
-    def _train_one_iter_multi(self, grad, hess, row_weight, qscales,
-                              entered) -> bool:
-        """All num_class trees of one iteration as ONE device program
-        (serial learner; see _grow_and_update_multi_impl), fetched at
-        once: where the one-class loop is synchronous too (a validation
-        set, explicit gradients, DART)."""
-        # leaving the pipelined path: the pending iteration first
-        self._flush_pending()
-        it = self.iter_
-        small = self._dispatch_multi(grad, hess, row_weight, qscales)
-        trees, timing = self._fetch_class_trees(small, it,
-                                                _dispatched(*entered))
-        could_split_any = False
-        for cls, tree in enumerate(trees):
-            if tree.num_leaves > 1:
-                could_split_any = True
-                self._update_valid_scores(cls, tree)
-            self.models.append(tree)
-        self._log_pass_economics(*timing)
-        return self._finish_iter(could_split_any)
-
     def rollback_one_iter(self) -> None:
         """Reference: GBDT::RollbackOneIter, gbdt.cpp:476-492."""
         import jax.numpy as jnp
@@ -1861,25 +1749,6 @@ class GBDT:
         self.iter_ -= 1
         self._bump_model_version()
 
-    # ------------------------------------------------------------------
-    def eval_once(self) -> List[Tuple[str, str, float, bool]]:
-        """Evaluate all metrics; returns (data_name, metric_name, value,
-        is_bigger_better) tuples (reference: GBDT::OutputMetric,
-        gbdt.cpp:575-632)."""
-        out = []
-        self.finalize_training()
-        if self.metrics and self.config.metric.is_provide_training_metric:
-            train_score = self._train_score_unpadded()
-            for m in self.metrics:
-                for name, val in m.eval(train_score, self.objective):
-                    out.append(("training", name, val, m.is_bigger_better))
-        for vi, ms in enumerate(self.valid_metrics):
-            vscore = np.asarray(self._valid_score[vi], np.float64).reshape(-1)
-            for m in ms:
-                for name, val in m.eval(vscore, self.objective):
-                    out.append((self.valid_names[vi], name, val, m.is_bigger_better))
-        return out
-
     def _train_score_unpadded(self) -> np.ndarray:
         s = np.asarray(self._score, np.float64)
         return s[:, :self._n].reshape(-1)
@@ -1892,8 +1761,8 @@ class GBDT:
     def current_iteration(self) -> int:
         # drain the async pipeline like num_trees(): mid-pipeline the
         # counter could name an iteration whose tree later fails to
-        # split and is rolled back (non-monotonic, inconsistent with
-        # num_trees — ADVICE.md round 5 #2)
+        # split and is rolled back, so it would go down again and
+        # disagree with num_trees()
         self.finalize_training()
         return self.iter_
 

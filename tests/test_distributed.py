@@ -144,9 +144,10 @@ def test_feature_parallel_sparse_data_pins_unbundled_behavior(caplog):
 
 
 def test_multiclass_serial_batched_matches_data_parallel():
-    """The vmap'd one-program multiclass iteration (serial learner) must
-    produce the SAME model as the data-parallel learner's per-class loop
-    on the 8-device mesh — cross-validating the two multiclass paths."""
+    """The serial learner's one-program multiclass iteration (the class
+    trees one after another) must produce the SAME model as the
+    data-parallel learner's per-class dispatches on the 8-device mesh —
+    cross-validating the two multiclass paths."""
     import lightgbm_tpu as lgb
     rng = np.random.RandomState(5)
     n, f, k = 600, 8, 3

@@ -1,8 +1,8 @@
 """The multiclass deployment (`covtype-581kx54`, cell
 `covtype-multiclass-1chip`) at a size a test run can hold: the program
 against the benchmark's plain reference class by class through
-`Booster.update()`, the pipelined multiclass loop against the
-synchronous one, the schedule the configuration's shape takes, the
+`Booster.update()`, the pipelined multiclass loop against the same
+loop drained at once, the schedule the configuration's shape takes, the
 per-iteration record and its readers, the faults the limits must catch,
 the generator and what `BENCHMARK.json` says of the cell. Nothing here is
 a device measurement."""
@@ -164,7 +164,7 @@ def test_reference_reads_a_one_leaf_class_tree_by_its_root():
 
 
 # ---------------------------------------------------------------------------
-# (b) the pipelined multiclass loop against the synchronous one
+# (b) the pipelined multiclass loop against the same loop drained
 # ---------------------------------------------------------------------------
 PARAMS = {"objective": "multiclass", "num_class": 7, "num_leaves": 15,
           "max_bin": 63, "learning_rate": 0.1, "min_data_in_leaf": 1,

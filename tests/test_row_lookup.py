@@ -96,7 +96,7 @@ def test_row_lookup_denormals_survive():
                          ids=["leaf_values", "node_table"])
 @pytest.mark.parametrize("shared_table", [False, True])
 def test_row_lookup_under_vmap(m, dtype, shared_table):
-    """the class axis of the multiclass vmap: a table a class, or one"""
+    """the class axis of the sweep grower's vmap: a table a class, or one"""
     classes, n = 3, 700
     tables = np.stack([_table(m, dtype, seed=c) for c in range(classes)])
     ids = np.stack([_ids(m, n, seed=10 + c) for c in range(classes)])

@@ -577,7 +577,7 @@ def test_benchmark_json_names_the_cell_and_its_readers():
         # with a list of cells came with higgs-train-dp4 (the merge),
         # msltr-rank-1chip (the ranking gradients), expo-train-1chip
         # (bundles and the sparse ingest) and covtype-multiclass-1chip
-        # (the vmapped class trees)
+        # (the class trees)
         assert ("workloads" in m) == (
             m["name"].startswith("merge.")
             or m["name"].startswith("gradients.")
